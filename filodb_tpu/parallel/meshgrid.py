@@ -30,6 +30,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from filodb_tpu.ops.grid import lane_tile
 from filodb_tpu.query.logical import AggregationOperator as Agg
 from filodb_tpu.utils import devicewatch
 from filodb_tpu.utils.devicewatch import LEDGER
@@ -184,9 +185,9 @@ def _grouped_local(q, mode: str, ksub: int, lanes: int, num_groups: int,
 def _grouped_inner(mesh, q, mode: str, ksub: int, nrows: int, lmax: int,
                    num_groups: int, op: str):
     """shard_map-wrapped grouped body at the shared lane width rule
-    (devicestore._plan_locked: tall strided slices narrow the tile)."""
+    (ops/grid.py lane_tile: tall strided slices narrow the tile)."""
     from jax.sharding import PartitionSpec as P
-    lanes = 1024 if (lmax % 1024 == 0 and nrows <= 256) else _LANE_PAD
+    lanes = lane_tile(lmax, nrows)
     local, psum_planes = _grouped_local(q, mode, ksub, lanes, num_groups,
                                         op)
     in_specs = (P(_AXES, None, None), P(_AXES, None, None),
@@ -360,7 +361,7 @@ def _grid_mesh_topk_program(mesh_key, q, mode: str, ksub: int, nrows: int,
     from filodb_tpu.parallel.mesh import _MESHES
     mesh = _MESHES[mesh_key]
     nst = mesh.devices.shape[1]
-    lanes = 1024 if (lmax % 1024 == 0 and nrows <= 256) else _LANE_PAD
+    lanes = lane_tile(lmax, nrows)
     G = num_groups
     leaf = _stepped_lanes(mode, q, lanes)
     sign = -1.0 if bottom else 1.0
@@ -415,7 +416,7 @@ def _grid_mesh_quantile_program(mesh_key, q, mode: str, ksub: int,
     from filodb_tpu.ops import tdigest_device as tdd
     from filodb_tpu.parallel.mesh import _MESHES
     mesh = _MESHES[mesh_key]
-    lanes = 1024 if (lmax % 1024 == 0 and nrows <= 256) else _LANE_PAD
+    lanes = lane_tile(lmax, nrows)
     G, C = num_groups, compression
     leaf = _stepped_lanes(mode, q, lanes)
 
@@ -459,7 +460,7 @@ def _grid_mesh_values_program(mesh_key, q, mode: str, ksub: int,
 
     from filodb_tpu.parallel.mesh import _MESHES
     mesh = _MESHES[mesh_key]
-    lanes = 1024 if (lmax % 1024 == 0 and nrows <= 256) else _LANE_PAD
+    lanes = lane_tile(lmax, nrows)
     leaf = _stepped_lanes(mode, q, lanes)
 
     def local(ts, vals, phase, s0):

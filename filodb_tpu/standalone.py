@@ -96,6 +96,8 @@ driven by a JSON config instead of HOCON:
 from __future__ import annotations
 
 import json
+import os
+import pathlib
 import signal
 import sys
 import threading
@@ -1087,12 +1089,44 @@ class FiloServer:
         self.metastore.shutdown()
 
 
-def main(argv=None) -> int:
-    # epoch-ms timestamps need int64 end to end; on CPU hosts x64 must be
-    # enabled explicitly (TPU kernels rebase to int32 offsets internally)
+def enable_server_x64() -> None:
+    """The x64 setting of a server process.  Epoch-ms step grids and
+    sample timestamps are int64 on the general path (ops/windows.py,
+    query/rangefns.py), so x64 is on whatever the backend.  The Pallas
+    grid kernels take int32-rebased planes and are traced with 32-bit
+    defaults inside that process (ops/grid.py ``_x32``): Mosaic accepts
+    no i64 scalar."""
     import jax
     jax.config.update("jax_enable_x64", True)
 
+
+def place_compile_cache() -> None:
+    """Give JAX's persistent compilation cache a home before the first
+    jit.  A deployment places it through ``JAX_COMPILATION_CACHE_DIR``
+    (JAX reads the variable itself; nothing is set here).  Without the
+    variable it lives at ``<checkout>/.jax_cache`` — a fixed path,
+    because the path is part of the cache's key: a directory that moves
+    between runs never hits."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        str(pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"))
+
+
+def boot(config: dict) -> FiloServer:
+    """Bring one server process up: JAX settings, compile cache, every
+    subsystem, listening.  ``main`` and ``chip_smoke.py`` both come up
+    through here, so the smoke proves the program users start."""
+    enable_server_x64()
+    place_compile_cache()
+    server = FiloServer(config)
+    server.start()
+    return server
+
+
+def main(argv=None) -> int:
     args = argv if argv is not None else sys.argv[1:]
     if not args:
         print("usage: python -m filodb_tpu.standalone <config.json>",
@@ -1100,9 +1134,8 @@ def main(argv=None) -> int:
         return 2
     with open(args[0]) as f:
         config = json.load(f)
-    server = FiloServer(config)
-    port = server.start()
-    print(f"FiloDB-TPU node {server.node} up: http={port} "
+    server = boot(config)
+    print(f"FiloDB-TPU node {server.node} up: http={server.http.port} "
           f"datasets={server.manager.datasets()}")
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *a: stop.set())

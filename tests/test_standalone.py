@@ -217,3 +217,36 @@ class TestCli:
                          "instance"]) == 0
         out = capsys.readouterr().out
         assert "i0" in out
+
+
+class TestCompileCachePlacement:
+    """standalone.place_compile_cache: placed from outside, or at one
+    fixed path inside the checkout — never anywhere that moves."""
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        import jax
+        seen = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: seen.append((k, v)))
+        return seen
+
+    def test_env_var_set_means_nothing_is_set_in_code(self, monkeypatch,
+                                                      updates, tmp_path):
+        from filodb_tpu import standalone
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        standalone.place_compile_cache()
+        assert updates == []
+
+    def test_unset_means_a_fixed_path_in_the_checkout(self, monkeypatch,
+                                                      updates):
+        import pathlib
+
+        import filodb_tpu
+        from filodb_tpu import standalone
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        standalone.place_compile_cache()
+        standalone.place_compile_cache()
+        checkout = pathlib.Path(filodb_tpu.__file__).resolve().parents[1]
+        assert updates == [("jax_compilation_cache_dir",
+                            str(checkout / ".jax_cache"))] * 2
