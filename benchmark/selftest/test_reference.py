@@ -1,0 +1,128 @@
+"""The vectorised reference against the repo's brute-force oracle
+(``tests/oracle.py``, a Python loop a window) on a few hundred series:
+resets among them, both edge rows of every window (a phase of 1 ms and of
+scrape - 1 ms), panels that start before the first row."""
+
+import json
+import sys
+
+import numpy as np
+import pytest
+from conftest import BENCH, ROOT
+from harness import compare, traffic
+from harness.population import Population
+
+sys.path.insert(0, str(ROOT / "tests"))
+oracle = pytest.importorskip("oracle")
+
+SPEC = dict(json.loads((BENCH / "configs" / "jmh-inmem-1shard.json")
+                       .read_text())["population"], namespaces=3)
+
+
+def population(seed=11):
+    pop = Population(SPEC, seed)
+    # both edges of the scrape interval
+    pop.phase[:4] = [1, SPEC["scrape_ms"] - 1, 1, SPEC["scrape_ms"] - 1]
+    pop.ts = (pop.base_ms + np.arange(pop.rows, dtype=np.int64)[None, :]
+              * pop.scrape_ms + pop.phase[:, None])
+    return pop
+
+
+def panels():
+    for path in sorted((BENCH / "traffic").glob("*.json")):
+        for p in traffic.load(path)["panels"]:
+            yield pytest.param(p, id=f"{path.stem}.{p['name']}")
+    # panels over the whole shard, summed in blocks and by a label
+    for by in ({}, {"by": "g"}):
+        yield pytest.param(
+            {"name": "wide", "query": "", "steps": 221, "step_ms": 15000,
+             "end": "newest", "reference": dict(
+                 {"fn": "rate", "window_ms": 300000, "aggregate": "sum"},
+                 **by)}, id="wide-sum" + ("-by-g" if by else ""))
+    # a panel that starts before the data: windows with 0 and 1 rows
+    yield pytest.param(
+        {"name": "early", "query": "", "steps": 255, "step_ms": 15000,
+         "end": "newest", "reference": {"fn": "rate", "window_ms": 300000,
+                                        "aggregate": "none",
+                                        "key": "instance"},
+         "select": {"draw": "zipf", "s": 1.1, "over": "namespaces"}},
+        id="early-rate")
+
+
+@pytest.mark.parametrize("panel", panels())
+def test_reference_agrees_with_the_oracle(panel):
+    pop = population()
+    assert len(pop.reset_series) >= 3
+    ref = panel["reference"]
+    ns = int(pop.ns[pop.reset_series[0]])      # a namespace with a reset
+    got = compare.reference_answer(pop, panel, ns)
+    start, end, step, _n = traffic.panel_range(panel, SPEC)
+    sel = compare.selection(pop, panel, ns)
+    per = np.stack([oracle.range_fn(ref["fn"], pop.ts[s], pop.vals[s], start,
+                                    end, step, ref["window_ms"])
+                    for s in sel])
+    if ref["aggregate"] == "sum" and "by" not in ref:
+        want = {"": per.sum(axis=0)}
+    elif ref["aggregate"] == "sum":
+        want = {f"g{g:02d}": per[pop.g[sel] == g].sum(axis=0)
+                for g in range(pop.groups)}
+    elif ref["aggregate"] == "quantile":
+        want = {"": np.quantile(per, ref["q"], axis=0)}
+    else:
+        want = {pop.instance_name(s): per[i] for i, s in enumerate(sel)}
+    g = compare.gap(got, want)
+    assert g["series_off"] == 0 and g["absent_cells"] == 0
+    assert g["rel_err"] < 1e-12
+
+
+MIXES = sorted(p.stem for p in (BENCH / "traffic").glob("*.json"))
+
+
+@pytest.mark.parametrize("control", compare.CONTROLS)
+@pytest.mark.parametrize("mix", MIXES)
+def test_every_control_comes_out_as_not_correct(mix, control):
+    """The degraded reference in the program's place fails the limits of
+    ``run.py``, at a size a test holds: the lower precision and the lost
+    series in EVERY panel of the mix, the stale scrape in at least one (a
+    panel's last step alone sees it)."""
+    pop = population(5)
+    t = traffic.load(BENCH / "traffic" / f"{mix}.json")
+    failed = []
+    for p in t["panels"]:
+        g = compare.gap(compare.control_answers(pop, control, p, 1),
+                        compare.reference_answer(pop, p, 1))
+        failed.append(g["rel_err"] > p["limits"]["rel_err"]
+                      or g["series_off"] > 0 or g["absent_cells"] > 0)
+    assert all(failed) if control != "one_scrape_stale" else any(failed), \
+        list(zip([p["name"] for p in t["panels"]], failed))
+
+
+def test_the_reference_imports_nothing_of_the_program():
+    for path in list((BENCH / "harness" / "reference").glob("*.py")) \
+            + [BENCH / "harness" / "compare.py",
+               BENCH / "harness" / "population.py", BENCH / "loadgen.py",
+               BENCH / "harness" / "traffic.py"]:
+        text = path.read_text()
+        assert "filodb_tpu" not in text and "import jax" not in text, path
+
+
+def test_every_seed_gets_the_same_work_in_another_order():
+    t = traffic.load(BENCH / "traffic" / "jmh-queries.json")
+    full = dict(SPEC, namespaces=800)
+
+    def draw(seed, n=240):
+        s = traffic.session(t, seed, 0, full, "prom", 30, False)
+        return [next(s) for _ in range(n)]
+    a, b = draw(1), draw(2 ** 31 + 9)
+    assert sorted(r.panel for r in a) == sorted(r.panel for r in b)
+    assert [r.key for r in a] != [r.key for r in b]
+    assert [r.path for r in draw(1)] == [r.path for r in a]
+
+
+def test_every_seed_loads_the_same_series_dealt_anew():
+    a, b = Population(SPEC, 1), Population(SPEC, 2 ** 31 + 9)
+    assert (a.vals != b.vals).any()
+    order = lambda v: v[np.lexsort(v.T[::-1])]       # noqa: E731
+    assert (order(a.vals) == order(b.vals)).all()
+    assert len(a.reset_series) == len(b.reset_series) >= 3
+    assert (Population(SPEC, 1).vals == a.vals).all()
