@@ -218,6 +218,10 @@ def _host_sync_kind(call: ast.Call) -> Optional[tuple]:
             return ".block_until_ready()", recv
     elif isinstance(f, ast.Name) and f.id == "float" and call.args:
         return "float()", call.args[0]
+    elif isinstance(f, ast.Name) and f.id == "_fetch" and call.args:
+        # devicestore's one readback helper (device wait + np.asarray
+        # as stage spans): the sync is declared where it is asked for
+        return "_fetch()", call.args[0]
     return None
 
 

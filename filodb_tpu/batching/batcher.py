@@ -37,7 +37,7 @@ import time
 import numpy as np
 
 from filodb_tpu.utils.devicewatch import FLIGHT
-from filodb_tpu.utils.observability import batch_metrics
+from filodb_tpu.utils.observability import TRACER, batch_metrics
 from filodb_tpu.workload import deadline as wdl
 
 _BATCH_BROKEN = False
@@ -193,10 +193,19 @@ class QueryBatcher:
                         self._inflight.pop(key, None)
         if lead:
             self._lead(g, window_ms, batch_launch)
-        elif not g.done.wait(timeout=window_ms / 1000.0 + 60.0):
-            self._m["fallbacks"].inc(dataset=self.dataset,
-                                     reason="timeout")
-            return None
+        else:
+            # a member's rendezvous wait holds the leader's window AND
+            # its stacked launch: dispatch, device and readback run on
+            # the leader's thread and show in the leader's request; a
+            # pure wait, so no leaf on the profiler's host plane
+            with TRACER.stage("batch.wait", leaf=False,
+                              role="member") as sp:
+                done = g.done.wait(timeout=window_ms / 1000.0 + 60.0)
+                sp.tag(members=len(g.members))
+            if not done:
+                self._m["fallbacks"].inc(dataset=self.dataset,
+                                         reason="timeout")
+                return None
         res = g.results[my] if g.results is not None else None
         return res
 
@@ -204,11 +213,13 @@ class QueryBatcher:
 
     def _lead(self, g, window_ms, batch_launch) -> None:
         end = time.monotonic() + window_ms / 1000.0
-        while not g.full.is_set():
-            left = end - time.monotonic()
-            if left <= 0:
-                break
-            g.full.wait(left)
+        with TRACER.stage("batch.wait", role="leader") as sp:
+            while not g.full.is_set():
+                left = end - time.monotonic()
+                if left <= 0:
+                    break
+                g.full.wait(left)
+            sp.tag(members=len(g.members))
         with self._lock:
             g.open = False
             if self._groups.get(g.key) is g:

@@ -329,6 +329,7 @@ def execplan_handler(memstore) -> Callable[..., dict]:
             result = plan.execute(ctx)
         out = serialize_result(result)
         try:
+            TRACER.flush()      # this thread's share of the trace
             out["spans"] = [span_to_dict(r)
                             for r in TRACE_STORE.spans_for(tid)]
         except Exception:  # noqa: BLE001 — span return is best-effort

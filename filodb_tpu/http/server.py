@@ -184,6 +184,9 @@ class FiloHttpServer:
     _thread: Optional[threading.Thread] = None
     _wm_lock: threading.Lock = field(default_factory=threading.Lock)
     _ins_lock: threading.Lock = field(default_factory=threading.Lock)
+    # (trace id, root span id) of the query the request on THIS thread
+    # ran: http.encode / http.write join that query's trace
+    _answered: threading.local = field(default_factory=threading.local)
 
     def bind_dataset(self, binding: DatasetBinding) -> None:
         self.datasets[binding.dataset] = binding
@@ -219,6 +222,15 @@ class FiloHttpServer:
     # --------------------------------------------------------------- routing
 
     def _handle(self, req: BaseHTTPRequestHandler, method: str) -> None:
+        """One request as the ``http.request`` stage, from entry to
+        after the write.  It encloses the others, so it is no leaf."""
+        self._answered.tok = None
+        with TRACER.stage("http.request", leaf=False, cpu=True,
+                          method=method):
+            self._handle_request(req, method)
+
+    def _handle_request(self, req: BaseHTTPRequestHandler,
+                        method: str) -> None:
         if req.path.split("?")[0] == "/metrics":
             # plain-text route handled entirely outside the JSON error
             # epilogue; generation errors become a 500, write errors on a
@@ -303,28 +315,35 @@ class FiloHttpServer:
             code, payload = 400, error_response("bad_data", str(e))
         except Exception as e:  # noqa: BLE001
             code, payload = 500, error_response("internal", str(e))
-        data = json.dumps(payload).encode()
+        # the body is built, so these two can be in no answer's stats:
+        # they live in the stage table, and in the trace of the query
+        # this request ran (under its root span) where it ran one
+        tok = self._answered.tok
+        with TRACER.attach(tok), TRACER.stage("http.encode"):
+            data = json.dumps(payload).encode()
         try:
-            req.send_response(code)
-            req.send_header("Content-Type", "application/json")
-            if retry_after is not None:
-                req.send_header("Retry-After",
-                                str(int(math.ceil(retry_after))))
-            if isinstance(payload, dict) and payload.get("warnings"):
-                # partial-data flag as a header too, so load balancers /
-                # caches can act on it without parsing the body
-                req.send_header("X-FiloDB-Partial-Data", "true")
-            trace_id = None
-            if isinstance(payload, dict) \
-                    and isinstance(payload.get("data"), dict) \
-                    and isinstance(payload["data"].get("stats"), dict):
-                trace_id = payload["data"]["stats"].get("traceId")
-            if trace_id:
-                # lets the client jump straight to /admin/traces/<id>
-                req.send_header("X-FiloDB-Trace-Id", str(trace_id))
-            req.send_header("Content-Length", str(len(data)))
-            req.end_headers()
-            req.wfile.write(data)
+            with TRACER.attach(tok), \
+                    TRACER.stage("http.write", bytes=len(data)):
+                req.send_response(code)
+                req.send_header("Content-Type", "application/json")
+                if retry_after is not None:
+                    req.send_header("Retry-After",
+                                    str(int(math.ceil(retry_after))))
+                if isinstance(payload, dict) and payload.get("warnings"):
+                    # partial-data flag as a header too, so load balancers /
+                    # caches can act on it without parsing the body
+                    req.send_header("X-FiloDB-Partial-Data", "true")
+                trace_id = None
+                if isinstance(payload, dict) \
+                        and isinstance(payload.get("data"), dict) \
+                        and isinstance(payload["data"].get("stats"), dict):
+                    trace_id = payload["data"]["stats"].get("traceId")
+                if trace_id:
+                    # lets the client jump straight to /admin/traces/<id>
+                    req.send_header("X-FiloDB-Trace-Id", str(trace_id))
+                req.send_header("Content-Length", str(len(data)))
+                req.end_headers()
+                req.wfile.write(data)
         except Exception:  # noqa: BLE001 — client disconnected mid-response
             pass
 
@@ -784,7 +803,7 @@ class FiloHttpServer:
     def _kernels(self) -> tuple[int, dict]:
         """The kernel flight deck (ISSUE 15): per-program launches,
         compiles, sampled EWMA device time, achieved GB/s vs the
-        configured HBM roof, and regression-sentry state — the live
+        device's published HBM peak, and regression-sentry state — the live
         counterpart of doc/kernel.md's static roofline table."""
         from filodb_tpu.utils import devicewatch
         return 200, {"status": "success",
@@ -863,12 +882,11 @@ class FiloHttpServer:
             storm_window_s=p.get("jit-storm-window-s"))
         if "flight-recorder-size" in p:
             devicewatch.FLIGHT.resize(int(p["flight-recorder-size"]))
-        # kernel flight deck (ISSUE 15): sampling rate, HBM roof, and
+        # kernel flight deck (ISSUE 15): sampling rate and
         # regression-sentry tuning are runtime-adjustable — raising the
         # sample rate during an incident must not require a restart
         devicewatch.KERNEL_TIMER.configure(
             sample_1_in=p.get("kernel-sample-1-in"),
-            hbm_roof_bytes_per_s=p.get("hbm-roof-bytes-per-s"),
             regression_factor=p.get("kernel-regression-factor"),
             regression_window_s=p.get("kernel-regression-window-s"),
             baseline_min_samples=p.get("kernel-baseline-min-samples"))
@@ -1002,8 +1020,6 @@ class FiloHttpServer:
                 "devicewatch-enabled": devicewatch.enabled(),
                 "kernel-sample-1-in":
                     devicewatch.KERNEL_TIMER.sample_1_in,
-                "hbm-roof-bytes-per-s":
-                    devicewatch.KERNEL_TIMER.hbm_roof_bytes_per_s,
                 "kernel-regression-factor":
                     devicewatch.KERNEL_TIMER.regression_factor,
                 "kernel-regression-window-s":
@@ -1302,12 +1318,15 @@ class FiloHttpServer:
     def _stats_wanted(p: dict) -> bool:
         return str(p.get("stats", "")).lower() in ("true", "1", "all")
 
-    def _finish_query(self, result, trace_id: str, body: dict, p: dict,
-                      ser_s: float) -> dict:
-        """Attach data.stats (Prometheus stats=true shape) to a query
-        response and round off the serialize bucket."""
+    def _finish_query(self, result, trace_id: str, p: dict, build) -> dict:
+        """Build the response body under the ``serialize`` stage (the
+        span is the bucket's source, in the query's own trace) and
+        attach data.stats (Prometheus stats=true shape)."""
+        with TRACER.attach(self._answered.tok), \
+                TRACER.stage("serialize") as ser:
+            body = build()
         if self._stats_wanted(p):
-            result.stats.add_timing("serialize", ser_s)
+            result.stats.add_timing("serialize", ser.duration_s)
             body["data"]["stats"] = stats_payload(result.stats, trace_id)
         return body
 
@@ -1319,10 +1338,9 @@ class FiloHttpServer:
         step = parse_duration_ms(p.get("step", "15s"))
         plan = query_range_to_logical_plan(query, start, step, end)
         result, trace_id = self._exec(b, plan, query=query, params=p)
-        t0 = time.perf_counter()
-        body = to_prom_matrix(result, b.metric_column)
-        return 200, self._finish_query(result, trace_id, body, p,
-                                       time.perf_counter() - t0)
+        return 200, self._finish_query(
+            result, trace_id, p,
+            lambda: to_prom_matrix(result, b.metric_column))
 
     @_timed("query")
     def _query_instant(self, b: DatasetBinding, p: dict) -> tuple[int, dict]:
@@ -1333,10 +1351,9 @@ class FiloHttpServer:
             else int(_time.time() * 1000)
         plan = query_to_logical_plan(query, time_ms)
         result, trace_id = self._exec(b, plan, query=query, params=p)
-        t0 = time.perf_counter()
-        body = to_prom_vector(result, time_ms, b.metric_column)
-        return 200, self._finish_query(result, trace_id, body, p,
-                                       time.perf_counter() - t0)
+        return 200, self._finish_query(
+            result, trace_id, p,
+            lambda: to_prom_vector(result, time_ms, b.metric_column))
 
     @staticmethod
     def _query_context(p: dict) -> QueryContext:
@@ -1423,9 +1440,11 @@ class FiloHttpServer:
             # scheduler's queue-wait/run spans and the exec tree all
             # parent under it, so /admin/traces shows a single tree
             with TRACER.attach((qctx.trace_id, None)), \
-                    TRACER.span("query", dataset=b.dataset, query=query):
-                t_plan = _time.perf_counter()
-                with TRACER.span("query.plan"):
+                    TRACER.span("query", dataset=b.dataset,
+                                query=query) as root:
+                self._answered.tok = (qctx.trace_id, root.span_id)
+                # the span is the plan bucket's source: no second clock
+                with TRACER.stage("query.plan") as plan_span:
                     ep = b.planner.materialize(plan, qctx)
                 if qctx.downsample_pixels:
                     # ?downsample=<pixels>: M4 decimation at the exec
@@ -1438,7 +1457,6 @@ class FiloHttpServer:
                     ep.add_transformer(
                         DownsampleMapper(pixels=qctx.downsample_pixels))
                     downsample_metrics()["queries"].inc(dataset=b.dataset)
-                plan_s = _time.perf_counter() - t_plan
                 if not qctx.tenant:
                     from filodb_tpu.workload.admission import plan_tenant
                     qctx.tenant = plan_tenant(ep)
@@ -1498,7 +1516,7 @@ class FiloHttpServer:
                                 sp.tag(resultcache="hit" if not rc_r
                                        else ("partial" if rc_c
                                              else "miss"))
-                    res.stats.add_timing("plan", plan_s)
+                    res.stats.add_timing("plan", plan_span.duration_s)
                     # queue = scheduler wait ONLY (t_submit is stamped
                     # right before submission below): planning and
                     # admission run on the entry thread and must not
@@ -1519,6 +1537,7 @@ class FiloHttpServer:
             FLIGHT.record("query.end", trace_id=qctx.trace_id,
                           dataset=b.dataset, error=repr(e)[:200],
                           seconds=round(fail_s, 6))
+            TRACER.flush()
             TRACE_STORE.note_complete(qctx.trace_id, fail_s,
                                       query=query, dataset=b.dataset,
                                       error=repr(e))
@@ -1529,6 +1548,9 @@ class FiloHttpServer:
         result.stats.timings.setdefault("total", total_s)
         FLIGHT.record("query.end", trace_id=qctx.trace_id,
                       dataset=b.dataset, seconds=round(total_s, 6))
+        # the entry thread's share of the trace, the root span too: the
+        # trace is whole in the store before the answer leaves
+        TRACER.flush()
         TRACE_STORE.note_complete(qctx.trace_id, total_s, query=query,
                                   dataset=b.dataset)
         self._note_insight(b, ins, ins_keys, qctx, query, total_s,

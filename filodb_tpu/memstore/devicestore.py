@@ -50,6 +50,7 @@ from filodb_tpu.ops.grid import (DENSE_ONLY_OPS, PHASE_OPS, TS_FREE_OPS,
 from filodb_tpu.query.logical import RangeFunctionId as F
 from filodb_tpu.utils import devicewatch
 from filodb_tpu.utils.devicewatch import FLIGHT, LEDGER
+from filodb_tpu.utils.observability import TRACER
 
 BLOCK_BUCKETS = 128
 _LANE_PAD = 128
@@ -258,12 +259,23 @@ def _fused_progs():
     from filodb_tpu.ops.grid import (rate_grid_auto, rate_grid_batch_impl,
                                      rate_grid_packed)
 
+    # jax.named_scope on the programs' stages (decode, window, reduce):
+    # names on the HLO's op_name metadata only, nothing computed changes
     def _concat(parts, decode):
         if not parts:
             return None    # phase mode: no ts plane in the program
-        segs = [decode(s) for s in parts]
-        return segs[0] if len(segs) == 1 \
-            else jnp.concatenate(segs, axis=0)
+        with jax.named_scope("decode"):
+            segs = [decode(s) for s in parts]
+            return segs[0] if len(segs) == 1 \
+                else jnp.concatenate(segs, axis=0)
+
+    def _window(fn, *a, **kw):
+        with jax.named_scope("window"):
+            return fn(*a, **kw)
+
+    def _reduce(stepped, garr, num_groups, op):
+        with jax.named_scope("reduce"):
+            return _grouped_reduce_impl(stepped, garr, num_groups, op)
 
     def _sliced(parts, row0, nrows, decode):
         all_ = _concat(parts, decode)
@@ -277,7 +289,8 @@ def _fused_progs():
                     q, lanes, nrows):
         ts_sl = _sliced(ts_parts, row0, nrows, _seg_ts_device)
         val_sl = _sliced(val_parts, row0, nrows, _seg_vals_device)
-        return rate_grid_auto(ts_sl, val_sl, steps0, q, lanes, phase=phase)
+        return _window(rate_grid_auto, ts_sl, val_sl, steps0, q, lanes,
+                       phase=phase)
 
     @functools.partial(devicewatch.jit, program="devicestore.grouped",
                        static_argnames=("q", "lanes", "nrows",
@@ -286,9 +299,9 @@ def _fused_progs():
                      *, q, lanes, nrows, num_groups, op):
         ts_sl = _sliced(ts_parts, row0, nrows, _seg_ts_device)
         val_sl = _sliced(val_parts, row0, nrows, _seg_vals_device)
-        stepped = rate_grid_auto(ts_sl, val_sl, steps0, q, lanes,
-                                 phase=phase)
-        return _grouped_reduce_impl(stepped, garr, num_groups, op)
+        stepped = _window(rate_grid_auto, ts_sl, val_sl, steps0, q, lanes,
+                          phase=phase)
+        return _reduce(stepped, garr, num_groups, op)
 
     # fused compressed-resident programs (ISSUE 3 tentpole): the XOR-
     # class decode runs INSIDE the grid kernel, so HBM serves the
@@ -301,8 +314,8 @@ def _fused_progs():
                                         "interpret"))
     def series_prog_packed(packed, steps0, *, q, row0, use_phase,
                            interpret=False):
-        return rate_grid_packed(packed, steps0, q, row0=row0,
-                                interpret=interpret, use_phase=use_phase)
+        return _window(rate_grid_packed, packed, steps0, q, row0=row0,
+                       interpret=interpret, use_phase=use_phase)
 
     @functools.partial(devicewatch.jit,
                        program="devicestore.grouped_packed",
@@ -310,10 +323,9 @@ def _fused_progs():
                                         "num_groups", "op", "interpret"))
     def grouped_prog_packed(packed, steps0, garr, *, q, row0, use_phase,
                             num_groups, op, interpret=False):
-        stepped = rate_grid_packed(packed, steps0, q, row0=row0,
-                                   interpret=interpret,
-                                   use_phase=use_phase)
-        return _grouped_reduce_impl(stepped, garr, num_groups, op)
+        stepped = _window(rate_grid_packed, packed, steps0, q, row0=row0,
+                          interpret=interpret, use_phase=use_phase)
+        return _reduce(stepped, garr, num_groups, op)
 
     # fleet-batched programs (ISSUE 20): B shape-compatible queries
     # against the SAME resident planes — decode + concat happen ONCE,
@@ -333,8 +345,8 @@ def _fused_progs():
         val_b = jax.vmap(
             lambda r: lax.dynamic_slice_in_dim(val_all, r, nrows,
                                                axis=0))(row0s)
-        return rate_grid_batch_impl(ts_b, val_b, steps0s, q, lanes,
-                                    phase=phase)
+        return _window(rate_grid_batch_impl, ts_b, val_b, steps0s, q,
+                       lanes, phase=phase)
 
     @functools.partial(devicewatch.jit,
                        program="devicestore.grouped_batch",
@@ -350,18 +362,45 @@ def _fused_progs():
             ts_sl = None if ts_all is None else \
                 lax.dynamic_slice_in_dim(ts_all, r, nrows, axis=0)
             val_sl = lax.dynamic_slice_in_dim(val_all, r, nrows, axis=0)
-            stepped = rate_grid_auto(ts_sl, val_sl, s, q, lanes,
-                                     phase=phase)
-            return _grouped_reduce_impl(stepped, garr, num_groups, op)
+            stepped = _window(rate_grid_auto, ts_sl, val_sl, s, q, lanes,
+                              phase=phase)
+            return _reduce(stepped, garr, num_groups, op)
         return jax.vmap(one)(row0s, steps0s)
 
-    _FUSED_PROGS["series"] = series_prog
-    _FUSED_PROGS["grouped"] = grouped_prog
-    _FUSED_PROGS["series_packed"] = series_prog_packed
-    _FUSED_PROGS["grouped_packed"] = grouped_prog_packed
-    _FUSED_PROGS["series_batch"] = series_batch_prog
-    _FUSED_PROGS["grouped_batch"] = grouped_batch_prog
+    def staged(prog):
+        """``prog`` as the ``grid.dispatch`` stage: the call until it
+        returns is the host work inside the jit call (operand handling,
+        enqueue).  On a launch the kernel timer samples (1 in 64) the
+        wrapper waits for the device inside it too."""
+        name = getattr(prog, "_program", "")
+
+        @functools.wraps(prog)       # keeps _program and _jitted
+        def launch(*a, **kw):
+            with TRACER.stage("grid.dispatch", program=name):
+                return prog(*a, **kw)
+        return launch
+
+    _FUSED_PROGS["series"] = staged(series_prog)
+    _FUSED_PROGS["grouped"] = staged(grouped_prog)
+    _FUSED_PROGS["series_packed"] = staged(series_prog_packed)
+    _FUSED_PROGS["grouped_packed"] = staged(grouped_prog_packed)
+    _FUSED_PROGS["series_batch"] = staged(series_batch_prog)
+    _FUSED_PROGS["grouped_batch"] = staged(grouped_batch_prog)
     return _FUSED_PROGS
+
+
+def _fetch(out, dtype=None) -> np.ndarray:
+    """Wait for a launch's result and copy it to the host, as the
+    ``grid.device_wait`` and ``grid.readback`` stages.  ``np.asarray``
+    blocks on the device anyway: the explicit wait adds no sync, it
+    only tells the device's time from the copy's."""
+    import jax
+    with TRACER.stage("grid.device_wait"):
+        jax.block_until_ready(out)
+    with TRACER.stage("grid.readback") as sp:
+        host = np.asarray(out, dtype=dtype)
+        sp.tag(bytes=int(host.nbytes))
+    return host
 
 
 def _run_packed(dispatch):
@@ -768,10 +807,11 @@ class DeviceGridCache:
             return None
         if len(fargs) != _ARG_OPS.get(_GRID_OPS[func], 0):
             return None        # unexpected / missing function argument
+        waited = TRACER.stage("grid.lock_wait", leaf=False).begin()
         with self._lock:
-            plan = self._plan_locked(  # filolint: disable=blocking-under-lock — staging under the grid lock is the design: one query stages the block, contenders reuse it instead of duplicating the HBM upload; the breaker bounds pathological re-staging
-                part_ids, func, steps0, nsteps,
-                step_ms, window_ms, fargs)
+            waited.end()
+            plan = self._plan_staged(  # filolint: disable=blocking-under-lock — staging under the grid lock is the design: one query stages the block, contenders reuse it instead of duplicating the HBM upload; the breaker bounds pathological re-staging
+                part_ids, func, steps0, nsteps, step_ms, window_ms, fargs)
             if plan is None:
                 return None
             _note_hbm(plan)
@@ -805,10 +845,11 @@ class DeviceGridCache:
             return None        # re-based ops skip the fused reduce
         if len(fargs) != _ARG_OPS.get(_GRID_OPS[func], 0):
             return None        # unexpected / missing function argument
+        waited = TRACER.stage("grid.lock_wait", leaf=False).begin()
         with self._lock:
-            plan = self._plan_locked(  # filolint: disable=blocking-under-lock — staging under the grid lock is the design: one query stages the block, contenders reuse it instead of duplicating the HBM upload; the breaker bounds pathological re-staging
-                part_ids, func, steps0, nsteps,
-                step_ms, window_ms, fargs)
+            waited.end()
+            plan = self._plan_staged(  # filolint: disable=blocking-under-lock — staging under the grid lock is the design: one query stages the block, contenders reuse it instead of duplicating the HBM upload; the breaker bounds pathological re-staging
+                part_ids, func, steps0, nsteps, step_ms, window_ms, fargs)
             if plan is None:
                 return None
             stride = self.hb if self.hist else 1
@@ -829,7 +870,7 @@ class DeviceGridCache:
                 garr, plan.phase, q=plan.q, lanes=plan.lane_mult,
                 nrows=plan.nrows, num_groups=num_groups * stride, op=op)
             _note_kernel_bytes(_fused_progs()["grouped"], plan)
-            return np.asarray(o, dtype=np.float64)  # host-sync-ok: ONE blocked readback of the reduced partials
+            return _fetch(o, np.float64)  # host-sync-ok: ONE blocked readback of the reduced partials
 
         both = None
         if plan.packed is not None and not _PACKED_BROKEN:
@@ -847,24 +888,26 @@ class DeviceGridCache:
                     interpret=_PACKED_INTERPRET))
             if out is not None:
                 _note_kernel_bytes(_fused_progs()["grouped_packed"], plan)
-                both = np.asarray(out, dtype=np.float64)  # host-sync-ok: the one designed readback of the fused reduce
+                both = _fetch(out, np.float64)  # host-sync-ok: the one designed readback of the fused reduce
         if both is None and not self.hist:
             both = self._batched_grouped(plan, garr,
                                          num_groups * stride, op,
                                          grouped_solo)
         if both is None:
             both = grouped_solo()
-        if self.hist:
-            # both: [2, G*hb, T] hist planes
-            return hist_state_from_planes(both, num_groups, stride, tops)
-        if op in ("sum", "avg", "count", "moments"):
-            if op == "count":
-                return {"count": both[1]}
-            if op == "moments":
-                return {"sum": both[0], "count": both[1],
-                        "sumsq": both[2]}
-            return {"sum": both[0], "count": both[1]}
-        return {op: both}
+        with TRACER.stage("grid.select"):
+            if self.hist:
+                # both: [2, G*hb, T] hist planes
+                return hist_state_from_planes(both, num_groups, stride,
+                                              tops)
+            if op in ("sum", "avg", "count", "moments"):
+                if op == "count":
+                    return {"count": both[1]}
+                if op == "moments":
+                    return {"sum": both[0], "count": both[1],
+                            "sumsq": both[2]}
+                return {"sum": both[0], "count": both[1]}
+            return {op: both}
 
     def _batched_grouped(self, plan, garr, num_groups, op, grouped_solo):
         """Offer a fused grouped reduce to the fleet batching tier.
@@ -890,7 +933,7 @@ class DeviceGridCache:
                 plan.phase, q=plan.q, lanes=plan.lane_mult,
                 nrows=plan.nrows, num_groups=num_groups, op=op)
             _note_kernel_bytes(prog, plan)
-            return np.asarray(out, dtype=np.float64)  # host-sync-ok: ONE stacked readback of the group's reduced partials
+            return _fetch(out, np.float64)  # host-sync-ok: ONE stacked readback of the group's reduced partials
 
         return batcher.dispatch(key, plan.row0, plan.steps0_rel, qctx,
                                 batch_launch, grouped_solo)
@@ -915,10 +958,11 @@ class DeviceGridCache:
         op = _GRID_OPS[func]
         if op in _REBASE_OPS or len(fargs) != _ARG_OPS.get(op, 0):
             return None
+        waited = TRACER.stage("grid.lock_wait", leaf=False).begin()
         with self._lock:
-            plan = self._plan_locked(  # filolint: disable=blocking-under-lock — staging under the grid lock is the design: one query stages the block, contenders reuse it instead of duplicating the HBM upload; the breaker bounds pathological re-staging
-                part_ids, func, steps0, nsteps,
-                step_ms, window_ms, fargs)
+            waited.end()
+            plan = self._plan_staged(  # filolint: disable=blocking-under-lock — staging under the grid lock is the design: one query stages the block, contenders reuse it instead of duplicating the HBM upload; the breaker bounds pathological re-staging
+                part_ids, func, steps0, nsteps, step_ms, window_ms, fargs)
             if plan is None or not plan.segs:
                 return None
             _note_hbm(plan)
@@ -967,6 +1011,21 @@ class DeviceGridCache:
                                  self._shard.grid_device, hb=hb,
                                  bucket_tops=tops, col_pids=col_pids)
 
+    def _plan_staged(self, part_ids, func, steps0, nsteps, step_ms,  # holds-lock: _lock
+                     window_ms, fargs):
+        """``_plan_locked`` as the ``grid.plan`` stage: lane and group
+        resolution (``_prep_for``), block assembly and, on a cold range,
+        the builds (``grid.build``) under the grid lock.  The wait for
+        that lock is the caller's ``grid.lock_wait`` stage: what a
+        worker loses to the other workers' plans."""
+        with TRACER.stage("grid.plan", cpu=True,
+                          lanes_requested=len(part_ids)) as sp:
+            plan = self._plan_locked(part_ids, func, steps0, nsteps,
+                                     step_ms, window_ms, fargs)
+            if plan is not None:
+                sp.tag(lanes=plan.ncols)
+            return plan
+
     def _series_solo(self, plan):
         """Today's per-query series launch + readback: the unchanged
         chain every batching fallback demotes to (bit-identical by
@@ -976,7 +1035,7 @@ class DeviceGridCache:
             plan.phase, q=plan.q, lanes=plan.lane_mult,
             nrows=plan.nrows)
         _note_kernel_bytes(_fused_progs()["series"], plan)
-        return np.asarray(stepped)  # host-sync-ok: the designed stepped readback — only [T, lanes] crosses the host link
+        return _fetch(stepped)  # host-sync-ok: the designed stepped readback — only [T, lanes] crosses the host link
 
     def _batched_series(self, plan):
         """Offer this dispatch to the fleet batching tier (ISSUE 20).
@@ -1007,7 +1066,7 @@ class DeviceGridCache:
                 plan.phase, q=plan.q, lanes=plan.lane_mult,
                 nrows=plan.nrows)
             _note_kernel_bytes(prog, plan)
-            return np.asarray(out)  # host-sync-ok: ONE stacked [B, T, lanes] readback serves the whole co-arrival group
+            return _fetch(out)  # host-sync-ok: ONE stacked [B, T, lanes] readback serves the whole co-arrival group
 
         return batcher.dispatch(key, plan.row0, plan.steps0_rel, qctx,
                                 batch_launch, lambda: self._series_solo(plan))
@@ -1029,11 +1088,18 @@ class DeviceGridCache:
                     # packed lane order: compose request map with inv
                     lanes_req = plan.packed_inv[plan.lane_idx]
                 _note_kernel_bytes(_fused_progs()["series_packed"], plan)
-                out_np = np.asarray(stepped)  # host-sync-ok: the designed stepped readback — only [T, lanes] crosses the host link
+                out_np = _fetch(stepped)  # host-sync-ok: the designed stepped readback — only [T, lanes] crosses the host link
         if out_np is None:
             out_np = self._batched_series(plan)
         if out_np is None:
             out_np = self._series_solo(plan)
+        with TRACER.stage("grid.select"):
+            return self._select_lanes(plan, out_np, lanes_req, used_packed)
+
+    def _select_lanes(self, plan, out_np, lanes_req, used_packed):
+        """Host lane selection: the requested series' columns of the
+        stepped ``[T, lanes]`` plane, and the re-base of the ops that
+        need one."""
         if self.hist:
             # COLUMN-granular indirection: a hist series' device columns
             # are lane*hb + bucket, so the pack's inv must compose with
@@ -1482,7 +1548,12 @@ class DeviceGridCache:
         return np.float64 if jax.config.jax_enable_x64 else np.float32
 
     def _build(self, bi: int, lanes: int, compress: bool = True):
-        """Host staging + one upload for block ``bi``."""
+        """Host staging + one upload for block ``bi``, as the
+        ``grid.build`` stage (most of a cold node's set-up)."""
+        with TRACER.stage("grid.build", lanes=lanes, compressed=compress):
+            return self._build_block(bi, lanes, compress)
+
+    def _build_block(self, bi: int, lanes: int, compress: bool):
         g = self.gstep
         stride = self.hb if self.hist else 1
         # block bi holds buckets [bi*BB, bi*BB+BB-1]; bucket c covers

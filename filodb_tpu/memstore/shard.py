@@ -38,6 +38,7 @@ from filodb_tpu.memstore.partition import TimeSeriesPartition
 from filodb_tpu.store.columnstore import ColumnStore, NullColumnStore, PartKeyRecord
 from filodb_tpu.store.metastore import InMemoryMetaStore, MetaStore
 from filodb_tpu.utils.bloom import BloomFilter
+from filodb_tpu.utils.observability import TRACER
 from filodb_tpu.workload.quota import SeriesQuotaExceeded
 
 
@@ -521,7 +522,6 @@ class TimeSeriesShard:
         Instrumented per ISSUE 2 (reference: Kamon spans around flush,
         TimeSeriesShard.scala:888-891): one span + the filodb_flush_*
         metrics per task; failures count before re-raising."""
-        from filodb_tpu.utils.observability import TRACER
         m = _flush_m()
         t0 = time.perf_counter()
         try:
@@ -941,6 +941,12 @@ class TimeSeriesShard:
                                  hist=(ctype == ColumnType.HISTOGRAM)), \
             part_ids
 
+    def _grid_resolve(self, part_ids: Sequence[int],
+                      column_id: Optional[int]):
+        """``_grid_cache_for`` as the ``grid.resolve`` stage."""
+        with TRACER.stage("grid.resolve"):
+            return self._grid_cache_for(part_ids, column_id)
+
     def scan_grid(self, part_ids: Sequence[int], func, steps0: int,
                   nsteps: int, step_ms: int, window_ms: int,
                   column_id: Optional[int] = None, fargs: tuple = ()):
@@ -952,7 +958,7 @@ class TimeSeriesShard:
         :meth:`scan_batch` + the general kernels.  This is the serving
         seam the reference places at block memory (queries read encoded
         chunks straight from BlockManager memory, never re-copying them)."""
-        got = self._grid_cache_for(part_ids, column_id)
+        got = self._grid_resolve(part_ids, column_id)
         if got is None:
             return None
         cache, ids = got
@@ -978,7 +984,7 @@ class TimeSeriesShard:
         aggregation happens on device, so only [G, T] partials come back
         (see DeviceGridCache.scan_rate_grouped).  Returns the mergeable
         state dict or None to fall back."""
-        got = self._grid_cache_for(part_ids, column_id)
+        got = self._grid_resolve(part_ids, column_id)
         if got is None:
             return None
         cache, ids = got
@@ -991,7 +997,7 @@ class TimeSeriesShard:
                        group_ids: Sequence[int], fargs: tuple = ()):
         """Device-resident staging for the SPMD mesh serving path
         (devicestore.mesh_plan); None -> host-batch mesh fallback."""
-        got = self._grid_cache_for(part_ids, None)
+        got = self._grid_resolve(part_ids, None)
         if got is None:
             return None
         cache, ids = got

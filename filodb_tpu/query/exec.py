@@ -12,7 +12,6 @@ from __future__ import annotations
 import concurrent.futures
 import dataclasses
 import threading
-import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -40,6 +39,15 @@ _ACTIVE = threading.local()
 
 def active_exec_ctx() -> Optional["ExecContext"]:
     return getattr(_ACTIVE, "ctx", None)
+
+
+# the steps of one grid call (doc/observability.md "Stage spans"): a
+# request the grid served carries all eight, 0.0 for a step its path
+# skipped, so that their means are over the same requests and add up
+# to device_compute's
+GRID_STAGES = ("grid.resolve", "grid.lock_wait", "grid.plan", "batch.wait",
+               "grid.dispatch", "grid.device_wait", "grid.readback",
+               "grid.select")
 
 
 @dataclasses.dataclass
@@ -86,8 +94,22 @@ class ExecContext:
             self._shards_down += n
 
     def note_timing(self, stage: str, seconds: float) -> None:
+        self.note_timings(((stage, seconds),))
+
+    def note_timings(self, walls) -> None:
+        """``(stage, seconds)`` pairs under one taking of the lock: the
+        ``scan`` stage hands over every stage that ended inside it."""
+        timings = self._timings
         with self._corrupt_lock:
-            self._timings[stage] = self._timings.get(stage, 0.0) + seconds
+            for stage, seconds in walls:
+                timings[stage] = timings.get(stage, 0.0) + seconds
+
+    def ensure_timings(self, stages: Sequence[str]) -> None:
+        """Every one of ``stages`` is a key of the timings, 0.0 where
+        nothing was noted under it."""
+        with self._corrupt_lock:
+            for stage in stages:
+                self._timings.setdefault(stage, 0.0)
 
     def note_counts(self, samples: int = 0, chunks: int = 0,
                     bytes_: int = 0, pages: int = 0,
@@ -434,26 +456,29 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         # the leaf owns the "scan" stage bucket; lower layers without a
         # ctx parameter (ODP page-in, predecode) attribute theirs
         # through the active-ctx thread-local installed here
-        t0 = time.perf_counter()
         prev = getattr(_ACTIVE, "ctx", None)
         _ACTIVE.ctx = ctx
         try:
-            shard = ctx.memstore.get_shard(self.dataset, self.shard)
-            lookup = shard.lookup_partitions(self.filters, self.start_ms,
-                                             self.end_ms)
-            if self.reshard_to is not None:
-                lookup = shard.filter_resharded(lookup, *self.reshard_to)
-            try:
-                batches = self._do_scan(ctx, shard, lookup)
-                self._note_batch_counts(ctx, batches)
-                return batches
-            finally:
-                # AFTER the scan, so corruption detected by this very
-                # query already counts toward its own partial-data warning
-                self._note_quarantined(ctx, shard, lookup.part_ids)
+            # ... and the stage spans that end inside the scan land in
+            # this query's timings under their own names
+            with TRACER.stage("scan", leaf=False, timings=ctx):
+                shard = ctx.memstore.get_shard(self.dataset, self.shard)
+                lookup = shard.lookup_partitions(
+                    self.filters, self.start_ms, self.end_ms)
+                if self.reshard_to is not None:
+                    lookup = shard.filter_resharded(lookup,
+                                                    *self.reshard_to)
+                try:
+                    batches = self._do_scan(ctx, shard, lookup)
+                    self._note_batch_counts(ctx, batches)
+                    return batches
+                finally:
+                    # AFTER the scan, so corruption detected by this
+                    # very query already counts toward its own
+                    # partial-data warning
+                    self._note_quarantined(ctx, shard, lookup.part_ids)
         finally:
             _ACTIVE.ctx = prev
-            ctx.note_timing("scan", time.perf_counter() - t0)
 
     @staticmethod
     def _note_batch_counts(ctx: ExecContext, batches) -> None:
@@ -474,16 +499,14 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
 
     @staticmethod
     def _grid_timed(fn, *args, **kw):
-        """Run a device-grid serving call, attributing its wall time to
-        the active query's device_compute stage bucket."""
+        """Run a device-grid serving call under the ``device_compute``
+        stage: the enclosing bucket that the grid's own stage spans
+        (``GRID_STAGES``) split."""
         ctx = active_exec_ctx()
-        if ctx is None:
+        if ctx is not None:
+            ctx.ensure_timings(GRID_STAGES)
+        with TRACER.stage("device_compute", leaf=False, cpu=True):
             return fn(*args, **kw)
-        t0 = time.perf_counter()
-        try:
-            return fn(*args, **kw)
-        finally:
-            ctx.note_timing("device_compute", time.perf_counter() - t0)
 
     def _do_scan(self, ctx: ExecContext, shard, lookup) -> list:
         schema = None

@@ -141,7 +141,7 @@ class QueryScheduler:
                 # any thread — report it parented on the submitter's span
                 TRACER.record("scheduler.queue_wait", waited,
                               trace_id=token[0], parent_id=token[1],
-                              scheduler=self.name)
+                              stage=True, scheduler=self.name)
             if deadline_ms and time.time() * 1000.0 > deadline_ms:
                 # ISSUE 5 satellite: the submit-time deadline expired
                 # while queued — the caller (local client or upstream
@@ -178,7 +178,8 @@ class QueryScheduler:
             t_run = time.monotonic()
             try:
                 with TRACER.attach(token), \
-                        TRACER.span("scheduler.run", scheduler=self.name):
+                        TRACER.stage("scheduler.run", leaf=False,
+                                     cpu=True, scheduler=self.name):
                     out = fn()
                 fut.set_result(out)
             except BaseException as e:  # noqa: BLE001 — surface via future

@@ -47,9 +47,9 @@ when.  This module is the device-side counterpart, three pillars:
    measured seconds into a per-program EWMA + streaming log-histogram.
    Joined with the per-plan ``hbm_read_bytes`` notes from devicestore,
    that yields a LIVE achieved-bytes/s per program against the
-   configured HBM roof (``hbm-roof-bytes-per-s``, default 819e9 — the
-   doc/kernel.md roofline, now measured on real traffic instead of
-   derived offline).  A regression sentry compares each program's EWMA
+   serving device's published HBM peak (``HBM_ROOF_BYTES_PER_S``, a
+   table keyed by jax's ``device_kind``; a device the table lacks gets
+   no roofline fraction rather than an assumed one).  A regression sentry compares each program's EWMA
    against a learned baseline (seeded after a quiet warmup, ratcheted
    DOWNWARD only, persisted in the metastore KV by the standalone
    server): sustained >= 1.5x degradation over a window fires ONE
@@ -139,7 +139,7 @@ def device_metrics() -> dict:
             "kernel_roofline": REGISTRY.gauge(
                 "filodb_kernel_roofline_fraction",
                 "live achieved HBM bytes/s per program as a fraction "
-                "of the configured roof (hbm-roof-bytes-per-s)"),
+                "of the device's published HBM peak"),
             "kernel_regressions": REGISTRY.counter(
                 "filodb_kernel_regressions_total",
                 "kernel-regression episodes: sustained EWMA device "
@@ -501,6 +501,32 @@ COMPILE_WATCH = CompileWatch()
 _KHIST_EDGES = tuple(2.0 ** i * 1e-6 for i in range(25))
 
 
+# published peak HBM bandwidth of one chip, bytes/s, keyed by jax's
+# ``device_kind`` (Google Cloud TPU documentation, system architecture
+# pages: v4 1228 GB/s, v5e 819 GB/s, v5p 2765 GB/s, v6e 1640 GB/s).
+# A device that is not here has no roof: /admin/kernels then leaves
+# ``roofline_fraction`` out instead of dividing by a guess.
+HBM_ROOF_BYTES_PER_S = {
+    "TPU v4": 1228e9,
+    "TPU v5 lite": 819e9,
+    "TPU v5": 2765e9,
+    "TPU v6 lite": 1640e9,
+}
+
+def device_kind() -> Optional[str]:
+    """``device_kind`` of the first device (None where jax cannot say)."""
+    try:
+        import jax
+        return jax.devices()[0].device_kind
+    except Exception:  # noqa: BLE001 — host-only deployment
+        return None
+
+
+def hbm_roof() -> Optional[float]:
+    """The serving device's HBM peak from the table, or None."""
+    return HBM_ROOF_BYTES_PER_S.get(device_kind())
+
+
 class KernelTimer:
     """Per-program device-time ledger, sampled (ISSUE 15).
 
@@ -533,13 +559,11 @@ class KernelTimer:
     """
 
     def __init__(self, sample_1_in: int = 64,
-                 hbm_roof_bytes_per_s: float = 819e9,
                  regression_factor: float = 1.5,
                  regression_window_s: float = 30.0,
                  baseline_min_samples: int = 8,
                  ewma_alpha: float = 0.25):
         self.sample_1_in = int(sample_1_in)
-        self.hbm_roof_bytes_per_s = float(hbm_roof_bytes_per_s)
         self.regression_factor = float(regression_factor)
         self.regression_window_s = float(regression_window_s)
         self.baseline_min_samples = int(baseline_min_samples)
@@ -551,7 +575,6 @@ class KernelTimer:
         self._baseline_save: Optional[Callable[[str, float], None]] = None
 
     def configure(self, sample_1_in: Optional[int] = None,
-                  hbm_roof_bytes_per_s: Optional[float] = None,
                   regression_factor: Optional[float] = None,
                   regression_window_s: Optional[float] = None,
                   baseline_min_samples: Optional[int] = None) -> None:
@@ -559,9 +582,6 @@ class KernelTimer:
             if sample_1_in is not None:
                 # 0 disables sampling entirely; 1 = time every launch
                 self.sample_1_in = max(0, int(sample_1_in))
-            if hbm_roof_bytes_per_s is not None:
-                self.hbm_roof_bytes_per_s = max(1.0,
-                                                float(hbm_roof_bytes_per_s))
             if regression_factor is not None:
                 self.regression_factor = max(1.01,
                                              float(regression_factor))
@@ -717,10 +737,10 @@ class KernelTimer:
             save = self._baseline_save
         m = device_metrics()
         m["kernel_seconds"].inc(dt, program=program)
-        if bytes_total and launches and ew > 0:
+        roof = hbm_roof()
+        if roof and bytes_total and launches and ew > 0:
             m["kernel_roofline"].set(
-                (bytes_total / launches) / ew / self.hbm_roof_bytes_per_s,
-                program=program)
+                (bytes_total / launches) / ew / roof, program=program)
         if persist is not None:
             # seeding also exports the regressed=0 level row so the
             # alert rules see the healthy state before any episode.
@@ -770,7 +790,7 @@ class KernelTimer:
 
     def table(self) -> list[dict]:
         """The /admin/kernels ledger rows, most-launched first."""
-        roof = self.hbm_roof_bytes_per_s
+        roof = hbm_roof()
         with self._lock:
             rows = []
             for program, r in self._rows.items():
@@ -778,7 +798,7 @@ class KernelTimer:
                 achieved = None
                 if r["bytes"] and r["launches"] and ew:
                     achieved = (r["bytes"] / r["launches"]) / ew
-                rows.append({
+                row = {
                     "program": program,
                     "launches": r["launches"],
                     "sampled": r["sampled"],
@@ -787,8 +807,6 @@ class KernelTimer:
                     else None,
                     "bytes_total": r["bytes"],
                     "achieved_bytes_per_s": round(achieved, 1)
-                    if achieved is not None else None,
-                    "roofline_fraction": round(achieved / roof, 6)
                     if achieved is not None else None,
                     "baseline_s": round(r["baseline"], 6)
                     if r["baseline"] is not None else None,
@@ -799,7 +817,11 @@ class KernelTimer:
                         ("+Inf" if i == len(_KHIST_EDGES)
                          else repr(_KHIST_EDGES[i])): n
                         for i, n in enumerate(r["hist"]) if n},
-                })
+                }
+                if roof:
+                    row["roofline_fraction"] = round(achieved / roof, 6) \
+                        if achieved is not None else None
+                rows.append(row)
         rows.sort(key=lambda r: -r["launches"])
         return rows
 
@@ -822,7 +844,8 @@ def kernel_summary() -> dict:
     return {
         "enabled": _ENABLED,
         "sample_1_in": KERNEL_TIMER.sample_1_in,
-        "hbm_roof_bytes_per_s": KERNEL_TIMER.hbm_roof_bytes_per_s,
+        "device_kind": device_kind(),
+        "hbm_roof_bytes_per_s": hbm_roof(),
         "regression": {
             "factor": KERNEL_TIMER.regression_factor,
             "window_s": KERNEL_TIMER.regression_window_s,
@@ -1036,7 +1059,6 @@ def configure(conf: Optional[dict] = None) -> None:
     ``{"enabled": bool, "flight-recorder-size": int,
     "jit-storm-shapes": int, "jit-storm-window-s": float,
     "kernel-sample-1-in": int (0 disables sampling),
-    "hbm-roof-bytes-per-s": float,
     "kernel-regression-factor": float,
     "kernel-regression-window-s": float,
     "kernel-baseline-min-samples": int}``."""
@@ -1051,7 +1073,6 @@ def configure(conf: Optional[dict] = None) -> None:
         storm_window_s=conf.get("jit-storm-window-s"))
     KERNEL_TIMER.configure(
         sample_1_in=conf.get("kernel-sample-1-in"),
-        hbm_roof_bytes_per_s=conf.get("hbm-roof-bytes-per-s"),
         regression_factor=conf.get("kernel-regression-factor"),
         regression_window_s=conf.get("kernel-regression-window-s"),
         baseline_min_samples=conf.get("kernel-baseline-min-samples"))
@@ -1066,6 +1087,7 @@ def device_summary() -> dict:
     """The process-wide device-resource view: ledger tree, pools,
     per-device reconciliation, compile table, storm state.  The HTTP
     layer adds per-dataset arena budgets (it owns the bindings)."""
+    from filodb_tpu.utils.observability import TRACER
     return {
         "enabled": _ENABLED,
         "ledger": {
@@ -1081,4 +1103,7 @@ def device_summary() -> dict:
             "storm_window_s": COMPILE_WATCH.storm_window_s,
         },
         "flight_recorder": {"capacity": FLIGHT.capacity},
+        # the stage clock's process-wide totals since start
+        # ({count, wall_s, cpu_s} per stage span name)
+        "stages": TRACER.stages.snapshot(),
     }

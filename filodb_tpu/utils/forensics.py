@@ -15,7 +15,7 @@ import collections
 import random
 import threading
 import time
-from typing import Optional
+from typing import Optional, Sequence
 
 from filodb_tpu.utils.observability import (SpanRecord, TRACER,
                                             query_metrics)
@@ -24,7 +24,7 @@ from filodb_tpu.utils.observability import (SpanRecord, TRACER,
 def span_to_dict(rec: SpanRecord) -> dict:
     """JSON-safe span for the /execplan response and admin endpoints."""
     return {"name": rec.name, "start_s": rec.start_s,
-            "duration_s": rec.duration_s,
+            "duration_s": rec.duration_s, "cpu_s": rec.cpu_s,
             "tags": {k: str(v) for k, v in rec.tags.items()},
             "error": rec.error, "trace_id": rec.trace_id,
             "span_id": rec.span_id, "parent_id": rec.parent_id}
@@ -36,7 +36,8 @@ def span_from_dict(d: dict) -> SpanRecord:
                       dict(d.get("tags", {})), None,
                       error=d.get("error"), trace_id=d.get("trace_id"),
                       span_id=d.get("span_id", ""),
-                      parent_id=d.get("parent_id"))
+                      parent_id=d.get("parent_id"),
+                      cpu_s=float(d.get("cpu_s", 0.0)))
 
 
 class TraceStore:
@@ -69,18 +70,22 @@ class TraceStore:
 
     # -------------------------------------------------------------- writes
 
-    def report(self, rec: SpanRecord) -> None:
-        """TRACER reporter hook (exceptions are swallowed upstream)."""
-        if not rec.trace_id:
-            return
+    def report(self, recs: Sequence[SpanRecord]) -> None:
+        """TRACER reporter hook (exceptions are swallowed upstream): the
+        spans one thread finished since its last flush, under one taking
+        of the lock."""
+        traces = self._traces
         with self._lock:
-            spans = self._traces.get(rec.trace_id)
-            if spans is None:
-                spans = self._traces[rec.trace_id] = []
-                while len(self._traces) > self.max_traces:
-                    self._traces.popitem(last=False)
-            if len(spans) < self.max_spans_per_trace:
-                spans.append(rec)
+            for rec in recs:
+                if not rec.trace_id:
+                    continue
+                spans = traces.get(rec.trace_id)
+                if spans is None:
+                    spans = traces[rec.trace_id] = []
+                    while len(traces) > self.max_traces:
+                        traces.popitem(last=False)
+                if len(spans) < self.max_spans_per_trace:
+                    spans.append(rec)
 
     def ingest_remote(self, trace_id: str, spans: list[dict]) -> None:
         """Merge spans shipped back by a remote /execplan execution.
@@ -279,7 +284,14 @@ def device_profile(seconds: float = 2.0,
         path = tempfile.mkdtemp(
             prefix=time.strftime("trace-%Y%m%d-%H%M%S-"), dir=root)
         try:
-            profiler.start_trace(path)
+            # as benchmark/run.py starts it: the Python tracer off (it
+            # would slow the very threads it records), the host tracer
+            # at the level that keeps TraceMe events — the leaf stage
+            # spans (TRACER.stage) land on the capture's host plane
+            opts = profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            profiler.start_trace(path, profiler_options=opts)
         except Exception as e:  # noqa: BLE001 — backend refused
             raise DeviceProfilerUnavailable(
                 f"device trace capture failed to start: {e}") from e

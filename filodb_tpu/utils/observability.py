@@ -16,8 +16,8 @@ from __future__ import annotations
 
 import bisect
 import collections
-import contextlib
 import dataclasses
+import itertools
 import random
 import sys
 import threading
@@ -201,6 +201,11 @@ class MetricsRegistry:
                   buckets: Sequence[float] = _BUCKETS) -> Histogram:
         return self._get(name, lambda: Histogram(name, help_, buckets),
                          Histogram)
+
+    def collector(self, name: str, obj) -> None:
+        """Register anything with ``expose() -> list[str]`` under
+        ``name`` (a table that keeps its own totals)."""
+        self._get(name, lambda: obj, type(obj))
 
     def _get(self, name, ctor, cls):
         with self._lock:
@@ -932,14 +937,23 @@ class PeriodicThread:
 
 
 def _new_id() -> str:
-    """64-bit random hex id (span/trace ids on the wire)."""
+    """64-bit random hex id (trace ids on the wire)."""
     return f"{random.getrandbits(64):016x}"
+
+
+# span ids count up from a random start per process: as good as random
+# where nodes' spans meet in one trace, at a third of the price
+_SPAN_IDS = itertools.count(random.getrandbits(63))
+
+
+def _new_span_id() -> str:
+    return f"{next(_SPAN_IDS):016x}"
 
 
 @dataclasses.dataclass
 class SpanRecord:
     name: str
-    start_s: float
+    start_s: float                 # time.time() stamped when the span began
     duration_s: float
     tags: dict
     parent: Optional[str]          # parent span NAME (log reporters)
@@ -949,6 +963,89 @@ class SpanRecord:
     trace_id: Optional[str] = None
     span_id: str = ""
     parent_id: Optional[str] = None
+    # the thread's own CPU seconds inside the span (time.thread_time):
+    # wall less CPU is what the thread WAITED — for the GIL, the device,
+    # a lock, a socket.  Read by the stages that ask for it (the ones
+    # that enclose others, and grid.plan); 0.0 everywhere else: the
+    # clock is a system call, 15-30 us where a sandbox's kernel answers
+    # it, and the served path is short of exactly that.
+    cpu_s: float = 0.0
+
+
+class StageTable:
+    """Process-wide totals per stage name: ``{count, wall_s, cpu_s}``,
+    one lock, one dict.  Every *stage* span folds in here, so work
+    outside any request's body (encode, write, the collector, set-up's
+    block builds) has a number too.  Exposed as
+    ``filodb_stage_seconds_total{stage,kind}`` / ``filodb_stage_total
+    {stage}`` on /metrics and as the ``stages`` block of /admin/device."""
+
+    def __init__(self) -> None:
+        self._rows: dict[str, list] = {}
+        self._lock = threading.Lock()
+        # called before a read takes the lock: the tracer drains what a
+        # collector hook left for it (Tracer.defer)
+        self.before_read: Optional[Callable[[], None]] = None
+
+    def add_all(self, spans) -> None:
+        """Fold every span of ``spans`` in under one taking of the lock
+        (a thread hands over what it finished since its last flush)."""
+        rows = self._rows
+        with self._lock:
+            for sp in spans:
+                row = rows.get(sp.name)
+                if row is None:
+                    rows[sp.name] = [1, sp.duration_s, sp.cpu_s]
+                else:
+                    row[0] += 1
+                    row[1] += sp.duration_s
+                    row[2] += sp.cpu_s
+
+    def snapshot(self) -> dict:
+        if self.before_read is not None:
+            self.before_read()
+        with self._lock:
+            return {name: {"count": r[0], "wall_s": r[1], "cpu_s": r[2]}
+                    for name, r in sorted(self._rows.items())}
+
+    def expose(self) -> list[str]:
+        rows = self.snapshot()
+        out = ["# TYPE filodb_stage_seconds_total counter"]
+        for name, r in rows.items():
+            for kind in ("wall", "cpu"):
+                key = (("kind", kind), ("stage", name))
+                out.append(f"filodb_stage_seconds_total{_fmt_labels(key)} "
+                           f"{_fmt_val(r[kind + '_s'])}")
+        out.append("# TYPE filodb_stage_total counter")
+        for name, r in rows.items():
+            out.append(f"filodb_stage_total{_fmt_labels((('stage', name),))}"
+                       f" {r['count']}")
+        return out
+
+
+def _jax_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is missing:
+    a leaf stage entered through it lies on the host plane of whatever
+    profiler session is live, on the same clock as the ``XLA Ops``."""
+    try:
+        from jax.profiler import TraceAnnotation
+        return TraceAnnotation
+    except Exception:  # noqa: BLE001 — host-only deployment
+        return None
+
+
+class _ThreadState:
+    """What one thread carries: its open spans, the finished ones not
+    yet handed over, the trace context, and where stages' wall goes."""
+
+    __slots__ = ("stack", "depth", "done", "trace_id", "parent_hint",
+                 "timings")
+
+    def __init__(self) -> None:
+        self.stack: list = []
+        self.depth = 0          # open spans, across attached stacks too
+        self.done: list = []
+        self.trace_id = self.parent_hint = self.timings = None
 
 
 class Tracer:
@@ -960,37 +1057,62 @@ class Tracer:
     ``attach()`` move that context across thread pools (scheduler
     workers, scatter-gather child dispatch), and the dispatch layer
     moves it across processes via an HTTP header + execplan-wire field.
+
+    A span opened with :meth:`stage` is the served path's stage clock
+    (doc/observability.md "Stage spans"): it also folds into ``stages``
+    and into the ``timings`` of the query it ran for, and a *leaf* stage
+    is entered as a profiler annotation.
+
+    In place a span does the least it can: two clock reads, a push and
+    a pop.  A finished span then waits on its thread until the thread's
+    outermost span ends (or a ``scan`` stage, whose query reads its
+    timings next; or :meth:`flush` is called; or ``MAX_WAITING`` have
+    gathered), and :meth:`_flush` does the rest for all that waited, in
+    one loop: ids, records, the stage table, the timings, the
+    reporters.  Code that runs once a request runs cold, at several
+    times its price in a loop; the flush's loop runs warm, and takes
+    each lock once: two or three times a request on a thread.
     """
+
+    MAX_WAITING = 64
 
     def __init__(self) -> None:
         self._local = threading.local()
-        self._reporters: list[Callable[[SpanRecord], None]] = []
+        # replaced, never mutated: _flush reads it without the lock
+        self._reporters: tuple[Callable[[Sequence[SpanRecord]], None],
+                               ...] = ()
         self._lock = threading.Lock()
+        self.stages = StageTable()
+        self.stages.before_read = self.flush_deferred
+        # name -> context manager for leaf stages; resolved on first use
+        self._annotate = _jax_annotation
+        # synthetic spans left by code that may take no lock (defer)
+        self._deferred: collections.deque = collections.deque()
 
-    def add_reporter(self, fn: Callable[[SpanRecord], None]) -> None:
+    def add_reporter(self,
+                     fn: Callable[[Sequence[SpanRecord]], None]) -> None:
+        """``fn`` is handed the records of the spans a thread finished
+        since its last flush, oldest first."""
         with self._lock:
-            self._reporters.append(fn)
+            self._reporters = self._reporters + (fn,)
 
-    def remove_reporter(self, fn: Callable[[SpanRecord], None]) -> None:
-        with self._lock:
-            self._reporters = [r for r in self._reporters if r is not fn]
-
-    def clear_reporters(self) -> None:
-        with self._lock:
-            self._reporters = []
+    def _state(self) -> _ThreadState:
+        try:
+            return self._local.st
+        except AttributeError:
+            st = self._local.st = _ThreadState()
+            return st
 
     def current_span(self) -> Optional[str]:
-        stack = getattr(self._local, "stack", None)
-        return stack[-1][0] if stack else None
+        stack = self._state().stack
+        return stack[-1].name if stack else None
 
     def current_span_id(self) -> Optional[str]:
-        stack = getattr(self._local, "stack", None)
-        if stack:
-            return stack[-1][1]
-        return getattr(self._local, "parent_hint", None)
+        st = self._state()
+        return st.stack[-1].span_id if st.stack else st.parent_hint
 
     def current_trace_id(self) -> Optional[str]:
-        return getattr(self._local, "trace_id", None)
+        return self._state().trace_id
 
     @staticmethod
     def new_trace_id() -> str:
@@ -1000,105 +1122,356 @@ class Tracer:
         """(trace_id, span_id) token for cross-thread propagation."""
         return self.current_trace_id(), self.current_span_id()
 
-    @contextlib.contextmanager
     def attach(self, token):
         """Install a captured trace context on this thread: spans opened
         inside parent onto ``token``'s span id and carry its trace id.
         The span stack is swapped for a FRESH one — the context is
         foreign, so an unrelated span already open on this thread (e.g.
         a scheduler worker's own span) must not capture the parentage."""
-        tid, sid = token if token else (None, None)
-        old_tid = getattr(self._local, "trace_id", None)
-        old_hint = getattr(self._local, "parent_hint", None)
-        old_stack = getattr(self._local, "stack", None)
-        self._local.trace_id = tid
-        self._local.parent_hint = sid
-        self._local.stack = []
-        try:
-            yield
-        finally:
-            self._local.trace_id = old_tid
-            self._local.parent_hint = old_hint
-            self._local.stack = old_stack
+        return _Attached(self._state(), token)
 
     def span(self, name: str, **tags):
         return _Span(self, name, tags)
 
+    def stage(self, name: str, leaf: bool = True, cpu: bool = False,
+              timings=None, **tags):
+        """A span that is also a stage: its name is the key of the stage
+        table and of ``QueryStats.timings``.  ``leaf=False`` for a stage
+        that encloses other stages (``device_compute``, ``scan``,
+        ``http.request``, ``scheduler.run``) or only waits for another
+        thread (``grid.lock_wait``, a batch member's ``batch.wait``):
+        leaves alone are annotated on the profiler's host plane, because
+        an enclosing span would cover every device-idle gap and name
+        none, and a wait would name the symptom and not the work that
+        holds the host.  ``cpu=True`` reads the thread's CPU clock as
+        well (``SpanRecord.cpu_s``).  ``timings`` (anything with
+        ``note_timings``: a query's ``ExecContext``) takes the wall of
+        this stage and of every stage that ends inside it on its thread,
+        under their names."""
+        return _Span(self, name, tags, True, leaf, cpu, timings)
+
     def record(self, name: str, duration_s: float,
                trace_id: Optional[str] = None,
-               parent_id: Optional[str] = None, **tags) -> SpanRecord:
+               parent_id: Optional[str] = None, *,
+               start_s: Optional[float] = None, cpu_s: float = 0.0,
+               stage: bool = False, **tags) -> SpanRecord:
         """Report a synthetic span that did not run on this thread
-        (queue wait measured by a worker, a remote node's spans)."""
-        rec = SpanRecord(name, time.time() - duration_s, duration_s,
-                         tags, None, trace_id=trace_id, span_id=_new_id(),
-                         parent_id=parent_id)
-        self._report(rec)
+        (queue wait measured by a worker, a remote node's spans).
+        ``start_s`` is when it began on ``time.time()``'s clock; a
+        caller that only knows it just ended leaves it out.
+        ``stage=True`` folds it into the stage table as well."""
+        if start_s is None:
+            start_s = time.time() - duration_s
+        rec = SpanRecord(name, start_s, duration_s, tags, None,
+                         trace_id=trace_id, span_id=_new_span_id(),
+                         parent_id=parent_id, cpu_s=cpu_s)
+        if stage:
+            self.stages.add_all((rec,))
+        self._report((rec,))
         return rec
 
-    def _report(self, rec: SpanRecord) -> None:
-        with self._lock:
-            reporters = list(self._reporters)
-        for fn in reporters:
+    def defer(self, name: str, duration_s: float, **kw) -> None:
+        """:meth:`record` for a caller that may take no lock and call no
+        reporter: a ``gc.callbacks`` hook runs on whichever thread set
+        the collection off, at whatever point it had reached, and that
+        can be inside the stage table's lock or a reporter's.  The span
+        is reported by the next flush on any thread, or the next read of
+        the stage table."""
+        self._deferred.append((name, duration_s, kw))
+
+    def flush_deferred(self) -> None:
+        q = self._deferred
+        while q:
             try:
-                fn(rec)
+                name, duration_s, kw = q.popleft()
+            except IndexError:      # another thread took the last one
+                return
+            self.record(name, duration_s, **kw)
+
+    def _annotation_class(self):
+        ann = self._annotate
+        if ann is _jax_annotation:
+            ann = self._annotate = _jax_annotation()
+        return ann
+
+    def _leaf_annotation(self, name: str):
+        """An entered profiler annotation for ``name``, or None: jax
+        missing or the annotation failing degrades to no annotation,
+        never to an error on the request."""
+        try:
+            ann = self._annotation_class()
+            if ann is None:
+                return None
+            cm = ann(name)
+            cm.__enter__()
+            return cm
+        except Exception:  # noqa: BLE001 — tracing never fails the work
+            self._annotate = None
+            return None
+
+    def flush(self) -> None:
+        """Hand over what this thread has finished now: a reader of the
+        trace store calls it first, so that the trace it reads is whole
+        as far as this thread goes."""
+        st = self._state()
+        if st.done:
+            self._flush(st)
+
+    def _flush(self, st: _ThreadState) -> None:
+        """Hand over the spans this thread finished: the stages to the
+        table and to their queries' timings, a record of each to the
+        reporters."""
+        done, st.done = st.done, []
+        recs, stages = [], []
+        # spans keep perf_counter's reading; this is time.time() less it
+        epoch = time.time() - time.perf_counter()
+        for sp in done:
+            parent = sp.parent
+            if parent is None:
+                pname, pid = None, sp.parent_hint
+            else:
+                pname, pid = parent.name, parent.span_id
+            recs.append(SpanRecord(sp.name, epoch + sp._t0, sp.duration_s,
+                                   sp.tags, pname, sp.error, sp.trace_id,
+                                   sp.span_id, pid, sp.cpu_s))
+            if sp.stage:
+                stages.append(sp)
+        if stages:
+            self.stages.add_all(stages)
+            sink, walls = None, []
+            for sp in stages:       # runs of one query's stages
+                if sp.sink is not sink:
+                    if walls:
+                        sink.note_timings(walls)
+                    sink, walls = sp.sink, []
+                if sink is not None:
+                    walls.append((sp.name, sp.duration_s))
+            if walls:
+                sink.note_timings(walls)
+        if self._deferred:
+            self.flush_deferred()
+        self._report(recs)
+
+    def _report(self, recs: Sequence[SpanRecord]) -> None:
+        for fn in self._reporters:
+            try:
+                fn(recs)
             except Exception:  # noqa: BLE001 — reporters must not break work
                 traceback.print_exc()
 
 
+class _Attached:
+    """``Tracer.attach``'s context manager (a class: it is entered a few
+    times a request)."""
+
+    __slots__ = ("_st", "_new", "_old")
+
+    def __init__(self, st: _ThreadState, token):
+        self._st = st
+        self._new = token if token else (None, None)
+
+    def __enter__(self):
+        st = self._st
+        self._old = (st.trace_id, st.parent_hint, st.stack)
+        st.trace_id, st.parent_hint = self._new
+        st.stack = []
+
+    def __exit__(self, *exc):
+        st = self._st
+        st.trace_id, st.parent_hint, st.stack = self._old
+        return False
+
+
 class _Span:
-    def __init__(self, tracer: Tracer, name: str, tags: dict):
+    """One timed interval: wall (``perf_counter``) and, where asked for,
+    the thread's CPU (``thread_time``), started at ``start_s`` on
+    ``time.time()``'s clock.  ``duration_s`` / ``cpu_s`` stay readable
+    after exit, so a caller that owes a stats bucket takes it from the
+    span instead of timing the same interval again.  Its ``SpanRecord``
+    is made when the thread flushes (``Tracer._flush``), its start put
+    on ``time.time()``'s clock there."""
+
+    duration_s = cpu_s = 0.0
+    parent = None           # the span this one began inside, if any
+    parent_hint = None      # ... or the attached context's span id
+    error = trace_id = sink = None
+    _ann = _sid = None
+
+    def __init__(self, tracer: Tracer, name: str, tags: dict,
+                 stage: bool = False, leaf: bool = False,
+                 cpu: bool = False, timings=None):
         self.tracer = tracer
         self.name = name
         self.tags = tags
-        self.span_id = _new_id()
-        self._t0 = 0.0
+        self.stage = stage
+        self.leaf = stage and leaf
+        self.cpu = cpu
+        self.timings = timings
+
+    @property
+    def span_id(self) -> str:
+        """Made when first asked for: by a child's record, a capture
+        across threads, or this span's own record."""
+        sid = self._sid
+        if sid is None:
+            sid = self._sid = _new_span_id()
+        return sid
 
     def __enter__(self):
-        local = self.tracer._local
-        stack = getattr(local, "stack", None)
-        if stack is None:
-            stack = local.stack = []
+        tracer = self.tracer
+        try:
+            st = tracer._local.st
+        except AttributeError:
+            st = tracer._state()
+        self._st = st
+        stack = st.stack
         if stack:
-            self.parent, self.parent_id = stack[-1]
+            self.parent = stack[-1]
         else:
-            self.parent = None
-            self.parent_id = getattr(local, "parent_hint", None)
-        stack.append((self.name, self.span_id))
+            self.parent_hint = st.parent_hint
+        stack.append(self)
+        st.depth += 1
+        if self.timings is not None:
+            self._outer_timings, st.timings = st.timings, self.timings
+        if self.leaf:
+            self._ann = tracer._leaf_annotation(self.name)
         self._t0 = time.perf_counter()
+        if self.cpu:
+            self._c0 = time.thread_time()
         return self
 
     def tag(self, **tags):
         self.tags.update(tags)
         return self
 
+    # for an interval that no ``with`` block can delimit (the wait for
+    # a lock ends INSIDE the block that holds it)
+    begin = __enter__
+
+    def end(self) -> None:
+        self.__exit__(None, None, None)
+
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter() - self._t0
-        try:  # spans must NEVER raise into the instrumented path
-            self.tracer._local.stack.pop()
-        except (AttributeError, IndexError):
-            pass
-        self.tracer._report(SpanRecord(
-            self.name, time.time() - dur, dur, dict(self.tags), self.parent,
-            error=repr(exc) if exc is not None else None,
-            trace_id=self.tracer.current_trace_id(),
-            span_id=self.span_id, parent_id=self.parent_id))
+        self.duration_s = time.perf_counter() - self._t0
+        if self.cpu:
+            # not held to the wall: where the CPU clock ticks in steps
+            # (10 ms under gVisor) one span reads high or low by a tick,
+            # and only the sums over many spans mean anything
+            self.cpu_s = max(time.thread_time() - self._c0, 0.0)
+        if self._ann is not None:
+            try:  # spans must NEVER raise into the instrumented path
+                self._ann.__exit__(None, None, None)
+            except Exception:  # noqa: BLE001
+                pass
+            self._ann = None
+        if exc is not None:
+            self.error = repr(exc)
+        st = self._st
+        self.trace_id = st.trace_id
+        flush = False
+        if self.stage:
+            self.sink = st.timings
+            if self.timings is not None:
+                # its query reads the timings once the scan is done
+                st.timings, flush = self._outer_timings, True
+        if st.stack:
+            st.stack.pop()
+        done = st.done
+        done.append(self)
+        st.depth = depth = max(st.depth - 1, 0)
+        if flush or not depth or len(done) >= Tracer.MAX_WAITING:
+            self.tracer._flush(st)
         return False
 
 
 TRACER = Tracer()
+REGISTRY.collector("filodb_stage", TRACER.stages)
 
 
-def span_log_reporter(log: Callable[[str], None] = print,
-                      min_duration_s: float = 0.0):
-    """Span -> log line reporter (reference: KamonSpanLogReporter)."""
+class GcPauseWatch:
+    """``gc.callbacks`` hook: Python's collector stops every thread of
+    the process while it runs, and a full collection over a large heap
+    (the index of 100 000 series) takes tenths of a second.  Each
+    collection of generation 2, and any of a younger generation over
+    ``SLOW_S``, becomes a ``gc.pause`` stage span (tag ``generation``; a
+    full one also a profiler annotation, so a device-idle gap it caused
+    carries its name).  Every collection's seconds are in
+    ``filodb_gc_pause_seconds_total{generation}``.
 
-    def report(rec: SpanRecord) -> None:
-        if rec.duration_s >= min_duration_s:
-            tags = " ".join(f"{k}={v}" for k, v in rec.tags.items())
-            err = f" ERROR={rec.error}" if rec.error else ""
-            log(f"span {rec.name} {rec.duration_s * 1000:.2f}ms "
-                f"parent={rec.parent} {tags}{err}")
-    return report
+    The hook runs on whichever thread set the collection off, at
+    whatever point it had reached, and that thread may hold the stage
+    table's lock, the trace store's or a counter's.  So the hook takes
+    no lock and calls no reporter: it adds to its own totals and leaves
+    the span with ``Tracer.defer``.  A serving process collects
+    generation 0 some 1 500 times a second; that path is two clock
+    reads and an add.
+
+    One slot of state is enough: the interpreter runs one collection at
+    a time, and both callbacks of it on the collecting thread."""
+
+    SLOW_S = 0.010
+    name = "filodb_gc_pause_seconds_total"
+
+    def __init__(self, tracer: "Tracer" = TRACER):
+        self._tracer = tracer
+        tracer._annotation_class()      # no import from inside the hook
+        self.seconds = [0.0, 0.0, 0.0]  # by generation; the hook alone adds
+        self._t0 = self._wall0 = self._cpu0 = self._ann = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        gen = info["generation"]
+        if phase == "start":
+            if gen == 2:
+                self._ann = self._tracer._leaf_annotation("gc.pause")
+                self._cpu0 = time.thread_time()
+            self._wall0 = time.time()
+            self._t0 = time.perf_counter()
+            return
+        if self._t0 is None:
+            return              # installed in the middle of a collection
+        dur = time.perf_counter() - self._t0
+        self._t0 = None
+        self.seconds[gen] += dur
+        if gen < 2 and dur <= self.SLOW_S:
+            return
+        cpu = 0.0               # the CPU clock is read for full ones only
+        if gen == 2:
+            cpu = max(time.thread_time() - self._cpu0, 0.0)
+            ann, self._ann = self._ann, None
+            if ann is not None:
+                try:
+                    ann.__exit__(None, None, None)
+                except Exception:  # noqa: BLE001 — tracing never fails work
+                    pass
+        tracer = self._tracer
+        tracer.defer("gc.pause", dur,
+                     trace_id=tracer.current_trace_id(),
+                     parent_id=tracer.current_span_id(),
+                     start_s=self._wall0, cpu_s=cpu, stage=True,
+                     generation=gen, collected=info.get("collected", 0))
+
+    def expose(self) -> list[str]:
+        out = [f"# TYPE {self.name} counter"]
+        for gen, secs in enumerate(self.seconds):
+            if secs:
+                key = (("generation", gen),)
+                out.append(f"{self.name}{_fmt_labels(key)} {_fmt_val(secs)}")
+        return out
+
+
+_GC_WATCH: Optional[GcPauseWatch] = None
+
+
+def install_gc_watch() -> GcPauseWatch:
+    """Install the process's one :class:`GcPauseWatch` (idempotent)."""
+    global _GC_WATCH
+    import gc
+    if _GC_WATCH is None:
+        _GC_WATCH = GcPauseWatch()
+        REGISTRY.collector(GcPauseWatch.name, _GC_WATCH)
+    if _GC_WATCH not in gc.callbacks:
+        gc.callbacks.append(_GC_WATCH)
+    return _GC_WATCH
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ ledger is worse than none (operators size HBM budgets from it).
 import collections
 import gc
 import json
+import time
 import urllib.parse
 import urllib.request
 
@@ -241,12 +242,21 @@ def kt_config():
     """Snapshot + restore the process-wide KernelTimer knobs so tests
     can crank the sample rate / sentry windows without leaking."""
     kt = KERNEL_TIMER
-    old = (kt.sample_1_in, kt.hbm_roof_bytes_per_s, kt.regression_factor,
+    old = (kt.sample_1_in, kt.regression_factor,
            kt.regression_window_s, kt.baseline_min_samples)
     yield kt
-    kt.configure(sample_1_in=old[0], hbm_roof_bytes_per_s=old[1],
-                 regression_factor=old[2], regression_window_s=old[3],
-                 baseline_min_samples=old[4])
+    kt.configure(sample_1_in=old[0],
+                 regression_factor=old[1], regression_window_s=old[2],
+                 baseline_min_samples=old[3])
+
+
+@pytest.fixture()
+def roof_table(monkeypatch):
+    """The roof table with a row for the device the tests run on (the
+    CPU has no published HBM peak, so the table itself has none)."""
+    monkeypatch.setitem(devicewatch.HBM_ROOF_BYTES_PER_S,
+                        devicewatch.device_kind(), 1e9)
+    return devicewatch.HBM_ROOF_BYTES_PER_S
 
 
 class TestKernelTimer:
@@ -301,9 +311,9 @@ class TestKernelTimer:
         # wrappers): counting resumes with the switch
         assert row["bytes_total"] == 0
 
-    def test_bytes_join_yields_roofline_fraction(self, kt_config):
-        kt = KernelTimer(sample_1_in=1, hbm_roof_bytes_per_s=1e9,
-                         baseline_min_samples=100)
+    def test_bytes_join_yields_roofline_fraction(self, kt_config,
+                                                 roof_table):
+        kt = KernelTimer(sample_1_in=1, baseline_min_samples=100)
         kt.note_bytes("p", 4_000)
         kt._fold("p", 0.001, "k")          # 4000 B / launch... but
         # launches=0 until tick(); note_bytes alone must not divide by 0
@@ -315,6 +325,27 @@ class TestKernelTimer:
         # 4000 bytes / 1 launch / ewma(0.001 s) / roof(1e9 B/s)
         assert row["achieved_bytes_per_s"] == pytest.approx(4e6, rel=0.01)
         assert row["roofline_fraction"] == pytest.approx(4e-3, rel=0.01)
+
+    def test_device_the_table_lacks_gets_no_roofline(self, kt_config,
+                                                     monkeypatch):
+        """No assumed peak: on a device_kind the table has no row for,
+        /admin/kernels leaves roofline_fraction out altogether."""
+        monkeypatch.delitem(devicewatch.HBM_ROOF_BYTES_PER_S,
+                            devicewatch.device_kind(), raising=False)
+        kt = KernelTimer(sample_1_in=1, baseline_min_samples=100)
+        kt.note_bytes("p", 4_000)
+        assert kt.tick("p")
+        kt._fold("p", 0.001, "k")
+        row = [r for r in kt.table() if r["program"] == "p"][0]
+        assert row["achieved_bytes_per_s"] == pytest.approx(4e6, rel=0.01)
+        assert "roofline_fraction" not in row
+        assert devicewatch.hbm_roof() is None
+        assert devicewatch.kernel_summary()["hbm_roof_bytes_per_s"] is None
+
+    def test_roof_table_has_the_v5e(self):
+        assert devicewatch.HBM_ROOF_BYTES_PER_S["TPU v5 lite"] == 819e9
+        assert all(v > 1e11 for v in
+                   devicewatch.HBM_ROOF_BYTES_PER_S.values())
 
     def test_baseline_store_merge_and_persist(self, kt_config):
         saved = {}
@@ -795,7 +826,8 @@ class TestKernelDeckEndpoints:
         assert total <= stats["timings"]["device_compute"] + 0.005
 
     def test_admin_kernels_joins_and_reconciles_exactly(self, server,
-                                                        kt_config):
+                                                        kt_config,
+                                                        roof_table):
         port, _ms = server
         kt_config.configure(sample_1_in=1)
         self._warm(port)
@@ -804,7 +836,8 @@ class TestKernelDeckEndpoints:
         assert code == 200
         data = body["data"]
         assert data["sample_1_in"] == 1
-        assert data["hbm_roof_bytes_per_s"] > 0
+        assert data["hbm_roof_bytes_per_s"] == 1e9
+        assert data["device_kind"] == devicewatch.device_kind()
         rows = {r["program"]: r for r in data["programs"]}
         # a devicestore program THIS test's 1-in-1 queries sampled
         # (earlier tests at the default rate leave bytes-only rows)
@@ -825,7 +858,8 @@ class TestKernelDeckEndpoints:
             assert m.value(program=program) == r["launches"], program
 
     def test_roofline_degrades_and_row_flags_regression(self, server,
-                                                        kt_config):
+                                                        kt_config,
+                                                        roof_table):
         """ISSUE 15 acceptance: an injected slowdown on the serving
         program degrades its /admin/kernels roofline fraction and flips
         the row's sentry state."""
@@ -891,6 +925,42 @@ class TestKernelDeckEndpoints:
         finally:
             forensics._PROFILE_LOCK.release()
 
+    def test_a_capture_holds_the_leaf_spans_and_no_python_tracer(
+            self, tmp_path):
+        """/debug/device_profilez starts the profiler as benchmark/run.py
+        does: the Python tracer off (it slows the threads it records),
+        the host tracer keeping TraceMe events — so the leaf stage spans
+        lie on the capture's host plane, and an enclosing stage does
+        not."""
+        import glob
+        import os
+        import threading
+        from jax.profiler import ProfileData
+        from filodb_tpu.utils import forensics
+        from filodb_tpu.utils.observability import TRACER
+        got = {}
+        t = threading.Thread(target=lambda: got.update(
+            forensics.device_profile(seconds=0.6,
+                                     trace_root=str(tmp_path))))
+        t.start()
+        deadline = time.time() + 0.9
+        while time.time() < deadline and t.is_alive():
+            with TRACER.stage("device_compute", leaf=False):
+                with TRACER.stage("grid.select"):
+                    time.sleep(0.005)
+        t.join()
+        xplane = glob.glob(os.path.join(
+            got["trace_dir"], "plugins", "profile", "*", "*.xplane.pb"))
+        assert xplane, os.listdir(got["trace_dir"])
+        names = {e.name
+                 for p in ProfileData.from_file(xplane[0]).planes
+                 if p.name.startswith("/host:")
+                 for ln in p.lines for e in ln.events}
+        assert "grid.select" in names
+        assert "device_compute" not in names
+        assert not any(n.startswith("$") for n in names), \
+            "the Python tracer was on"
+
     def test_device_trace_dirs_are_retention_bounded(self, tmp_path):
         """Review fix: repeated captures must not fill the disk — at
         most DEVICE_TRACE_RETAIN capture dirs survive, oldest pruned."""
@@ -917,16 +987,14 @@ class TestKernelDeckEndpoints:
         assert code == 200
         obs = body["data"]["observability"]
         assert "kernel-sample-1-in" in obs
-        assert "hbm-roof-bytes-per-s" in obs
+        assert not any("roof" in k for k in obs)   # a table, not a knob
         code, body = _post_json(port, "/admin/config",
                                 **{"kernel-sample-1-in": "8",
-                                   "hbm-roof-bytes-per-s": "1e9",
                                    "kernel-regression-factor": "2.0",
                                    "kernel-baseline-min-samples": "5"})
         assert code == 200
         obs = body["data"]["observability"]
         assert obs["kernel-sample-1-in"] == 8
-        assert obs["hbm-roof-bytes-per-s"] == 1e9
         assert obs["kernel-regression-factor"] == 2.0
         assert obs["kernel-baseline-min-samples"] == 5
         assert KERNEL_TIMER.sample_1_in == 8

@@ -187,7 +187,7 @@ class TestTraceStore:
 
         tid = self._traced(store, work)
         # spans with no trace id never enter the store
-        store.report(SpanRecord("orphan", 0, 0.1, {}, None))
+        store.report([SpanRecord("orphan", 0, 0.1, {}, None)])
         tree = store.tree(tid)
         assert len(tree) == 1 and tree[0]["name"] == "root"
         kids = [c["name"] for c in tree[0]["children"]]
@@ -213,7 +213,7 @@ class TestTraceStore:
         tid = "feedfeedfeedfeed"
         local = SpanRecord("dispatch.http", 0, 1.0, {}, None,
                            trace_id=tid, span_id="aaa")
-        store.report(local)
+        store.report([local])
         remote = [{"name": "execplan.execute", "start_s": 0.1,
                    "duration_s": 0.5, "tags": {"shard": "1"},
                    "trace_id": tid, "span_id": "bbb", "parent_id": "aaa"}]
@@ -228,8 +228,8 @@ class TestTraceStore:
     def test_bounded_traces(self):
         store = TraceStore(max_traces=4)
         for i in range(10):
-            store.report(SpanRecord("s", 0, 0.1, {}, None,
-                                    trace_id=f"t{i}", span_id=f"id{i}"))
+            store.report([SpanRecord("s", 0, 0.1, {}, None,
+                                     trace_id=f"t{i}", span_id=f"id{i}")])
         assert len(store.trace_ids()) == 4
         assert store.trace_ids()[-1] == "t9"
 
@@ -264,7 +264,7 @@ def test_profile_returns_hot_frames():
 def test_tracer_ids_and_attach():
     tracer = Tracer()
     recs = []
-    tracer.add_reporter(recs.append)
+    tracer.add_reporter(recs.extend)
     tid = tracer.new_trace_id()
     with tracer.attach((tid, "parenthint")):
         with tracer.span("outer"):
@@ -279,3 +279,417 @@ def test_tracer_ids_and_attach():
     assert token == (tid, outer.span_id)
     # outside the attach the thread is clean again
     assert tracer.current_trace_id() is None
+
+
+# ---------------------------------------------------------------------------
+# The stage clock (PR 27): wall + CPU, the stage table, leaf annotations
+# ---------------------------------------------------------------------------
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records enter/exit."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture()
+def tracer():
+    t = Tracer()
+    t.recs = []
+    t.add_reporter(t.recs.extend)
+    _FakeAnnotation.log = []
+    t._annotate = _FakeAnnotation
+    return t
+
+
+def _burn(seconds: float) -> None:
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        sum(i * i for i in range(500))
+
+
+class TestStageClock:
+    def test_cpu_is_near_zero_across_a_sleep(self, tracer):
+        with tracer.stage("sleeper", cpu=True):
+            time.sleep(0.05)
+        rec = tracer.recs[0]
+        assert rec.duration_s >= 0.05
+        assert 0.0 <= rec.cpu_s < 0.01, "a sleeping thread burns no CPU"
+
+    def test_cpu_follows_wall_when_the_thread_is_busy(self, tracer):
+        with tracer.stage("burner", leaf=False, cpu=True) as sp:
+            _burn(0.03)
+        # at most its wall, up to the two clocks' grain: the span does
+        # not clamp (a clock that ticks in steps would read low in sum)
+        assert 0.03 <= sp.cpu_s <= sp.duration_s + 0.002
+        assert tracer.recs[0].cpu_s == sp.cpu_s
+
+    def test_only_a_stage_that_asks_reads_the_cpu_clock(self, tracer,
+                                                        monkeypatch):
+        """The CPU clock is a system call (6-15 us where gVisor answers
+        it): plain spans and stages that do not ask never pay it."""
+        calls = []
+        real = time.thread_time
+        monkeypatch.setattr(time, "thread_time",
+                            lambda: calls.append(1) or real())
+        with tracer.span("execplan.execute"):
+            pass
+        with tracer.stage("grid.readback"):
+            pass
+        with tracer.stage("scan", leaf=False):
+            pass
+        assert calls == []
+        assert [r.cpu_s for r in tracer.recs] == [0.0, 0.0, 0.0]
+        with tracer.stage("grid.plan", cpu=True):
+            pass
+        assert len(calls) == 2               # enter and exit, no cache
+
+    def test_cpu_is_not_clamped_to_the_wall(self, tracer, monkeypatch):
+        """A CPU clock in 10 ms ticks overshoots a short span as often
+        as it undershoots: clamping would bias the sums low."""
+        ticks = iter([0.00, 0.01])
+        monkeypatch.setattr(time, "thread_time", lambda: next(ticks))
+        with tracer.stage("grid.plan", cpu=True) as sp:
+            pass
+        assert sp.cpu_s == pytest.approx(0.01) and sp.duration_s < 0.01
+        assert tracer.stages.snapshot()["grid.plan"]["cpu_s"] == \
+            pytest.approx(0.01)
+
+    def test_spans_wait_for_the_threads_outermost_one_to_end(self, tracer):
+        """Finished spans are handed over in one list: when the thread's
+        outermost span ends, when a stage that carries a query's timings
+        does, when ``flush`` is called, or when 64 have gathered."""
+        batches = []
+        tracer.add_reporter(lambda recs: batches.append(
+            [r.name for r in recs]))
+
+        class Timings:
+            got = []
+
+            def note_timings(self, walls):
+                self.got.extend(name for name, _ in walls)
+
+        with tracer.stage("http.request", leaf=False):
+            with tracer.attach((tracer.new_trace_id(), None)), \
+                    tracer.span("query"):
+                with tracer.stage("scan", leaf=False, timings=Timings()):
+                    with tracer.stage("grid.plan"):
+                        pass
+                    with tracer.stage("grid.dispatch"):
+                        pass
+                    assert batches == [] and tracer.stages.snapshot() == {}
+                assert batches == [["grid.plan", "grid.dispatch", "scan"]]
+                assert Timings.got == ["grid.plan", "grid.dispatch", "scan"]
+                with tracer.stage("serialize"):
+                    pass
+            assert batches[1:] == []     # an attached stack emptied: no
+            tracer.flush()
+            assert batches[1:] == [["serialize", "query"]]
+            for _ in range(tracer.MAX_WAITING):
+                with tracer.stage("http.encode"):
+                    pass
+            assert batches[2:] == [["http.encode"] * tracer.MAX_WAITING]
+        assert batches[3:] == [["http.request"]]
+        with tracer.stage("grid.build"):            # outermost: at once
+            pass
+        assert batches[4:] == [["grid.build"]]
+        assert tracer.stages.snapshot()["grid.plan"]["count"] == 1
+
+    def test_a_wait_only_stage_is_no_leaf(self, tracer):
+        waited = tracer.stage("grid.lock_wait", leaf=False).begin()
+        waited.end()
+        with tracer.stage("batch.wait", leaf=False, role="member"):
+            pass
+        assert _FakeAnnotation.log == []
+        assert set(tracer.stages.snapshot()) == {"grid.lock_wait",
+                                                 "batch.wait"}
+
+    def test_start_is_stamped_at_enter(self, tracer):
+        before = time.time()
+        with tracer.span("s"):
+            time.sleep(0.03)
+        after = time.time()
+        rec = tracer.recs[0]
+        assert before <= rec.start_s <= after - 0.03
+        # a synthetic span takes its start from the caller, or ends now
+        given = tracer.record("synthetic", 2.0, start_s=123.0)
+        assert given.start_s == 123.0 and given.cpu_s == 0.0
+        ended_now = tracer.record("synthetic", 2.0)
+        assert ended_now.start_s == pytest.approx(time.time() - 2.0, abs=0.5)
+
+    def test_duration_stays_readable_after_exit(self, tracer):
+        with tracer.stage("query.plan") as sp:
+            time.sleep(0.01)
+        assert sp.duration_s >= 0.01
+        assert sp.duration_s == tracer.recs[0].duration_s
+
+    def test_begin_and_end_delimit_what_no_with_block_can(self, tracer):
+        waited = tracer.stage("grid.lock_wait").begin()
+        assert tracer.current_span() == "grid.lock_wait"
+        waited.end()
+        assert tracer.current_span() is None
+        assert tracer.stages.snapshot()["grid.lock_wait"]["count"] == 1
+
+    def test_a_plain_span_stays_out_of_the_stage_table(self, tracer):
+        with tracer.span("query.execute"):
+            pass
+        assert tracer.stages.snapshot() == {}
+        assert _FakeAnnotation.log == []
+
+    def test_stage_table_adds_up_under_eight_threads(self, tracer):
+        def work():
+            for _ in range(200):
+                with tracer.stage("grid.select"):
+                    pass
+                tracer.record("scheduler.queue_wait", 0.5, stage=True)
+        threads = [threading.Thread(target=work) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        table = tracer.stages.snapshot()
+        assert table["grid.select"]["count"] == 1600
+        sel = [r for r in tracer.recs if r.name == "grid.select"]
+        assert table["grid.select"]["wall_s"] == pytest.approx(
+            sum(r.duration_s for r in sel), rel=1e-9)
+        assert table["grid.select"]["cpu_s"] == pytest.approx(
+            sum(r.cpu_s for r in sel), rel=1e-9, abs=1e-12)
+        assert table["scheduler.queue_wait"] == {
+            "count": 1600, "wall_s": pytest.approx(800.0), "cpu_s": 0.0}
+
+    def test_a_leaf_is_annotated_and_an_enclosing_stage_is_not(self, tracer):
+        with tracer.stage("device_compute", leaf=False):
+            with tracer.stage("grid.dispatch"):
+                pass
+            with tracer.stage("grid.readback"):
+                pass
+        assert _FakeAnnotation.log == [
+            ("enter", "grid.dispatch"), ("exit", "grid.dispatch"),
+            ("enter", "grid.readback"), ("exit", "grid.readback")]
+        assert set(tracer.stages.snapshot()) == {
+            "device_compute", "grid.dispatch", "grid.readback"}
+
+    def test_a_failing_annotation_degrades_to_none(self, tracer):
+        def broken(name):
+            raise RuntimeError("no profiler here")
+        tracer._annotate = broken
+        with tracer.stage("grid.plan"):
+            pass
+        assert tracer._annotate is None      # not tried again
+        with tracer.stage("grid.plan"):
+            pass
+        assert tracer.stages.snapshot()["grid.plan"]["count"] == 2
+
+    def test_jax_missing_means_no_annotation(self, tracer, monkeypatch):
+        from filodb_tpu.utils import observability as obs
+        monkeypatch.setattr(obs, "_jax_annotation", lambda: None)
+        tracer._annotate = obs._jax_annotation
+        with tracer.stage("grid.plan"):
+            pass
+        assert tracer.recs and tracer.recs[0].error is None
+
+    def test_a_stage_inside_a_query_lands_in_its_timings(self, tracer):
+        from filodb_tpu.memstore.memstore import TimeSeriesMemStore
+        from filodb_tpu.query import exec as qexec
+        ctx = qexec.ExecContext(TimeSeriesMemStore())
+        with tracer.stage("query.plan"):            # before the scan
+            pass
+        with tracer.span("execplan.execute"):
+            with tracer.stage("scan", leaf=False, timings=ctx):
+                with tracer.stage("grid.select"):
+                    time.sleep(0.002)
+                with tracer.stage("device_compute", leaf=False):
+                    with tracer.stage("grid.select"):
+                        pass
+                with tracer.span("odp.pagein"):     # a plain span: no key
+                    pass
+            with tracer.stage("serialize"):         # after it
+                pass
+        assert set(ctx._timings) == {"grid.select", "device_compute",
+                                     "scan"}
+        walls = {}
+        for r in tracer.recs:
+            walls[r.name] = walls.get(r.name, 0.0) + r.duration_s
+        for name in ctx._timings:
+            assert ctx._timings[name] == pytest.approx(walls[name])
+
+    def test_nested_scans_each_take_their_own_stages(self, tracer):
+        from filodb_tpu.memstore.memstore import TimeSeriesMemStore
+        from filodb_tpu.query import exec as qexec
+        outer = qexec.ExecContext(TimeSeriesMemStore())
+        inner = qexec.ExecContext(TimeSeriesMemStore())
+        with tracer.stage("scan", leaf=False, timings=outer):
+            with tracer.stage("grid.plan"):
+                pass
+            with tracer.stage("scan", leaf=False, timings=inner):
+                with tracer.stage("grid.dispatch"):
+                    pass
+            with tracer.stage("grid.select"):
+                pass
+        assert set(inner._timings) == {"grid.dispatch", "scan"}
+        assert set(outer._timings) == {"grid.plan", "grid.select", "scan"}
+
+    def test_a_stage_outside_any_query_does_not_raise(self, tracer):
+        with tracer.stage("http.encode"):
+            pass
+        assert tracer.stages.snapshot()["http.encode"]["count"] == 1
+
+    def test_the_scan_stage_feeds_the_query_it_runs_for(self):
+        from filodb_tpu.query import exec as qexec
+        assert len(qexec.GRID_STAGES) == 8
+        ctx = qexec.ExecContext(None)
+        ctx.note_timings((("grid.plan", 0.25), ("grid.plan", 0.5),
+                          ("scan", 1.0)))
+        ctx.note_timing("scan", 1.0)
+        assert ctx._timings == {"grid.plan": 0.75, "scan": 2.0}
+
+    def test_stage_families_expose_in_valid_grammar(self, tracer):
+        with tracer.stage('odd"name'):
+            pass
+        tracer.record("scheduler.queue_wait", 0.25, stage=True)
+        text = "\n".join(tracer.stages.expose()) + "\n"
+        _assert_exposition_valid(text)
+        assert ('filodb_stage_seconds_total{kind="wall",'
+                'stage="scheduler.queue_wait"} 0.25') in text
+        assert 'filodb_stage_total{stage="scheduler.queue_wait"} 1' in text
+        assert 'kind="cpu"' in text
+
+    def test_process_registry_carries_the_stage_families(self):
+        from filodb_tpu.utils.observability import TRACER
+        with TRACER.stage("test.registry_probe", leaf=False):
+            pass
+        text = REGISTRY.expose_text()
+        _assert_exposition_valid(text)
+        assert 'filodb_stage_total{stage="test.registry_probe"}' in text
+
+    def test_span_dict_roundtrip_keeps_cpu(self):
+        rec = SpanRecord("grid.plan", 10.0, 0.5, {}, None, trace_id="t",
+                         span_id="s", cpu_s=0.125)
+        assert span_to_dict(rec)["cpu_s"] == 0.125
+        assert span_from_dict(span_to_dict(rec)).cpu_s == 0.125
+        assert span_from_dict({"name": "old-node"}).cpu_s == 0.0
+
+
+class TestGcPauseWatch:
+    def _watch(self, tracer):
+        from filodb_tpu.utils.observability import GcPauseWatch
+        return GcPauseWatch(tracer)
+
+    def test_a_forced_full_collection_is_recorded(self, tracer):
+        import gc
+        watch = self._watch(tracer)
+        gc.callbacks.append(watch)
+        try:
+            gc.collect()
+        finally:
+            gc.callbacks.remove(watch)
+        # the hook reports nothing itself: the span waits for a flush
+        assert tracer.recs == [] and len(tracer._deferred) == 1
+        row = tracer.stages.snapshot()["gc.pause"]     # a read drains it
+        recs = [r for r in tracer.recs if r.name == "gc.pause"]
+        assert len(recs) == 1
+        assert recs[0].tags["generation"] == 2
+        assert 0.0 <= recs[0].cpu_s <= recs[0].duration_s + 0.002
+        assert row["count"] == 1
+        assert row["wall_s"] == pytest.approx(recs[0].duration_s)
+        assert watch.seconds[2] == pytest.approx(recs[0].duration_s)
+        # a full collection is a leaf on the profiler's clock too
+        assert _FakeAnnotation.log == [("enter", "gc.pause"),
+                                       ("exit", "gc.pause")]
+
+    @pytest.mark.parametrize("held", ["stage_table", "trace_store"])
+    def test_a_collection_under_a_tracing_lock_cannot_deadlock(
+            self, tracer, held):
+        """The hook runs on whichever thread set the collection off, at
+        whatever point it had reached: inside the stage table's lock
+        or the trace store's.  Neither is reentrant, so the hook may
+        take neither."""
+        import gc
+        store = TraceStore()
+        tracer.add_reporter(store.report)
+        lock = {"stage_table": tracer.stages._lock,
+                "trace_store": store._lock}[held]
+        watch = self._watch(tracer)
+        done = threading.Event()
+
+        def collect_under_the_lock():
+            with tracer.attach((tracer.new_trace_id(), None)):
+                with tracer.stage("http.request", leaf=False):
+                    gc.callbacks.append(watch)
+                    try:
+                        with lock:
+                            gc.collect()
+                    finally:
+                        gc.callbacks.remove(watch)
+            done.set()
+
+        t = threading.Thread(target=collect_under_the_lock, daemon=True)
+        t.start()
+        assert done.wait(10.0), "the collector's hook waited for a lock " \
+                                "its own thread holds"
+        # and the span was not lost: the thread's next flush carried it
+        # into the trace it interrupted
+        pause = [r for r in tracer.recs if r.name == "gc.pause"]
+        assert len(pause) == 1 and pause[0].trace_id
+        assert [r.name for r in store.spans_for(pause[0].trace_id)] == [
+            "gc.pause", "http.request"]
+        assert tracer.stages.snapshot()["gc.pause"]["count"] == 1
+
+    def test_a_quick_young_collection_only_counts(self, tracer):
+        """No span, no lock: two clock reads and an add."""
+        watch = self._watch(tracer)
+        watch("start", {"generation": 0})
+        watch("stop", {"generation": 0, "collected": 3})
+        assert tracer.recs == [] and _FakeAnnotation.log == []
+        assert not tracer._deferred
+        assert watch.seconds[0] > 0.0 and watch.seconds[1:] == [0.0, 0.0]
+        watch("start", {"generation": 2})
+        watch("stop", {"generation": 2, "collected": 0})
+        tracer.flush_deferred()
+        assert [r.tags["generation"] for r in tracer.recs] == [2]
+
+    def test_a_slow_young_collection_is_recorded(self, tracer):
+        watch = self._watch(tracer)
+        watch("start", {"generation": 1})
+        time.sleep(watch.SLOW_S * 1.5)
+        watch("stop", {"generation": 1, "collected": 0})
+        with tracer.span("the next span on any thread"):
+            pass
+        assert [r.tags["generation"] for r in tracer.recs
+                if r.name == "gc.pause"] == [1]
+        assert tracer.recs[0].duration_s > watch.SLOW_S
+        assert tracer.recs[0].cpu_s == 0.0     # read for full ones only
+
+    def test_a_stop_without_its_start_is_ignored(self, tracer):
+        self._watch(tracer)("stop", {"generation": 2})
+        tracer.flush_deferred()
+        assert tracer.recs == []
+
+    def test_seconds_by_generation_expose_in_valid_grammar(self, tracer):
+        watch = self._watch(tracer)
+        watch.seconds[:] = [0.25, 0.0, 1.5]
+        text = "\n".join(watch.expose()) + "\n"
+        _assert_exposition_valid(text)
+        assert 'filodb_gc_pause_seconds_total{generation="0"} 0.25' in text
+        assert 'generation="2"} 1.5' in text and 'generation="1"' not in text
+
+    def test_install_is_idempotent(self):
+        import gc
+        from filodb_tpu.utils.observability import install_gc_watch
+        try:
+            a = install_gc_watch()
+            b = install_gc_watch()
+            assert a is b and gc.callbacks.count(a) == 1
+        finally:
+            gc.callbacks.remove(a)

@@ -15,8 +15,7 @@ import pytest
 from filodb_tpu.cli import main as cli_main
 from filodb_tpu.standalone import FiloServer
 from filodb_tpu.utils.observability import (REGISTRY, TRACER, MetricsRegistry,
-                                            SimpleProfiler, Tracer,
-                                            span_log_reporter)
+                                            SimpleProfiler, Tracer)
 
 BASE = 1_700_000_000_000
 
@@ -53,7 +52,7 @@ class TestTracer:
     def test_nested_spans_report_parent(self):
         tracer = Tracer()
         records = []
-        tracer.add_reporter(records.append)
+        tracer.add_reporter(records.extend)
         with tracer.span("outer", dataset="prom"):
             with tracer.span("inner"):
                 pass
@@ -65,20 +64,11 @@ class TestTracer:
     def test_span_error_recorded(self):
         tracer = Tracer()
         records = []
-        tracer.add_reporter(records.append)
+        tracer.add_reporter(records.extend)
         with pytest.raises(ValueError):
             with tracer.span("bad"):
                 raise ValueError("boom")
         assert "boom" in records[0].error
-
-    def test_log_reporter_formats(self):
-        lines = []
-        rep = span_log_reporter(lines.append)
-        tracer = Tracer()
-        tracer.add_reporter(rep)
-        with tracer.span("x", shard=3):
-            pass
-        assert lines and "span x" in lines[0] and "shard=3" in lines[0]
 
 
 class TestProfiler:

@@ -351,3 +351,270 @@ class TestForensicsEndpoints:
         url_b = f"http://127.0.0.1:{cluster['port_b']}/metrics"
         text_b = urllib.request.urlopen(url_b, timeout=10).read().decode()
         assert "filodb_query_execplan_remote_seconds" in text_b
+
+
+# ---------------------------------------------------------------------------
+# The stage clock inside the served path (PR 27): the grid call split into
+# stage spans, on the three paths a grid call takes
+# ---------------------------------------------------------------------------
+
+G_STEP = 60_000
+G_T0 = 1_700_000_040_000
+G_ROWS = 96
+GRID_BUCKETS = ("grid.resolve", "grid.lock_wait", "grid.plan", "batch.wait",
+                "grid.dispatch", "grid.device_wait", "grid.readback",
+                "grid.select")
+
+
+def _grid_server(path, monkeypatch):
+    """One local shard of uniform-phase counters that f32 holds, served
+    by the device grid on the ``solo`` (per-query launch of the decoded
+    plane), ``packed`` (fused compressed-resident kernels, interpret
+    mode here) or ``stacked`` (fleet batcher attached) path."""
+    from filodb_tpu.batching import QueryBatcher, reset_batch_breaker
+    from filodb_tpu.core.storeconfig import StoreConfig
+    from filodb_tpu.memstore import devicestore
+    packed = path == "packed"
+    monkeypatch.setattr(devicestore, "_PACKED_INTERPRET", packed)
+    monkeypatch.setattr(devicestore, "_PACKED_BROKEN", False)
+    if packed:
+        monkeypatch.setattr(devicestore.DeviceGridCache, "_val_dtype",
+                            lambda self: np.float32)
+    reset_batch_breaker()
+    ms = TimeSeriesMemStore()
+    shard = ms.setup("grid", DEFAULT_SCHEMAS, 0,
+                     StoreConfig(device_cache_compress=packed))
+    rng = np.random.default_rng(7)
+    b = RecordBuilder(DEFAULT_SCHEMAS["prom-counter"])
+    for i in range(8):
+        tags = {"__name__": "c_total", "instance": f"i{i}",
+                "_ws_": "w", "_ns_": "n"}
+        ph = int(rng.integers(1, G_STEP))
+        ts = G_T0 + np.arange(G_ROWS, dtype=np.int64) * G_STEP - G_STEP + ph
+        vals = (2 ** 23 + 128 * np.cumsum(
+            rng.integers(1, 8, G_ROWS))).astype(np.float64)
+        b.add_series(ts, [vals], tags)
+    for off, c in enumerate(b.containers()):
+        shard.ingest(decode_container(c, DEFAULT_SCHEMAS), off)
+    shard.flush_all()
+    if path == "stacked":
+        shard.query_batcher = QueryBatcher(
+            enabled=True, window_ms=150.0, max_batch=4, hot_ttl_s=30.0,
+            dataset="grid")
+    mapper = ShardMapper(1)
+    mapper.register_node([0], "local")
+    mapper.update_status(0, ShardStatus.ACTIVE)
+    srv = FiloHttpServer()
+    srv.bind_dataset(DatasetBinding(
+        "grid", ms, SingleClusterPlanner("grid", mapper, DatasetOptions(),
+                                         spread_default=0)))
+    return srv, srv.start()
+
+
+def _grid_query(port, query, first_step=7, **extra):
+    return _get(port, "/promql/grid/api/v1/query_range", query=query,
+                start=(G_T0 + first_step * G_STEP) / 1000,
+                end=(G_T0 + (first_step + 60) * G_STEP) / 1000, step="60s",
+                stats="true", **extra)
+
+
+def _launches(program):
+    from filodb_tpu.utils.devicewatch import device_metrics
+    return device_metrics()["kernel_launches"].value(program=program)
+
+
+def _grid_split_tiles(timings) -> bool:
+    """All eight keys are there, none negative, together no more than
+    the enclosing ``device_compute``; True where they also tile it to
+    5% + 1 ms.  (What lies between two spans is a thread switch away
+    from any size on a loaded test host, so a caller asks for the
+    tiling of most requests, not of each.)"""
+    for key in GRID_BUCKETS:
+        assert key in timings, f"missing {key}: {sorted(timings)}"
+        assert timings[key] >= 0.0
+    parts = sum(timings[k] for k in GRID_BUCKETS)
+    whole = timings["device_compute"]
+    assert parts <= whole + 1e-5, (parts, whole)     # 6-decimal rounding
+    assert timings["scan"] >= whole
+    return whole - parts <= 0.05 * whole + 0.001
+
+
+@pytest.mark.parametrize("path", ["solo", "packed", "stacked"])
+@pytest.mark.parametrize("query, program", [
+    ('rate(c_total{_ws_="w",_ns_="n"}[5m])', "series"),
+    ('sum(rate(c_total{_ws_="w",_ns_="n"}[5m]))', "grouped")])
+def test_grid_call_splits_into_eight_stages(path, query, program,
+                                            monkeypatch):
+    import threading
+    srv, port = _grid_server(path, monkeypatch)
+    try:
+        if path != "stacked":
+            served = "devicestore." + program + \
+                ("_packed" if path == "packed" else "")
+            before = _launches(served)
+            tiled = []
+            for _ in range(6):       # the first is cold: stages, compiles
+                code, body, _h = _grid_query(port, query)
+                assert code == 200 and body["data"]["result"]
+                tiled.append(_grid_split_tiles(
+                    body["data"]["stats"]["timings"]))
+            assert sum(tiled) >= 4, tiled
+            # the path under test really served, one launch a request
+            assert _launches(served) == before + 6
+            assert body["data"]["stats"]["timings"]["batch.wait"] == 0.0
+            return
+        stacked = "devicestore." + program + "_batch"
+        before = _launches(stacked)
+        tiled = []
+        for _round in range(12):            # a group forms off an overlap
+            barrier = threading.Barrier(5)
+            out = {}
+
+            def ask(i):
+                barrier.wait()
+                out[i] = _grid_query(port, query, first_step=7 + i)
+            threads = [threading.Thread(target=ask, args=(i,))
+                       for i in range(5)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            for code, body, _h in out.values():
+                assert code == 200 and body["data"]["result"]
+                tiled.append(_grid_split_tiles(
+                    body["data"]["stats"]["timings"]))
+            if _launches(stacked) > before:
+                break
+        assert _launches(stacked) > before, "no stacked launch in 12 rounds"
+        assert sum(tiled) >= 0.6 * len(tiled), tiled
+        # a member's request shows the rendezvous, the stacked launch
+        # itself folds into the leader's
+        waits = [b["data"]["stats"]["timings"]["batch.wait"]
+                 for _c, b, _h in out.values()]
+        assert max(waits) > 0.0
+    finally:
+        srv.shutdown()
+
+
+class TestStageClockEndpoints:
+    @pytest.fixture(scope="class")
+    def grid(self):
+        mp = pytest.MonkeyPatch()
+        srv, port = _grid_server("solo", mp)
+        yield port
+        srv.shutdown()
+        mp.undo()
+
+    QUERY = 'sum(rate(c_total{_ws_="w",_ns_="n"}[5m]))'
+
+    def test_admin_device_has_the_stage_table(self, grid):
+        _c, before, _h = _get(grid, "/admin/device")
+        code, _b, _h = _grid_query(grid, self.QUERY)
+        assert code == 200
+        _c, after, _h = _get(grid, "/admin/device")
+        b, a = before["data"]["stages"], after["data"]["stages"]
+        for name in ("http.request", "http.encode", "http.write",
+                     "query.plan", "scan", "device_compute", "serialize",
+                     "grid.resolve", "grid.lock_wait", "grid.plan",
+                     "grid.dispatch", "grid.device_wait", "grid.readback",
+                     "grid.select"):
+            assert set(a[name]) == {"count", "wall_s", "cpu_s"}, name
+            assert a[name]["count"] > b.get(name, {"count": 0})["count"], \
+                name
+            # CPU is not clamped to the wall: the two clocks' grain
+            assert 0.0 <= a[name]["cpu_s"] <= a[name]["wall_s"] + 1e-3, name
+        assert a["grid.build"]["count"] >= 1      # the cold block's staging
+
+    def test_a_served_request_annotates_work_and_not_waits(self, grid,
+                                                           monkeypatch):
+        """The profiler's host plane gets the leaves that do work; the
+        wait for the grid lock is a stage and no annotation, so a
+        device-idle gap takes the name of the plan that holds the lock."""
+        from filodb_tpu.utils.observability import TRACER
+        seen = []
+
+        class Ann:
+            def __init__(self, name):
+                seen.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(TRACER, "_annotate", Ann)
+        code, body, _h = _grid_query(grid, self.QUERY)
+        assert code == 200
+        assert {"grid.plan", "grid.dispatch", "grid.device_wait",
+                "grid.readback", "serialize", "http.encode"} <= set(seen)
+        assert not {"grid.lock_wait", "scan", "device_compute",
+                    "scheduler.run", "http.request"} & set(seen)
+        assert "grid.lock_wait" in body["data"]["stats"]["timings"]
+
+    def test_metrics_has_the_two_stage_families(self, grid):
+        _grid_query(grid, self.QUERY)
+        url = f"http://127.0.0.1:{grid}/metrics"
+        text = urllib.request.urlopen(url, timeout=10).read().decode()
+        assert ('filodb_stage_seconds_total{kind="wall",'
+                'stage="grid.dispatch"}') in text
+        assert ('filodb_stage_seconds_total{kind="cpu",'
+                'stage="http.request"}') in text
+        assert 'filodb_stage_total{stage="http.encode"}' in text
+
+    def test_encode_and_write_join_the_querys_trace(self, grid):
+        code, body, headers = _grid_query(grid, self.QUERY)
+        tid = body["data"]["stats"]["traceId"]
+        assert headers.get("X-FiloDB-Trace-Id") == tid
+        # the handler's thread hands its spans over when http.request
+        # ends, which is after the client holds the answer
+        for _ in range(200):
+            _c, tbody, _h = _get(grid, f"/admin/traces/{tid}")
+            roots = tbody["data"]["spans"]
+            if [r["name"] for r in roots] == ["query"] and any(
+                    n["name"] == "http.write"
+                    for n in roots[0]["children"]):
+                break
+            time.sleep(0.01)
+        assert [r["name"] for r in roots] == ["query"]
+        kids = {n["name"]: n for n in roots[0]["children"]}
+        assert {"query.plan", "serialize", "http.encode",
+                "http.write"} <= set(kids)
+        flat = {n["name"]: n for n in _flatten(roots)}
+        assert {"scan", "device_compute", "grid.dispatch",
+                "grid.device_wait", "grid.readback"} <= set(flat)
+        assert flat["grid.dispatch"]["tags"]["program"] == \
+            "devicestore.grouped"
+        assert int(flat["grid.readback"]["tags"]["bytes"]) > 0
+        assert all("cpu_s" in n for n in flat.values())
+        # nothing timed after the body was built can be in the body
+        assert not any(k.startswith("http.")
+                       for k in body["data"]["stats"]["timings"])
+
+    def test_plan_bucket_is_the_plan_spans_duration(self, grid):
+        _c, body, _h = _grid_query(grid, self.QUERY)
+        tid = body["data"]["stats"]["traceId"]
+        _c, tbody, _h = _get(grid, f"/admin/traces/{tid}")
+        plan = [n for n in _flatten(tbody["data"]["spans"])
+                if n["name"] == "query.plan"][0]
+        assert body["data"]["stats"]["timings"]["plan"] == \
+            pytest.approx(plan["duration_s"], abs=2e-6)
+
+    def test_launch_and_compile_counters_read_as_before(self, grid):
+        """What the benchmark's device_dispatches and compiles_in_window
+        read: one launch of a devicestore program a served request, and
+        the compile table's rows."""
+        _grid_query(grid, self.QUERY)
+        n0 = _launches("devicestore.grouped")
+        _c, d0, _h = _get(grid, "/admin/device")
+        _grid_query(grid, self.QUERY)
+        assert _launches("devicestore.grouped") == n0 + 1
+        _c, d1, _h = _get(grid, "/admin/device")
+        rows = {p["program"]: p for p in d1["data"]["compile"]["programs"]}
+        assert rows["devicestore.grouped"]["compiles"] >= 1
+        assert sum(p["compiles"] for p in rows.values()) == sum(
+            p["compiles"] for p in d0["data"]["compile"]["programs"])
+        url = f"http://127.0.0.1:{grid}/metrics"
+        text = urllib.request.urlopen(url, timeout=10).read().decode()
+        assert 'filodb_kernel_launches_total{program="devicestore.grouped"}' \
+            in text
