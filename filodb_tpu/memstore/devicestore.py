@@ -633,12 +633,15 @@ class _Block:
         """The ts-plane segment descriptor the serving program consumes."""
         return self.ts if self.ts is not None else self.ts_desc
 
-    def dense_or_empty(self, a: int, b: int):
-        """Per-lane (dense, empty) bool masks: lane is provably dense
-        over local rows [a, b] / provably empty there."""
-        contiguous = self.fcnt == self.fmax - self.fmin + 1
-        dense = contiguous & (self.fmin <= a) & (self.fmax >= b)
-        empty = (self.fcnt == 0) | (self.fmax < a) | (self.fmin > b)
+    def dense_or_empty(self, a: int, b: int, req: np.ndarray):
+        """(dense, empty) bool masks over the columns ``req``: provably
+        dense over local rows [a, b] / provably empty there.  The fill
+        stats are gathered at ``req`` FIRST: a plan costs what the
+        request asks for, not the block's width."""
+        fmin, fmax, fcnt = self.fmin[req], self.fmax[req], self.fcnt[req]
+        contiguous = fcnt == fmax - fmin + 1
+        dense = contiguous & (fmin <= a) & (fmax >= b)
+        empty = (fcnt == 0) | (fmax < a) | (fmin > b)
         return dense, empty
 
 
@@ -690,11 +693,17 @@ class DeviceGridCache:
         # mesh staging memo: (row0, nrows) -> (parts identity, staged
         # ts, staged vals) — see mesh_plan
         self._mesh_stage_memo: dict[tuple, tuple] = {}
-        # full-plan memo: a repeat dashboard query re-pays the dense/
-        # phase proof walk (~40ms at 20k lanes) without it.  Keys carry
-        # every invalidation axis (cache version, ingest epoch, removal
-        # epoch, id-list fingerprint); cleared on freeze/repin/reclaim
+        # full-plan memo: a repeat dashboard query skips lane
+        # resolution, block assembly and the proofs.  A miss costs
+        # O(lanes requested), never O(lanes resident), so the memo stays
+        # small: its key holds ``steps0``, and a dashboard whose ``end``
+        # advances never hits.  Keys carry every invalidation axis
+        # (cache version, ingest epoch, removal epoch, id-list
+        # fingerprint); cleared on freeze/repin/reclaim
         self._plan_memo: dict[tuple, "_GridPlan"] = {}
+        # the last frozen-frontier walk: (state key, earliest buffered
+        # row's timestamp or None) — see _frozen_high
+        self._frontier: tuple = (None, None)
         self._seq = 0
         self._lock = threading.Lock()
         # stats
@@ -702,6 +711,7 @@ class DeviceGridCache:
         self.hits = 0
         self.dense_hits = 0
         self.evictions = 0
+        self.frontier_walks = 0
 
     # ------------------------------------------------------------ bookkeeping
 
@@ -854,14 +864,17 @@ class DeviceGridCache:
                 return None
             stride = self.hb if self.hist else 1
             tops = np.asarray(self.bucket_tops) if self.hist else None
-            garr = np.full(plan.ncols, num_groups * stride, dtype=np.int32)
-            lane_idx = plan.lane_idx
-            gid_arr = np.asarray(group_ids, dtype=np.int32)
-            if stride == 1:
-                garr[lane_idx] = gid_arr
-            else:
-                hist_slot_garr(garr, lane_idx, gid_arr, stride)
             _note_hbm(plan)
+        # the full-width group map is built OUTSIDE the grid lock: the
+        # plan tuple and the caller's arguments are all it reads
+        # (``stride`` and ``tops`` were snapshotted under it)
+        garr = np.full(plan.ncols, num_groups * stride, dtype=np.int32)
+        gid_arr = np.asarray(group_ids, dtype=np.int32)
+        if stride == 1:
+            garr[plan.lane_idx] = gid_arr
+        else:
+            hist_slot_garr(garr, plan.lane_idx, gid_arr, stride)
+
         def grouped_solo():
             # today's per-query fused reduce: also the batching tier's
             # bit-identical fallback (it IS the same dispatch)
@@ -1013,15 +1026,23 @@ class DeviceGridCache:
 
     def _plan_staged(self, part_ids, func, steps0, nsteps, step_ms,  # holds-lock: _lock
                      window_ms, fargs):
-        """``_plan_locked`` as the ``grid.plan`` stage: lane and group
-        resolution (``_prep_for``), block assembly and, on a cold range,
-        the builds (``grid.build``) under the grid lock.  The wait for
-        that lock is the caller's ``grid.lock_wait`` stage: what a
-        worker loses to the other workers' plans."""
+        """``_plan_locked`` as the ``grid.plan`` stage, under the grid
+        lock: on a plan-memo miss lane resolution (``_prep_for``), block
+        assembly and the dense and phase proofs, all over the lanes
+        REQUESTED; what costs the lanes resident runs as a stage of its
+        own inside it, the frontier walk once per shard state
+        (``grid.frontier``; tag ``frontier``: ``walk`` where this plan
+        made it, else ``memo``) and, on a cold range, the builds
+        (``grid.build``).  The wait for the lock is the caller's
+        ``grid.lock_wait`` stage: what a worker loses to the other
+        workers' plans."""
         with TRACER.stage("grid.plan", cpu=True,
                           lanes_requested=len(part_ids)) as sp:
+            walks = self.frontier_walks
             plan = self._plan_locked(part_ids, func, steps0, nsteps,
                                      step_ms, window_ms, fargs)
+            sp.tag(frontier="memo" if walks == self.frontier_walks
+                   else "walk")
             if plan is not None:
                 sp.tag(lanes=plan.ncols)
             return plan
@@ -1327,13 +1348,14 @@ class DeviceGridCache:
         for off, blk in zip(range(bi_lo, bi_hi + 1), segments):
             a = max(c0 - off * BLOCK_BUCKETS, 0)
             b = min(c_last - off * BLOCK_BUCKETS, BLOCK_BUCKETS - 1)
-            d, e = blk.dense_or_empty(a, b)
-            all_dense &= d[req]
-            all_empty &= e[req]
+            d, e = blk.dense_or_empty(a, b, req)
+            all_dense &= d
+            all_empty &= e
             if ph_ok:
-                nonempty = ~e[req]
-                uniform = blk.pmin[req] == blk.pmax[req]
-                bph = blk.pmin[req].astype(np.int64)
+                nonempty = ~e
+                pmin = blk.pmin[req]
+                uniform = pmin == blk.pmax[req]
+                bph = pmin.astype(np.int64)
                 conflict = nonempty & (ph_req >= 0) & (ph_req != bph)
                 if (nonempty & ~uniform).any() or conflict.any():
                     ph_ok = False
@@ -1479,23 +1501,54 @@ class DeviceGridCache:
         self._disk_floor = (epoch, floor)
         return floor
 
-    def _frozen_high(self) -> int:
+    def _frozen_high(self) -> int:  # holds-lock: _lock
         """Highest bucket (exclusive) fully covered by frozen chunks: the
         earliest write-buffer row across THIS cache's lanes bounds it —
         an unrelated metric's laggy buffer must not demote this cache's
-        recent blocks to per-epoch-rebuilt tail blocks."""
-        lo = None
-        for pid in self.lane_of:
-            part = self._shard.grid_partition(pid)
-            if part is None:
-                continue
-            if part._buf_n:
-                t = int(part._buf_ts[0])
-                lo = t if lo is None or t < lo else lo
+        recent blocks to per-epoch-rebuilt tail blocks.
+
+        The walk over every lane runs once per STATE, not once per plan
+        (``shard.mutable_floor`` is the same idiom): its result stands
+        while nothing that can change a lane's write buffer, or the set
+        of lanes walked, has happened.  ``ingest_epoch`` moves with every
+        ingest batch that added rows and every chunk freeze,
+        ``removal_epoch`` with eviction, purge and page-cache eviction,
+        and the roster with a lane assigned (``_prep_for``) or pruned
+        (``_build_block``); a page-in moves none and need not (a paged
+        partition holds chunks only).  The key is read BEFORE the walk,
+        so a row ingested mid-walk leaves the memo stale, not fresh.  A
+        buffer detached for a pipelined flush (``freeze_raw``) moves no
+        epoch until its chunk freezes: the memo then reads too LOW, which
+        only sends a block down the exact tail path.  Like the tails and
+        the plan memo, it first sees a batch's rows when the batch's
+        epoch bump lands, which is before the batch is acknowledged."""
+        shard = self._shard
+        key = (shard.ingest_epoch, shard.removal_epoch, self._next_lane,
+               len(self.lane_of))
+        memo_key, lo = self._frontier
+        if memo_key != key:
+            with TRACER.stage("grid.frontier", lanes=len(self.lane_of)):
+                lo = self._earliest_buffered()
+            self._frontier = (key, lo)
+            self.frontier_walks += 1
         if lo is None:
             return 2**62
         # bucket containing lo is NOT fully frozen
         return (lo - self.epoch0 + self.gstep - 1) // self.gstep - 1
+
+    def _earliest_buffered(self) -> Optional[int]:
+        """Earliest write-buffer row's timestamp over every lane's
+        partition, or None when no lane has a row buffered: O(lanes
+        resident), so only ``_frozen_high`` calls it, on a memo miss."""
+        lo = None
+        grid_partition = self._shard.grid_partition
+        for pid in self.lane_of:
+            part = grid_partition(pid)
+            if part is not None and part._buf_n:
+                t = int(part._buf_ts[0])
+                if lo is None or t < lo:
+                    lo = t
+        return lo
 
     def _block_for(self, bi: int, lanes: int,  # holds-lock: _lock
                    frozen_hi: int,

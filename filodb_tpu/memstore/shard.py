@@ -292,37 +292,45 @@ class TimeSeriesShard:
         starts = np.concatenate(([0], np.cumsum(counts)))
         added_total = 0
         maxint = np.iinfo(np.int64).max
-        for u in range(n_uniq):
-            s0, s1 = int(starts[u]), int(starts[u + 1])
-            if s0 == s1:
-                continue  # every record of this series was watermark-skipped
-            first = int(dec.uniq_first[u])
-            try:
-                part = self._get_or_add_partition_pk(
-                    dec.partkeys[u], schema, int(dec.part_hashes[first]),
-                    int(ts_s[s0]))
-            except SeriesQuotaExceeded:
-                # over-quota NEW series: its rows drop, the rest of the
-                # container keeps ingesting (existing series unaffected)
-                self.stats.rows_quota_dropped += s1 - s0
-                self.series_quota.note_dropped_samples(
-                    parse_partkey(dec.partkeys[u]), s1 - s0)
-                continue
-            except SplitFiltered:
-                # the series belongs to the other half of a split: a
-                # child keeps only its half of the replayed parent
-                # partition (ISSUE 13)
-                self.stats.rows_split_filtered += s1 - s0
-                continue
-            added, dropped = self._ingest_series_block(
-                part, ts_s[s0:s1], [c[s0:s1] for c in cols_s])
-            added_total += added
-            self.stats.rows_ingested += added
-            self.stats.out_of_order_dropped += dropped
-            if self.index.end_time(part.part_id) != maxint:
-                self.index.mark_active(part.part_id)
-            with self._dirty_lock:
-                self._dirty_partkeys[int(groups_r[first])].add(part.part_id)
+        try:
+            for u in range(n_uniq):
+                s0, s1 = int(starts[u]), int(starts[u + 1])
+                if s0 == s1:
+                    continue  # all its records were watermark-skipped
+                first = int(dec.uniq_first[u])
+                try:
+                    part = self._get_or_add_partition_pk(
+                        dec.partkeys[u], schema, int(dec.part_hashes[first]),
+                        int(ts_s[s0]))
+                except SeriesQuotaExceeded:
+                    # over-quota NEW series: its rows drop, the rest of the
+                    # container keeps ingesting (existing series unaffected)
+                    self.stats.rows_quota_dropped += s1 - s0
+                    self.series_quota.note_dropped_samples(
+                        parse_partkey(dec.partkeys[u]), s1 - s0)
+                    continue
+                except SplitFiltered:
+                    # the series belongs to the other half of a split: a
+                    # child keeps only its half of the replayed parent
+                    # partition (ISSUE 13)
+                    self.stats.rows_split_filtered += s1 - s0
+                    continue
+                added, dropped = self._ingest_series_block(
+                    part, ts_s[s0:s1], [c[s0:s1] for c in cols_s])
+                added_total += added
+                self.stats.rows_ingested += added
+                self.stats.out_of_order_dropped += dropped
+                if self.index.end_time(part.part_id) != maxint:
+                    self.index.mark_active(part.part_id)
+                with self._dirty_lock:
+                    self._dirty_partkeys[int(groups_r[first])].add(
+                        part.part_id)
+        except BaseException:
+            # a batch that raises midway may have moved write buffers:
+            # what is cached per ingest epoch (the device grid's tails
+            # and frozen frontier, mutable_floor) must not outlive that
+            self.ingest_epoch += 1
+            raise
         if len(ts):
             self.latest_ingest_ts = max(self.latest_ingest_ts,
                                         int(ts.max()))
@@ -381,31 +389,35 @@ class TimeSeriesShard:
         if self.ingest_sched_check is not None:
             self.ingest_sched_check()
         n = 0
-        for rec in records:
-            group = rec.part_hash % self.num_groups
-            if offset <= self.group_watermarks[group]:
-                self.stats.rows_skipped += 1
-                continue
-            try:
-                part = self._get_or_add_partition(rec)
-            except SeriesQuotaExceeded:
-                self.stats.rows_quota_dropped += 1
-                self.series_quota.note_dropped_samples(rec.tags)
-                continue
-            except SplitFiltered:
-                self.stats.rows_split_filtered += 1
-                continue
-            if part.ingest(rec.timestamp, rec.values):
-                n += 1
-                self.stats.rows_ingested += 1
-            else:
-                self.stats.out_of_order_dropped += 1
-            if self.index.end_time(part.part_id) != np.iinfo(np.int64).max:
-                self.index.mark_active(part.part_id)
-            with self._dirty_lock:
-                self._dirty_partkeys[group].add(part.part_id)
-            if rec.timestamp > self.latest_ingest_ts:
-                self.latest_ingest_ts = rec.timestamp
+        try:
+            for rec in records:
+                group = rec.part_hash % self.num_groups
+                if offset <= self.group_watermarks[group]:
+                    self.stats.rows_skipped += 1
+                    continue
+                try:
+                    part = self._get_or_add_partition(rec)
+                except SeriesQuotaExceeded:
+                    self.stats.rows_quota_dropped += 1
+                    self.series_quota.note_dropped_samples(rec.tags)
+                    continue
+                except SplitFiltered:
+                    self.stats.rows_split_filtered += 1
+                    continue
+                if part.ingest(rec.timestamp, rec.values):
+                    n += 1
+                    self.stats.rows_ingested += 1
+                else:
+                    self.stats.out_of_order_dropped += 1
+                if self.index.end_time(part.part_id) != np.iinfo(np.int64).max:
+                    self.index.mark_active(part.part_id)
+                with self._dirty_lock:
+                    self._dirty_partkeys[group].add(part.part_id)
+                if rec.timestamp > self.latest_ingest_ts:
+                    self.latest_ingest_ts = rec.timestamp
+        except BaseException:
+            self.ingest_epoch += 1      # rows may have landed: see above
+            raise
         self.latest_offset = max(self.latest_offset, offset)
         if n:
             self.ingest_epoch += 1

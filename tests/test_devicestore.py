@@ -1313,3 +1313,310 @@ class TestFusedPackedHistServing:
         dense_bytes = sum(BLOCK_BUCKETS * b.width * 4
                           for b in cache.blocks.values())
         assert 0 < stats.hbm_read_bytes["compressed-hist"] < dense_bytes
+
+
+# ---------------------------------------------------------------------------
+# The frozen frontier is walked once per shard state, not once per plan
+# (PR 28): every event that can move it has to force the next plan to walk
+# ---------------------------------------------------------------------------
+
+FR_ROWS = 40
+
+
+def _fr_ingest(shard, metric, insts, row_lo, row_hi, offset, flush=False):
+    """Rows [row_lo, row_hi) of whole-number counters, one scrape a STEP
+    at one phase, for ``metric``'s ``insts``."""
+    b = RecordBuilder(DEFAULT_SCHEMAS["prom-counter"])
+    rows = np.arange(row_lo, row_hi, dtype=np.int64)
+    for i in insts:
+        tags = {"__name__": metric, "instance": f"i{i}", "_ws_": "w",
+                "_ns_": f"n{i // 8}"}
+        b.add_series(T0 + rows * STEP - STEP + 1000,
+                     [(1000.0 * (i + 1) + 7.0 * rows * (i + 1))], tags)
+    for k, c in enumerate(b.containers()):
+        shard.ingest(decode_container(c, DEFAULT_SCHEMAS), offset + k)
+    if flush:
+        shard.flush_all()
+
+
+def _fr_lookup(shard, metric, **labels):
+    flt = [ColumnFilter("_metric_", Equals(metric))] + \
+        [ColumnFilter(k, Equals(v)) for k, v in labels.items()]
+    return shard.lookup_partitions(flt, 0, 2**62)
+
+
+def _fr_cache(shard):
+    return next(iter(shard.device_caches.values()))
+
+
+def _fr_fresh_walk(cache):
+    """The frontier as the test's own walk over every lane reads it."""
+    lo = None
+    for pid in list(cache.lane_of):
+        part = cache._shard.grid_partition(pid)
+        if part is not None and part._buf_n:
+            t = int(part._buf_ts[0])
+            lo = t if lo is None else min(lo, t)
+    if lo is None:
+        return 2**62
+    return (lo - cache.epoch0 + cache.gstep - 1) // cache.gstep - 1
+
+
+def _fr_used(cache):
+    """The frontier the next plan uses."""
+    with cache._lock:
+        return cache._frozen_high()
+
+
+def _fr_agrees(shard, part_ids, n_rows):
+    """``scan_grid`` over rows [0, n_rows) against the general scan
+    path; returns the plan's segments, None where the grid declined."""
+    from filodb_tpu.ops.windows import StepRange
+    from filodb_tpu.query import rangefns
+    steps0, nsteps = _steps(n_rows)
+    got = shard.scan_grid(part_ids, F.RATE, steps0, nsteps, STEP, WINDOW)
+    if got is None:
+        return None
+    tags, vals, _tops = got
+    end = steps0 + (nsteps - 1) * STEP
+    t2, batch = shard.scan_batch(part_ids, steps0 - WINDOW, end)
+    want = np.asarray(rangefns.apply_range_function(
+        batch, StepRange(steps0, end, STEP), WINDOW, F.RATE))[:len(t2)]
+    assert [t["instance"] for t in tags] == [t["instance"] for t in t2]
+    np.testing.assert_allclose(vals, want, rtol=1e-6, equal_nan=True)
+    assert np.isfinite(vals[:, -1]).all()       # the newest rows count
+    cache = _fr_cache(shard)
+    return next(reversed(cache._plan_memo.values())).segs
+
+
+def _fr_frozen(cache, segs) -> bool:
+    """Every segment of the plan is a frozen block, none a tail."""
+    frozen = {id(blk) for blk in cache.blocks.values()}
+    return all(id(blk) in frozen for blk in segs)
+
+
+def _fr_shard():
+    ms = TimeSeriesMemStore()
+    shard = ms.setup("prom", DEFAULT_SCHEMAS, 0, StoreConfig())
+    _fr_ingest(shard, "m_a", range(6), 0, FR_ROWS, 0, flush=True)
+    res = _fr_lookup(shard, "m_a")
+    segs = _fr_agrees(shard, res.part_ids, FR_ROWS)
+    cache = _fr_cache(shard)
+    assert segs is not None and _fr_frozen(cache, segs)
+    assert _fr_used(cache) == 2**62 and cache.frontier_walks == 1
+    return shard, cache, res
+
+
+def _fr_ingest_after_plan(_tmp_path):
+    """Rows land in a staged lane's write buffer after a plan: the
+    frozen block of that range lacks them and must not be served."""
+    shard, cache, res = _fr_shard()
+    frozen = dict(cache.blocks)
+    _fr_ingest(shard, "m_a", [2], FR_ROWS, FR_ROWS + 3, 100)
+    yield shard, cache
+    segs = _fr_agrees(shard, res.part_ids, FR_ROWS + 3)
+    assert segs is not None
+    assert _fr_used(cache) < FR_ROWS + K
+    assert not any(blk is frozen.get(bi) for blk in segs for bi in frozen)
+
+
+def _fr_flush_afterwards(_tmp_path):
+    """... and once they are flushed the frontier returns, and the
+    frozen path with it."""
+    shard, cache, res = _fr_shard()
+    _fr_ingest(shard, "m_a", [2], FR_ROWS, FR_ROWS + 3, 100)
+    assert _fr_agrees(shard, res.part_ids, FR_ROWS + 3) is not None
+    assert _fr_used(cache) < 2**62
+    shard.flush_all()
+    yield shard, cache
+    assert _fr_used(cache) == 2**62
+    segs = _fr_agrees(shard, res.part_ids, FR_ROWS + 3)
+    assert segs is not None and _fr_frozen(cache, segs)
+
+
+def _fr_late_lanes_buffered(_tmp_path):
+    """A second metric of the schema gets its lanes after the walk, its
+    rows still in the write buffer."""
+    shard, cache, res = _fr_shard()
+    _fr_ingest(shard, "m_b", range(4), 0, FR_ROWS, 200)
+    # m_a's lanes alone are walked: another metric's buffer is not theirs
+    assert _fr_agrees(shard, res.part_ids, FR_ROWS) is not None
+    assert _fr_used(cache) == 2**62
+    res_b = _fr_lookup(shard, "m_b")
+    with cache._lock:       # lanes for m_b, as the next plan assigns them
+        assert cache._prep_for(res_b.part_ids) is not None
+    yield shard, cache
+    assert _fr_used(cache) == 0         # m_b's first row is bucket 1's
+    segs = _fr_agrees(shard, res_b.part_ids, FR_ROWS)
+    assert segs is not None and not _fr_frozen(cache, segs)
+
+
+def _fr_partition_removed(_tmp_path):
+    """The one partition with buffered rows is purged: what held the
+    frontier down is gone."""
+    shard, cache, _res = _fr_shard()
+    _fr_ingest(shard, "m_a", [9], 0, 5, 300)        # old rows, unflushed
+    res = _fr_lookup(shard, "m_a")
+    assert len(res.part_ids) == 7
+    assert shard.scan_grid(res.part_ids, F.RATE, *_steps(FR_ROWS), STEP,
+                           WINDOW) is not None
+    assert _fr_used(cache) == 0
+    cutoff = T0 + 20 * STEP
+    assert shard.purge_expired(0, cutoff) == 1
+    yield shard, cache
+    assert _fr_used(cache) == 2**62
+    res = _fr_lookup(shard, "m_a")
+    assert len(res.part_ids) == 6
+    # the build that meets the purged lane prunes it and declines once
+    segs = _fr_agrees(shard, res.part_ids, FR_ROWS) or \
+        _fr_agrees(shard, res.part_ids, FR_ROWS)
+    assert segs is not None and _fr_frozen(cache, segs)
+
+
+def _fr_ingest_during_walk(_tmp_path):
+    """A row lands in a lane the walk has already passed: the memo is
+    stamped with the epoch read BEFORE the walk, so it is stale."""
+    shard, cache, res = _fr_shard()
+    shard.bump_removal_epoch()          # any event: the next plan walks
+    pids = list(cache.lane_of)
+    inner = shard.grid_partition
+
+    def racing(pid):
+        if pid == pids[-1] and shard.grid_partition is racing:
+            shard.grid_partition = inner        # once
+            _fr_ingest(shard, "m_a", [0], FR_ROWS, FR_ROWS + 1, 400)
+        return inner(pid)
+
+    shard.grid_partition = racing
+    try:
+        assert _fr_used(cache) == 2**62     # the walk missed the row
+    finally:
+        shard.grid_partition = inner
+    assert cache._frontier[0][0] != shard.ingest_epoch
+    yield shard, cache
+    assert _fr_used(cache) < 2**62
+    assert _fr_agrees(shard, res.part_ids, FR_ROWS + 1) is not None
+
+
+def _fr_batch_raises_midway(_tmp_path):
+    """An ingest batch that raises after rows landed still moves the
+    epoch: the retry adds nothing (its rows are duplicates) and would
+    move none."""
+    shard, cache, res = _fr_shard()
+    b = RecordBuilder(DEFAULT_SCHEMAS["prom-counter"])
+    rows = np.arange(FR_ROWS, FR_ROWS + 2, dtype=np.int64)
+    b.add_series(T0 + rows * STEP - STEP + 1000, [7000.0 + rows],
+                 {"__name__": "m_a", "instance": "i3", "_ws_": "w",
+                  "_ns_": "n0"})
+    recs = list(decode_container(b.containers()[0], DEFAULT_SCHEMAS))
+
+    def batch():
+        yield from recs
+        raise OSError("the stream broke")
+
+    with pytest.raises(OSError):
+        shard.ingest(batch(), 500)
+    assert shard.ingest(recs, 501) == 0
+    yield shard, cache
+    assert _fr_used(cache) < 2**62
+    assert _fr_agrees(shard, res.part_ids, FR_ROWS + 2) is not None
+
+
+def _fr_odp_page_in_and_evict(tmp_path):
+    """An ``OnDemandPagingShard``: eviction takes the partitions with
+    their buffers away, a page-in brings them back as chunks alone, and
+    page-cache eviction takes them away again."""
+    from filodb_tpu.memstore.odp import OnDemandPagingShard
+    from filodb_tpu.store.persistence import (DiskColumnStore,
+                                              DiskMetaStore)
+    store = TimeSeriesMemStore(DiskColumnStore(str(tmp_path / "c.db")),
+                               DiskMetaStore(str(tmp_path / "m.db")))
+    shard = store.setup("prom", DEFAULT_SCHEMAS, 0,
+                        StoreConfig(groups_per_shard=2))
+    assert isinstance(shard, OnDemandPagingShard)
+    _fr_ingest(shard, "m_a", range(6), 0, FR_ROWS, 0, flush=True)
+    _fr_ingest(shard, "m_a", range(6), FR_ROWS, FR_ROWS + 2, 100)
+    res = _fr_lookup(shard, "m_a")
+    assert _fr_agrees(shard, res.part_ids, FR_ROWS + 2) is not None
+    cache = _fr_cache(shard)
+    assert _fr_used(cache) < 2**62      # the buffers hold it down
+    assert shard.evict_partitions(6) == 6           # flushes, then drops
+    shard.scan_batch(res.part_ids, 0, 2**62)        # pages all six in
+    assert shard.stats.partitions_paged == 6
+    yield shard, cache
+    assert _fr_used(cache) == 2**62
+    segs = _fr_agrees(shard, res.part_ids, FR_ROWS + 2)
+    assert segs is not None and _fr_frozen(cache, segs)
+    walks = cache.frontier_walks
+    shard.paged.max_bytes = 1
+    shard.paged.put(999_999, object(), 10)          # LRU pressure
+    assert _fr_used(cache) == _fr_fresh_walk(cache)
+    assert cache.frontier_walks == walks + 1
+    # declined (a lane's partition is paged out) or re-paged and right
+    _fr_agrees(shard, res.part_ids, FR_ROWS + 2)
+
+
+class TestFrozenFrontierMemo:
+    @pytest.mark.parametrize("event", [
+        _fr_ingest_after_plan, _fr_flush_afterwards,
+        _fr_late_lanes_buffered, _fr_partition_removed,
+        _fr_odp_page_in_and_evict, _fr_ingest_during_walk,
+        _fr_batch_raises_midway], ids=lambda f: f.__name__[4:])
+    def test_event_invalidates_the_frontier(self, event, tmp_path):
+        """After each event (a) the frontier the next plan uses is a
+        fresh walk's and (b) ``scan_grid`` agrees with the general scan
+        path on the rows involved (the case's own assertions, after its
+        ``yield``)."""
+        case = event(tmp_path)
+        shard, cache = next(case)
+        fresh = _fr_fresh_walk(cache)
+        assert _fr_used(cache) == fresh
+        walks = cache.frontier_walks
+        assert _fr_used(cache) == fresh             # ... and is memoized
+        assert cache.frontier_walks == walks
+        assert next(case, None) is None
+
+    @pytest.mark.parametrize("namespaces", [10, 30])
+    def test_a_plan_costs_the_lanes_requested(self, namespaces):
+        """Counted, not timed: with N lanes staged, a plan on a memo
+        miss asks ``grid_partition`` about the ids requested and no
+        others, and the walk over all N runs once for the state."""
+        from filodb_tpu.utils.observability import TRACER
+        ms = TimeSeriesMemStore()
+        shard = ms.setup("prom", DEFAULT_SCHEMAS, 0, StoreConfig())
+        n = 8 * namespaces
+        _fr_ingest(shard, "m_a", range(n), 0, FR_ROWS, 0, flush=True)
+        res = _fr_lookup(shard, "m_a")
+        assert _fr_agrees(shard, res.part_ids, FR_ROWS) is not None
+        cache = _fr_cache(shard)
+        assert len(cache.lane_of) == n
+
+        def walks():
+            return TRACER.stages.snapshot().get(
+                "grid.frontier", {"count": 0})["count"]
+
+        calls = []
+        inner = shard.grid_partition
+        shard.grid_partition = lambda pid: calls.append(pid) or inner(pid)
+        walks0, per_plan = walks(), []
+        steps0, nsteps = _steps(FR_ROWS)
+        args = (F.RATE, steps0, nsteps, STEP, WINDOW, ())
+        try:
+            for ns in range(10):
+                ids = _fr_lookup(shard, "m_a", _ns_=f"n{ns}").part_ids
+                assert len(ids) == 8
+                before = len(calls)
+                with cache._lock:
+                    plan = cache._plan_staged(ids, *args)
+                assert plan is not None and plan.ncols >= n
+                per_plan.append(len(calls) - before)
+            with cache._lock:
+                again = cache._plan_staged(ids, *args)
+        finally:
+            shard.grid_partition = inner
+        assert again is plan                # the memoized plan object
+        # the first id's schema check and one visit of each id: the same
+        # whatever is resident
+        assert per_plan == [1 + 8] * 10
+        assert walks() - walks0 <= 1
+        assert cache.frontier_walks <= 2

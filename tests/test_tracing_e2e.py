@@ -618,3 +618,41 @@ class TestStageClockEndpoints:
         text = urllib.request.urlopen(url, timeout=10).read().decode()
         assert 'filodb_kernel_launches_total{program="devicestore.grouped"}' \
             in text
+
+
+def test_frontier_walk_is_a_stage_and_a_tag_of_the_plan(monkeypatch):
+    """PR 28: the walk over every resident lane runs once per shard
+    state, as the leaf stage ``grid.frontier`` inside ``grid.plan``; a
+    plan that found the frontier memoized adds no such span, and says
+    so in its ``frontier`` tag."""
+    srv, port = _grid_server("solo", monkeypatch)
+    query = 'sum(rate(c_total{_ws_="w",_ns_="n"}[5m]))'
+
+    def ask(first_step):
+        _c, dev, _h = _get(port, "/admin/device")
+        before = dev["data"]["stages"].get("grid.frontier", {"count": 0})
+        code, body, _h = _grid_query(port, query, first_step=first_step)
+        assert code == 200 and body["data"]["result"]
+        _c, dev, _h = _get(port, "/admin/device")
+        after = dev["data"]["stages"].get("grid.frontier", {"count": 0})
+        tid = body["data"]["stats"]["traceId"]
+        _c, tbody, _h = _get(port, f"/admin/traces/{tid}")
+        flat = _flatten(tbody["data"]["spans"])
+        plan = [n for n in flat if n["name"] == "grid.plan"][0]
+        return (after["count"] - before["count"], plan,
+                body["data"]["stats"]["timings"])
+
+    try:
+        walked, plan, timings = ask(7)          # the first plan walks
+        assert walked == 1 and plan["tags"]["frontier"] == "walk"
+        assert [n["name"] for n in plan["children"]
+                if n["name"] == "grid.frontier"] == ["grid.frontier"]
+        assert 0.0 <= timings["grid.frontier"] <= timings["grid.plan"]
+        for first_step in (8, 8):   # a plan-memo miss, then a plan-memo hit
+            walked, plan, timings = ask(first_step)
+            assert walked == 0 and plan["tags"]["frontier"] == "memo"
+            assert not [n for n in plan["children"]
+                        if n["name"] == "grid.frontier"]
+            assert "grid.frontier" not in timings
+    finally:
+        srv.shutdown()
