@@ -126,6 +126,14 @@ class QueryBatcher:
         if hot_ttl_s is not None:
             self.hot_ttl_s = float(hot_ttl_s)
 
+    def stack_sizes(self) -> list:
+        """Every leading-axis size a stacked launch can have (the
+        powers of two ``_pad_pow2`` pads to): what the caller compiles
+        when a plan shape first stacks, so that no co-arrival later
+        waits for a compile."""
+        return sorted({_pad_pow2(n, self.max_batch)
+                       for n in range(2, self.max_batch + 1)})
+
     def snapshot(self) -> dict:
         return {"enabled": self.enabled, "window_ms": self.window_ms,
                 "max_batch": self.max_batch,

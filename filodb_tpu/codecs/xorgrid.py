@@ -156,7 +156,8 @@ def _align_classes(by_cls: list[list], widths: tuple, itemsize: int,
 
 def pack_vals(vals: np.ndarray, lane_block: int = LANE_BLOCK,
               phase: Optional[np.ndarray] = None,
-              min_width: int = 0, stride: int = 1) -> Optional[PackedVals]:
+              min_width: int = 0, stride: int = 1,
+              agree=None) -> Optional[PackedVals]:
     """Pack a ``[B, L]`` f32/f64 value plane into XOR-class form.
 
     Returns None when compression doesn't pay (packed footprint must
@@ -177,7 +178,15 @@ def pack_vals(vals: np.ndarray, lane_block: int = LANE_BLOCK,
     guarantee the fused hist kernels (ops/grid.py
     ``hist_grid_grouped_packed``) rely on to reduce the bucket
     dimension with banded matmuls.  ``unpack_vals`` stays bit-exact
-    for every stride."""
+    for every stride.
+
+    ``agree`` (``{class plane: lanes} -> {class plane: lanes}``) is told
+    what each class plane needs once aligned and answers what it is to
+    hold: a plane is padded up to the answer with zero lanes, and a class
+    this plane has no lane of comes to exist as pad alone.  It is how
+    planes packed apart (the shards of one dataset:
+    memstore/gridshapes.py) come out with the same shapes.  An answer is
+    the largest of aligned needs, so it is aligned itself."""
     B, L = vals.shape
     if B == 0 or L == 0:
         return None
@@ -210,6 +219,13 @@ def pack_vals(vals: np.ndarray, lane_block: int = LANE_BLOCK,
     by_cls.append(list(np.flatnonzero(cls == len(widths))))   # raw
     pads = _align_classes(by_cls, widths, itemsize, B, lane_block,
                           stride=stride)
+    class_keys = [f"p{w}" for w in widths] + ["raw"]
+    if agree is not None:
+        need = {k: len(by_cls[i]) + pads[i]
+                for i, k in enumerate(class_keys) if by_cls[i] or pads[i]}
+        want = agree(need)
+        pads = [max(pads[i], want.get(k, 0) - len(by_cls[i]))
+                for i, k in enumerate(class_keys)]
     # canonical order: ascending original lane within each class, so a
     # single-class pack is the IDENTITY permutation (the group-aligned
     # contract rate_grid_grouped_packed relies on)
@@ -218,7 +234,7 @@ def pack_vals(vals: np.ndarray, lane_block: int = LANE_BLOCK,
     order_parts: list[np.ndarray] = []
     first_parts: list[np.ndarray] = []
     meta = itemsize == 4                 # fused kernels are f32-only
-    for i, key in enumerate([f"p{w}" for w in widths] + ["raw"]):
+    for i, key in enumerate(class_keys):
         lanes_i = np.asarray(by_cls[i], dtype=np.int64)
         n = len(lanes_i) + pads[i]
         if n == 0:

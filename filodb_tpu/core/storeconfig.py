@@ -83,7 +83,7 @@ class StoreConfig:
                                                  d.page_cache_bytes)),
             grid_step_ms=(parse_duration_ms(conf["grid-step"])
                           if "grid-step" in conf else None),
-            device_cache_compress=parse_bool(
+            device_cache_compress=parse_plane_compress(
                 conf.get("device-cache-compress",
                          d.device_cache_compress)),
             device_headroom_frac=float(
@@ -139,6 +139,19 @@ def parse_bool(v) -> bool:
             return False
         raise ValueError(f"not a boolean config value: {v!r}")
     return bool(v)
+
+
+def parse_plane_compress(v) -> bool:
+    """``device-cache-compress``: a boolean, or ``dataset``: compressed, by
+    a configuration that counts on the dataset's local shards agreeing on
+    one set of plane shapes (memstore/gridshapes.py), so that what the node
+    holds in HBM does not depend on which shard was asked first.  No switch:
+    the caches agree whatever is written here.  The word is for a program
+    from before they could: its boolean parser refuses it, and that node
+    does not start on a configuration it cannot keep."""
+    if isinstance(v, str) and v.strip().lower() == "dataset":
+        return True
+    return parse_bool(v)
 
 
 def parse_duration_ms(v) -> int:

@@ -43,6 +43,7 @@ from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 
+from filodb_tpu.memstore.gridshapes import pad_lanes
 from filodb_tpu.ops.grid import (DENSE_ONLY_OPS, PHASE_OPS, TS_FREE_OPS,
                                  GridQuery, lane_tile, max_k_for,
                                  on_tpu_backend, phase_eligible,
@@ -53,7 +54,6 @@ from filodb_tpu.utils.devicewatch import FLIGHT, LEDGER
 from filodb_tpu.utils.observability import TRACER
 
 BLOCK_BUCKETS = 128
-_LANE_PAD = 128
 _I32_SPAN = 2**31 - 2
 
 # range functions the aligned grid can serve, mapped to the fused
@@ -387,6 +387,20 @@ def _fused_progs():
     _FUSED_PROGS["series_batch"] = staged(series_batch_prog)
     _FUSED_PROGS["grouped_batch"] = staged(grouped_batch_prog)
     return _FUSED_PROGS
+
+
+def _other_stack_sizes(row0s, steps0s, sizes):
+    """What a co-arrival group's launch is followed by where it compiled
+    (its program holds more executables than before: this size is the
+    first of its plan shape to stack): the ``(row0s, steps0s)`` of one
+    launch at each OTHER stack size, over copies of the group's first
+    member.  A shape that stacks at all soon stacks at every size, and a
+    size first met later would compile then, with a whole group waiting
+    on it.  What is loaded then depends on which shapes have stacked,
+    not on which sizes happened to meet first."""
+    for k in sizes:
+        if k != len(row0s):
+            yield np.full(k, row0s[0]), np.full(k, steps0s[0])
 
 
 def _fetch(out, dtype=None) -> np.ndarray:
@@ -940,11 +954,19 @@ class DeviceGridCache:
                garr.tobytes())
         prog = _fused_progs()["grouped_batch"]
 
+        kw = dict(q=plan.q, lanes=plan.lane_mult, nrows=plan.nrows,
+                  num_groups=num_groups, op=op)
+
         def batch_launch(row0s, steps0s):
+            loaded = prog._jitted._cache_size()
             out = _fused_progs()["grouped_batch"](
-                plan.ts_parts, plan.val_parts, row0s, steps0s, garr,
-                plan.phase, q=plan.q, lanes=plan.lane_mult,
-                nrows=plan.nrows, num_groups=num_groups, op=op)
+                plan.ts_parts, plan.val_parts, row0s, steps0s,
+                garr, plan.phase, **kw)
+            if prog._jitted._cache_size() > loaded:
+                for r0s, s0s in _other_stack_sizes(row0s, steps0s,
+                                                   batcher.stack_sizes()):
+                    prog(plan.ts_parts, plan.val_parts, r0s, s0s, garr,
+                         plan.phase, **kw)
             _note_kernel_bytes(prog, plan)
             return _fetch(out, np.float64)  # host-sync-ok: ONE stacked readback of the group's reduced partials
 
@@ -1081,11 +1103,18 @@ class DeviceGridCache:
                plan.q, plan.lane_mult, plan.nrows)
         prog = _fused_progs()["series_batch"]
 
+        kw = dict(q=plan.q, lanes=plan.lane_mult, nrows=plan.nrows)
+
         def batch_launch(row0s, steps0s):
+            loaded = prog._jitted._cache_size()
             out = _fused_progs()["series_batch"](
                 plan.ts_parts, plan.val_parts, row0s, steps0s,
-                plan.phase, q=plan.q, lanes=plan.lane_mult,
-                nrows=plan.nrows)
+                plan.phase, **kw)
+            if prog._jitted._cache_size() > loaded:
+                for r0s, s0s in _other_stack_sizes(row0s, steps0s,
+                                                   batcher.stack_sizes()):
+                    prog(plan.ts_parts, plan.val_parts, r0s, s0s,
+                         plan.phase, **kw)
             _note_kernel_bytes(prog, plan)
             return _fetch(out)  # host-sync-ok: ONE stacked [B, T, lanes] readback serves the whole co-arrival group
 
@@ -1293,8 +1322,12 @@ class DeviceGridCache:
         prep = self._prep_for(part_ids, fp=ids_fp)
         if prep is None:
             return None
-        lanes = max(_LANE_PAD,
-                    -(-self._next_lane // _LANE_PAD) * _LANE_PAD)
+        lanes = pad_lanes(self._next_lane)
+        shapes = self._shard.grid_shapes
+        if shapes is not None:
+            # ... or the widest sibling shard's, where that is close: one
+            # width a dataset, so that its shards share their programs
+            lanes = shapes.lanes_for(lanes)
         if any(b.lanes != lanes for b in self.blocks.values()):
             self.blocks.clear()                # widths must match to concat
             self._tails.clear()
@@ -1604,9 +1637,17 @@ class DeviceGridCache:
         """Host staging + one upload for block ``bi``, as the
         ``grid.build`` stage (most of a cold node's set-up)."""
         with TRACER.stage("grid.build", lanes=lanes, compressed=compress):
-            return self._build_block(bi, lanes, compress)
+            shapes = self._shard.grid_shapes if compress else None
+            if shapes is None:
+                return self._build_block(bi, lanes, compress, None)
+            # the dataset's other shards build this block now too (the
+            # query fanned out): their packs wait for each other and
+            # come out with the same class widths
+            with shapes.building((self.schema_hash, self.column_id, bi,
+                                  lanes)) as agree:
+                return self._build_block(bi, lanes, compress, agree)
 
-    def _build_block(self, bi: int, lanes: int, compress: bool):
+    def _build_block(self, bi: int, lanes: int, compress: bool, agree):
         g = self.gstep
         stride = self.hb if self.hist else 1
         # block bi holds buckets [bi*BB, bi*BB+BB-1]; bucket c covers
@@ -1716,7 +1757,7 @@ class DeviceGridCache:
         # in bucket order — the layout contract of the fused hist
         # kernels (ops/grid.py hist_grid_grouped_packed)
         packed = xorgrid.pack_vals(val_stage, phase=phase,
-                                   stride=stride) \
+                                   stride=stride, agree=agree) \
             if do_compress else None
         pack_inv = None
         if packed is not None:

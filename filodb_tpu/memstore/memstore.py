@@ -16,6 +16,7 @@ import numpy as np
 from filodb_tpu.core.filters import ColumnFilter
 from filodb_tpu.core.schemas import Schemas
 from filodb_tpu.core.storeconfig import StoreConfig
+from filodb_tpu.memstore.gridshapes import GridShapes
 from filodb_tpu.memstore.shard import PartLookupResult, TimeSeriesShard
 from filodb_tpu.store.columnstore import ColumnStore, NullColumnStore
 from filodb_tpu.store.metastore import InMemoryMetaStore, MetaStore
@@ -32,6 +33,9 @@ class TimeSeriesMemStore:
         self.meta = meta_store or InMemoryMetaStore()
         self._datasets: dict[str, dict[int, TimeSeriesShard]] = {}
         self._schemas: dict[str, Schemas] = {}
+        # dataset -> what its local shards' device caches agree on
+        # (memstore/gridshapes.py)
+        self._grid_shapes: dict = {}
         # elastic resharding (ISSUE 13): runs on every new shard BEFORE
         # any ingest can reach it — the split participant installs the
         # child half-filter here, so a child shard can never materialize
@@ -57,6 +61,10 @@ class TimeSeriesMemStore:
                                     self.store, self.meta)
         shards[shard_num] = shard
         self._schemas[dataset] = schemas
+        if dataset not in self._grid_shapes:
+            self._grid_shapes[dataset] = GridShapes(  # filolint: disable=bounded-cache — one small object a dataset, beside _datasets' own entry
+                lambda: self.shards(dataset))
+        shard.grid_shapes = self._grid_shapes[dataset]
         if self.shard_setup_hook is not None:
             self.shard_setup_hook(dataset, shard)
         return shard

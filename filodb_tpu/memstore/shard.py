@@ -176,6 +176,9 @@ class TimeSeriesShard:
         # (parallel/meshgrid.py) reads them in place — the multi-device
         # analog of BlockManager-resident serving
         self.grid_device = None
+        # shape agreement with the dataset's other local shards
+        # (memstore/gridshapes.py; the memstore sets it at setup)
+        self.grid_shapes = None
         # monotone counter observed by the device caches' tail versioning:
         # bumped whenever new rows or chunks could change query results
         self.ingest_epoch = 0

@@ -9,6 +9,7 @@ path; the shard/mesh dispatchers live in filodb_tpu.parallel.
 
 from __future__ import annotations
 
+import collections
 import concurrent.futures
 import dataclasses
 import threading
@@ -30,6 +31,26 @@ from filodb_tpu.query.model import (PeriodicBatch, QueryContext, QueryError,
                                     concat_periodic)
 from filodb_tpu.query.transformers import RangeVectorTransformer, _drop_metric
 from filodb_tpu.utils.observability import TRACER
+
+# helpers of every non-leaf plan's fan-out, process-wide: a pool made
+# and torn down inside each request starts a thread a child, and a
+# thread's start waits for the interpreter's turn (PERF.md, PR 29)
+FANOUT_THREADS = 32
+_FANOUT_POOL: Optional[concurrent.futures.ThreadPoolExecutor] = None
+_FANOUT_POOL_LOCK = threading.Lock()
+
+
+def _fanout_pool() -> concurrent.futures.ThreadPoolExecutor:
+    global _FANOUT_POOL
+    pool = _FANOUT_POOL
+    if pool is None:
+        with _FANOUT_POOL_LOCK:
+            pool = _FANOUT_POOL
+            if pool is None:
+                pool = _FANOUT_POOL = concurrent.futures.ThreadPoolExecutor(
+                    FANOUT_THREADS, thread_name_prefix="exec-fanout")
+    return pool
+
 
 # the ExecContext of the scan running on THIS thread: lower layers that
 # have no ctx parameter (ODP page-in, predecode) attribute their stage
@@ -305,9 +326,7 @@ class ExecPlan:
             tags["shard"] = getattr(self, "shard", "")
         try:
             with TRACER.span("execplan.execute", **tags):
-                batches = self.do_execute(ctx)
-                for t in self.transformers:
-                    batches = t.apply(batches, ctx)
+                batches = self._run(ctx)
                 self._enforce_limits(batches, ctx)
                 stats = self._collect_stats(batches)
                 # quarantined-chunk exclusions accumulate on the shared
@@ -323,6 +342,13 @@ class ExecPlan:
         except Exception as e:  # noqa: BLE001 - plan failure surfaces as QueryError
             raise QueryError(self.query_context.query_id,
                              f"{type(self).__name__}: {e}") from e
+
+    def _run(self, ctx: ExecContext) -> list:
+        """This node's own work and its transformers."""
+        batches = self.do_execute(ctx)
+        for t in self.transformers:
+            batches = t.apply(batches, ctx)
+        return batches
 
     def _enforce_limits(self, batches, ctx):
         total = 0
@@ -374,9 +400,23 @@ class NonLeafExecPlan(ExecPlan):
     def children(self) -> Sequence[ExecPlan]:
         return self._children
 
-    def do_execute(self, ctx: ExecContext) -> list:
-        results = self._dispatch_children(ctx)
-        return self.compose(results, ctx)
+    def _run(self, ctx: ExecContext) -> list:
+        """The scatter-gather's two stages: ``exec.fanout``, the wait
+        for the children (their own stages name the work), and
+        ``exec.compose``, this node's reduce and its transformers.  Both
+        land in the query's timings, summed over the plan's non-leaf
+        nodes."""
+        name = type(self).__name__
+        with TRACER.stage("exec.fanout", leaf=False, plan=name,
+                          children=len(self._children)) as fan:
+            results = self._dispatch_children(ctx)
+        with TRACER.stage("exec.compose", plan=name) as comp:
+            batches = self.compose(results, ctx)
+            for t in self.transformers:
+                batches = t.apply(batches, ctx)
+        ctx.note_timings((("exec.fanout", fan.duration_s),
+                          ("exec.compose", comp.duration_s)))
+        return batches
 
     def _dispatch_children(self, ctx) -> list[QueryResult]:
         """Children run via their own dispatchers, concurrently (reference:
@@ -408,16 +448,44 @@ class NonLeafExecPlan(ExecPlan):
 
         if len(kids) <= 1 or not self.parallel_children:
             return [one(c) for c in kids]
+        # at most ``parallelism`` children at a time: this thread runs
+        # its share itself and helpers of the shared pool the rest, all
+        # drawing from one queue.  A helper that starts late finds the
+        # queue empty, so a busy pool costs concurrency, never progress
+        # (a nested plan's helpers cannot wait for each other)
+        results: list = [None] * len(kids)
+        todo = collections.deque(range(len(kids)))
+        failed: list = []
+
+        def drain():
+            while True:
+                try:
+                    i = todo.popleft()
+                except IndexError:
+                    return
+                try:
+                    results[i] = one(kids[i])
+                except BaseException as e:  # noqa: BLE001 — re-raised by the caller below
+                    failed.append(e)
+                    todo.clear()
+                    return
+
         token = TRACER.capture()
 
-        def run(c):
+        def helper():
             with TRACER.attach(token):
-                return one(c)
+                drain()
 
-        with concurrent.futures.ThreadPoolExecutor(
-                max_workers=min(len(kids), ctx.parallelism)) as pool:
-            futs = [pool.submit(run, c) for c in kids]
-            return [f.result() for f in futs]
+        pool = _fanout_pool()
+        helpers = [pool.submit(helper)
+                   for _ in range(min(len(kids), ctx.parallelism) - 1)]
+        drain()
+        for f in helpers:
+            if not f.cancel():
+                f.result()
+        if failed:
+            raise failed[0]
+        return results
 
     def compose(self, results: list[QueryResult], ctx) -> list:
         raise NotImplementedError

@@ -855,10 +855,19 @@ class FiloServer:
         bat_conf = dict(ds_conf.get("batching",
                                     self.config.get("batching", {})))
         from filodb_tpu.batching import QueryBatcher
+        # a stack is no larger than the queries that run at once: the
+        # query workers, and the leaf workers where peers send this node
+        # leaves (more only where one query has several leaves of one
+        # shape on one shard; they form a second group).  Every stack
+        # size is a program of its own a plan shape, compiled and kept
+        # in HBM
+        reach = int(qconf.get("workers", 4))
+        if self.config.get("peers"):
+            reach += int(qconf.get("leaf-workers", qconf.get("workers", 4)))
         batcher = QueryBatcher(
             enabled=bool(bat_conf.get("enabled", True)),
             window_ms=float(bat_conf.get("window-ms", 3.0)),
-            max_batch=int(bat_conf.get("max-batch", 8)),
+            max_batch=min(int(bat_conf.get("max-batch", 8)), max(reach, 2)),
             hot_ttl_s=float(bat_conf.get("hot-ttl-s", 10.0)),
             dataset=name,
             ledger=lambda: self.http.insights)

@@ -188,6 +188,70 @@ def test_grouped_agg_batched_bit_equal():
     shard.query_batcher = None
 
 
+@pytest.mark.parametrize("grouped", [False, True],
+                         ids=["series", "grouped"])
+def test_a_shape_that_stacks_brings_every_stack_size_in(grouped):
+    """ISSUE 29: the first co-arrival group of a plan shape compiles the
+    shape's stacked programs of EVERY size, so that no later group,
+    whatever its size, waits for a compile; a shape that never stacks
+    (a lone query) compiles none."""
+    from filodb_tpu.memstore.devicestore import _fused_progs
+    _ms, shard = _mk_shard(n_series=5, seed=29)
+    pids = _part_ids(shard)
+    steps0 = T0 + (K - 1) * STEP
+    nsteps = 17 if grouped else 19      # shapes no other test compiles
+    gids = list(range(len(pids)))
+
+    def run(s0):
+        if grouped:
+            return shard.scan_grid_grouped(pids, F.RATE, s0, nsteps, STEP,
+                                           WINDOW, gids, len(pids), "sum")
+        return shard.scan_grid(pids, F.SUM_OVER_TIME, s0, nsteps, STEP,
+                               WINDOW)
+
+    def together(n):
+        barrier = threading.Barrier(n)
+        outs: dict = {}
+
+        def worker(i):
+            barrier.wait()
+            outs[i] = run(steps0 + i * STEP)
+
+        ts = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+        assert len(outs) == n and all(o is not None for o in outs.values())
+
+    bat = QueryBatcher(enabled=True, window_ms=150.0, max_batch=8,
+                       hot_ttl_s=30.0, dataset="prom")
+    assert bat.stack_sizes() == [2, 4, 8]
+    assert QueryBatcher(max_batch=6).stack_sizes() == [2, 4, 6]
+    assert QueryBatcher(max_batch=1).stack_sizes() == []
+    shard.query_batcher = bat
+    stacked = _fused_progs()["grouped_batch" if grouped
+                             else "series_batch"]._jitted
+    before = stacked._cache_size()
+    assert run(steps0) is not None and run(steps0 + STEP) is not None
+    assert stacked._cache_size() == before      # lone queries: no stack
+    for _round in range(12):
+        # three at once: one passes alone (a cold key), the next leads
+        # a group, the third joins it
+        together(3)
+        if bat.snapshot()["realized_peak"] >= 2:
+            break
+    assert bat.snapshot()["realized_peak"] == 2
+    assert stacked._cache_size() == before + 3  # sizes 2, 4 and 8
+    for n in (4, 6, 9):                         # stacks of 3..8, padded
+        for _round in range(4):
+            together(n)
+    assert bat.snapshot()["realized_peak"] > 2
+    assert stacked._cache_size() == before + 3
+    assert not batching_broken()
+    shard.query_batcher = None
+
+
 # ---------------------------------------------------------------------------
 # config / passthrough
 # ---------------------------------------------------------------------------

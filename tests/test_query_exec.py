@@ -3,6 +3,8 @@ in-process memstore (reference test pattern: direct ExecPlan construction
 with InProcessPlanDispatcher, MultiSchemaPartitionsExecSpec,
 AggrOverRangeVectorsSpec, BinaryJoinExecSpec — SURVEY.md §4)."""
 
+import threading
+
 import numpy as np
 import pytest
 
@@ -613,3 +615,110 @@ class TestHistMaxSchema:
             jnp.asarray(b2.bucket_tops), jnp.asarray(b2.hist),
             jnp.asarray(b2.values), 0.9))
         np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+class _Probe(MultiSchemaPartitionsExec):
+    """A leaf that answers with its own number after a short sleep, and
+    counts how many of its kind run at once."""
+
+    running = 0
+    peak = 0
+    lock = threading.Lock()
+
+    def __init__(self, i, fail=False):
+        super().__init__("ds", 0, [], 0, 0)
+        self.i, self.fail = i, fail
+
+    def execute(self, ctx):
+        import time
+        from filodb_tpu.query.model import QueryResult, QueryStats
+        cls = _Probe
+        with cls.lock:
+            cls.running += 1
+            cls.peak = max(cls.peak, cls.running)
+        try:
+            time.sleep(0.01)
+            if self.fail:
+                raise QueryError("t1", f"child {self.i} failed")
+            return QueryResult("t1", [self.i], QueryStats())
+        finally:
+            with cls.lock:
+                cls.running -= 1
+
+
+class _Gather(DistConcatExec):
+    def compose(self, results, ctx):
+        return [b for r in results for b in r.batches]
+
+
+class TestFanOut:
+    """ISSUE 29: children run on this thread and on helpers of one
+    shared pool, never on a pool made for the request."""
+
+    @pytest.fixture(autouse=True)
+    def _reset(self):
+        _Probe.running = _Probe.peak = 0
+
+    def test_results_keep_the_children_order_under_the_cap(self, ms):
+        ctx = ExecContext(ms, QueryContext(query_id="t1"), parallelism=3)
+        got = _Gather([_Probe(i) for i in range(20)]).execute(ctx).batches
+        assert got == list(range(20))
+        assert 2 <= _Probe.peak <= 3
+
+    def test_one_child_and_serial_plans_use_no_helper(self, ms, monkeypatch):
+        import filodb_tpu.query.exec as qe
+        monkeypatch.setattr(qe, "_fanout_pool", lambda: pytest.fail(
+            "a one-child or serial plan asked for the pool"))
+        ctx = ExecContext(ms, QueryContext(query_id="t1"))
+        assert _Gather([_Probe(7)]).execute(ctx).batches == [7]
+        serial = _Gather([_Probe(i) for i in range(4)])
+        serial.parallel_children = False
+        assert serial.execute(ctx).batches == [0, 1, 2, 3]
+        assert _Probe.peak == 1
+
+    def test_a_failing_child_fails_the_plan_and_stops_the_rest(self, ms):
+        ctx = ExecContext(ms, QueryContext(query_id="t1"), parallelism=2)
+        kids = [_Probe(i, fail=(i == 1)) for i in range(40)]
+        with pytest.raises(QueryError, match="child 1 failed"):
+            _Gather(kids).execute(ctx)
+        assert _Probe.running == 0
+
+    def test_nested_plans_finish_on_a_pool_that_is_full(self, ms,
+                                                        monkeypatch):
+        """Sixteen queries at once, each a plan of plans, over a pool
+        of two helpers: a helper that never starts costs concurrency
+        only (the caller drains the queue itself), so nothing waits
+        for a thread that waits for it."""
+        import concurrent.futures
+        import sys
+        import filodb_tpu.query.exec as qe
+        small = concurrent.futures.ThreadPoolExecutor(2)
+        monkeypatch.setattr(qe, "_fanout_pool", lambda: small)
+        out, errs = {}, []
+
+        def query(q):
+            try:
+                ctx = ExecContext(ms, QueryContext(query_id="t1"))
+                root = _Gather([_Gather([_Probe(10 * j + i)
+                                         for i in range(4)])
+                                for j in range(4)])
+                out[q] = root.execute(ctx).batches
+            except BaseException as e:  # noqa: BLE001 — asserted below
+                errs.append(e)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-4)
+        try:
+            ts = [threading.Thread(target=query, args=(q,))
+                  for q in range(16)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in ts)
+        finally:
+            sys.setswitchinterval(old)
+            small.shutdown(wait=True)
+        assert not errs, errs
+        want = [10 * j + i for j in range(4) for i in range(4)]
+        assert all(out[q] == want for q in range(16))

@@ -316,6 +316,48 @@ class TestSpanTagSatellites:
             assert rc["recomputedSamples"] == 0
 
 
+class TestScatterGatherStages:
+    """ISSUE 29: a non-leaf plan's wait for its children and its own
+    reduce are stages — in the stage table, in ``timings`` under
+    ``stats=true`` and in the trace, tagged with the fan-out."""
+
+    @pytest.mark.parametrize("dataset,metric,children", [
+        ("prom", "trace_total", 2),       # spread 1: two of four shards
+        ("proml", "local_total", 1)])     # one shard: the inline child
+    def test_fanout_and_compose(self, cluster, dataset, metric, children):
+        from filodb_tpu.utils.observability import TRACER
+        before = TRACER.stages.snapshot()
+        code, body, _ = _get(
+            cluster["port_a"], f"/promql/{dataset}/api/v1/query_range",
+            query=f'sum(rate({metric}{{_ws_="demo",_ns_="App-0",'
+                  f'instance!="sg"}}[2m]))',
+            start=(BASE + 600_000) / 1000, end=(BASE + 1_200_000) / 1000,
+            step="30s", stats="true")
+        assert code == 200 and len(body["data"]["result"]) == 1
+        timings = body["data"]["stats"]["timings"]
+        assert timings["exec.fanout"] > 0.0 and timings["exec.compose"] > 0.0
+        assert timings["exec.fanout"] + timings["exec.compose"] \
+            <= timings["total"]
+        after = TRACER.stages.snapshot()
+        for name in ("exec.fanout", "exec.compose"):
+            assert after[name]["count"] \
+                == before.get(name, {"count": 0})["count"] + 1
+            assert after[name]["wall_s"] > before.get(
+                name, {"wall_s": 0.0})["wall_s"]
+        tid = body["data"]["stats"]["traceId"]
+        code, tbody, _ = _get(cluster["port_a"], f"/admin/traces/{tid}")
+        assert code == 200
+        flat = _flatten(tbody["data"]["spans"])
+        (fan,) = [n for n in flat if n["name"] == "exec.fanout"]
+        (comp,) = [n for n in flat if n["name"] == "exec.compose"]
+        assert fan["tags"] == {"plan": "ReduceAggregateExec",
+                               "children": str(children)}
+        assert comp["tags"] == {"plan": "ReduceAggregateExec"}
+        # the leaves ran inside the fan-out, the reduce after it
+        under = {n["name"] for n in _flatten(fan["children"])}
+        assert "execplan.execute" in under and "exec.compose" not in under
+
+
 class TestForensicsEndpoints:
     def test_slowlog_captures_query(self, cluster):
         old = TRACE_STORE.slow_threshold_s
