@@ -12,6 +12,8 @@ path, at the member count where it ends."""
 import json
 import pathlib
 import sys
+import urllib.error
+import urllib.parse
 import urllib.request
 
 import numpy as np
@@ -269,22 +271,87 @@ def reduces(monkeypatch):
     return out
 
 
+def present_span(server, stats: dict) -> dict:
+    """The ``quantile.present`` span of the answer that carried ``stats``."""
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.http.port}/admin/traces/"
+            f"{stats['traceId']}", timeout=30) as r:
+        nodes = json.loads(r.read())["data"]["spans"]
+    found = []
+    while nodes:
+        n = nodes.pop()
+        nodes.extend(n["children"])
+        if n["name"] == "quantile.present":
+            found.append(n)
+    span, = found
+    return span
+
+
 def test_quantile_over_a_split_namespace_is_exact(dev4, pop, reduces,
                                                   monkeypatch):
     """128 instances over two shards sit ON the edge of the exact path:
     the members of the two partials sum to ``exact_members``.  One fewer
-    allowed and the answer is a t-digest sketch."""
+    allowed, or one more asked for, and the answer is a t-digest sketch;
+    the span ``quantile.present`` says which path served."""
     pi = next(i for i, p in enumerate(TRAFFIC["panels"])
               if p["name"] == "quantile")
+    panel = TRAFFIC["panels"][pi]
     ns = namespace_with_reset(pop)
-    ask(dev4, pop, pi, ns)
+    _got, stats = ask(dev4, pop, pi, ns, stats=True)
     (partials, res), = reduces
     assert len(partials) == 2
     assert sum(p.state["members"].shape[1] for p in partials) == pop.per_ns
     assert "members" in res.state and "td_means" not in res.state
-    # the same request one member short of the budget: a sketch
+    steps = str(panel["steps"])
+    assert present_span(dev4, stats)["tags"] == {
+        "path": "exact", "groups": "1", "members": str(pop.per_ns),
+        "steps": steps}
+    # a group of 129: the namespace and one instance of its neighbour
     del reduces[:]
-    monkeypatch.setattr(QuantileAggregator, "exact_members", pop.per_ns - 1)
-    ask(dev4, pop, pi, ns)
+    other = (ns + 1) % SPEC["namespaces"]
+    wanted = list(compare.selection(pop, panel, ns)) \
+        + [compare.selection(pop, panel, other)[0]]
+    query = 'quantile(0.75, %s{_ws_="%s",_ns_=~"%s|%s",instance=~"%s"})' % (
+        SPEC["metric"], SPEC["workspace"], pop.ns_name(ns),
+        pop.ns_name(other), "|".join(pop.instance_name(s) for s in wanted))
+    start, end, step, _n = traffic.panel_range(panel, SPEC)
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{dev4.http.port}/promql/{DEV4['dataset']}"
+            "/api/v1/query_range?" + urllib.parse.urlencode(
+                {"query": query, "start": start / 1000, "end": end / 1000,
+                 "step": f"{step}ms", "stats": "true"}), timeout=120) as r:
+        got, stats = compare.parse_matrix(r.read(), panel, SPEC)
     (_partials, res), = reduces
     assert "td_means" in res.state
+    assert present_span(dev4, stats)["tags"] == {
+        "path": "sketch", "groups": "1", "members": str(pop.per_ns + 1),
+        "steps": steps}
+    want = np.quantile(np.stack([oracle.range_fn(
+        "last", pop.ts[s], pop.vals[s], start, end, step,
+        panel["reference"]["window_ms"]) for s in wanted]), 0.75, axis=0)
+    assert compare.gap(got, {"": want})["rel_err"] < 0.05  # a sketch's
+    # the namespace alone, one member short of the budget: a sketch too
+    del reduces[:]
+    monkeypatch.setattr(QuantileAggregator, "exact_members", pop.per_ns - 1)
+    _got, stats = ask(dev4, pop, pi, ns, stats=True)
+    (_partials, res), = reduces
+    assert "td_means" in res.state
+    assert present_span(dev4, stats)["tags"]["path"] == "sketch"
+
+
+@pytest.mark.parametrize("q", ["-0.1", "1.5"])
+def test_quantile_outside_the_unit_interval_is_bad_data(dev4, pop, q):
+    """What the served path has always answered, from ``np.nanquantile``'s
+    own refusal: 400 ``bad_data`` in NumPy's words, not +-Inf."""
+    panel = next(p for p in TRAFFIC["panels"] if p["name"] == "quantile")
+    req = traffic.request_for(
+        dict(panel, query=panel["query"].replace("0.75", q)), 0,
+        namespace_with_reset(pop), SPEC, DEV4["dataset"],
+        TRAFFIC["timeout_s"], False)
+    with pytest.raises(urllib.error.HTTPError) as refused:
+        urllib.request.urlopen(
+            f"http://127.0.0.1:{dev4.http.port}{req.path}", timeout=120)
+    assert refused.value.code == 400
+    doc = json.loads(refused.value.read())
+    assert doc["errorType"] == "bad_data"
+    assert "Quantiles must be in the range [0, 1]" in doc["error"]

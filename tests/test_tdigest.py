@@ -5,11 +5,14 @@ Reference: exec/aggregator/RowAggregator.scala QuantileRowAggregator
 (TDigest partials bounding memory at high cardinality).
 """
 
+import sys
+
 import numpy as np
 import pytest
 
 from filodb_tpu.query import tdigest
-from filodb_tpu.query.aggregators import QuantileAggregator, aggregator_for
+from filodb_tpu.query.aggregators import (AggPartialBatch,
+                                          QuantileAggregator, aggregator_for)
 from filodb_tpu.query.model import PeriodicBatch, StepRange
 
 BASE = 1_700_000_000_000
@@ -140,3 +143,122 @@ class TestQuantileAggregatorSwitch:
         out = agg.present(agg.reduce([p]))
         want = np.quantile(vals, 0.95, axis=0)
         np.testing.assert_allclose(out.values[0], want, rtol=0.02)
+
+
+# ---------------------------------------------------------------------------
+# The exact present: one sort, bit for bit np.nanquantile (PR 30)
+# ---------------------------------------------------------------------------
+
+
+def _members(case: str) -> np.ndarray:
+    """Dense member matrices [G, M, T] of random doubles (no whole
+    numbers), NaN where a series is absent at a step."""
+    rng = np.random.default_rng(sum(case.encode()))
+
+    def scattered(shape, share=0.2):
+        m = rng.normal(50.0, 1e3, shape)
+        m[rng.random(shape) < share] = np.nan
+        return m
+    if case == "1x128x23":
+        return scattered((1, 128, 23))
+    if case == "1x128x23-full":                 # the benchmark's panel
+        return scattered((1, 128, 23), share=0.0)
+    if case == "1x1x5":
+        return scattered((1, 1, 5))
+    if case == "7x33x221":
+        return scattered((7, 33, 221))
+    if case == "column-nan":                    # a step nobody reported at
+        m = scattered((7, 33, 221))
+        m[:, :, 4] = np.nan
+        m[2, :, 100:] = np.nan
+        return m
+    if case == "group-nan":
+        m = scattered((7, 33, 221))
+        m[3] = np.nan
+        return m
+    if case == "unequal-groups":                # the dense matrix pads
+        sizes = [1, 33, 2, 17, 5, 32, 8]
+        vals = scattered((sum(sizes), 221), share=0.1)
+        keys = [{"inst": f"i{g}-{i}", "g": f"g{g}"}
+                for g, n in enumerate(sizes) for i in range(n)]
+        p = QuantileAggregator().map(_batch(vals, keys), ("g",), (), (0.5,),
+                                     1000)
+        assert p.state["members"].shape == (7, 33, 221)
+        assert np.isnan(p.state["members"][0, 1:]).all()
+        return p.state["members"]
+    assert case == "inf-and-zero"               # inf - inf, the zero's sign
+    return np.array([[[-0.0, np.inf, -np.inf, np.nan, 1.5, np.inf],
+                      [np.nan, np.inf, -np.inf, np.nan, 2.5, 3.0],
+                      [np.nan, 7.25, -np.inf, np.nan, np.nan, 0.1]]])
+
+
+def _partial(members: np.ndarray, q: float) -> AggPartialBatch:
+    G, _M, T = members.shape
+    return AggPartialBatch(QuantileAggregator.op, (q,),
+                           [{"g": str(g)} for g in range(G)],
+                           StepRange(BASE, 60_000, T), {"members": members})
+
+
+def _present(members: np.ndarray, q: float) -> np.ndarray:
+    p = _partial(members, q)
+    out = QuantileAggregator().present(p)
+    assert out.keys == p.group_keys and out.steps == p.steps
+    return out.values
+
+
+@pytest.mark.filterwarnings("ignore::RuntimeWarning")   # nanquantile's own
+@pytest.mark.parametrize("q", [0, 0.25, 0.5, 0.75, 0.99, 1])
+@pytest.mark.parametrize("case", [
+    "1x128x23", "1x128x23-full", "1x1x5", "7x33x221", "column-nan",
+    "group-nan", "unequal-groups", "inf-and-zero"])
+def test_exact_present_is_nanquantile_bit_for_bit(case, q):
+    members = _members(case)
+    want = np.nanquantile(members, q, axis=1)
+    state = members.copy()
+    got = _present(state, q)
+    assert np.array_equal(state, members, equal_nan=True)   # sorted a copy
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.array_equal(got, want, equal_nan=True)
+    # ... to the sign of a zero (the cases tie no zeros of two signs,
+    # whose order no sort defines)
+    assert np.array_equal(np.signbit(got), np.signbit(want))
+    if case in ("column-nan", "group-nan"):
+        assert np.isnan(got).any() and np.isfinite(got).any()
+
+
+@pytest.mark.parametrize("q", [-0.1, 1.5, float("nan")])
+def test_exact_present_refuses_q_outside_the_unit_interval(q):
+    """As ``np.nanquantile`` did on the served path: a ``ValueError`` with
+    NumPy's words, which the HTTP front end answers 400 ``bad_data``."""
+    with pytest.raises(ValueError,
+                       match=r"Quantiles must be in the range \[0, 1\]"):
+        _present(_members("1x1x5"), q)
+
+
+def _c_calls_in_present(members: np.ndarray) -> int:
+    p, agg, n = _partial(members, 0.75), QuantileAggregator(), [0]
+
+    def profile(_frame, event, _arg):
+        n[0] += event == "c_call"
+    sys.setprofile(profile)
+    try:
+        agg.present(p)
+    finally:
+        sys.setprofile(None)
+    return n[0]
+
+
+def test_exact_present_makes_no_call_per_step_or_group(monkeypatch):
+    """Counted, not timed: every C call of the exact path is over whole
+    arrays, so their number is the same for 23 steps and 2 300, for one
+    group and seven, for one member and 128."""
+    def no_loop(*_a, **_k):
+        raise AssertionError("np.apply_along_axis: a Python call a slice")
+    monkeypatch.setattr(np, "apply_along_axis", no_loop)
+    rng = np.random.default_rng(30)
+    _c_calls_in_present(rng.random((1, 2, 3)))      # first-call imports
+    counts = {shape: _c_calls_in_present(rng.random(shape))
+              for shape in [(1, 128, 23), (1, 128, 2300), (7, 128, 23),
+                            (7, 1, 2300)]}
+    assert len(set(counts.values())) == 1, counts
+    assert 0 < counts[1, 128, 23] < 60, counts
