@@ -166,9 +166,9 @@ def pack_vals(vals: np.ndarray, lane_block: int = LANE_BLOCK,
     meta tiles for the uniform-phase kernels; omit when unknown.
     ``min_width`` forces lanes that would classify narrower up to the
     given class — a workload whose residuals provably fit one width
-    (e.g. the north-star integer counters) then packs as a SINGLE class
-    plane, which preserves lane (and therefore group) order for the
-    fused grouped kernel's contiguity contract.
+    (e.g. integer counters with a pinned exponent) then packs as a
+    SINGLE class plane, which preserves lane (and therefore group)
+    order for the fused hist kernel's contiguity contract.
 
     ``stride`` (histogram bucket planes, devicestore's group-slot
     layout ``hist_slot_garr``: column ``s*stride + j`` = series s,
@@ -228,7 +228,7 @@ def pack_vals(vals: np.ndarray, lane_block: int = LANE_BLOCK,
                 for i, k in enumerate(class_keys)]
     # canonical order: ascending original lane within each class, so a
     # single-class pack is the IDENTITY permutation (the group-aligned
-    # contract rate_grid_grouped_packed relies on)
+    # contract ops/grid.py hist_grid_grouped_packed relies on)
     by_cls = [sorted(c) for c in by_cls]
     planes: dict[str, np.ndarray] = {}
     order_parts: list[np.ndarray] = []

@@ -380,12 +380,16 @@ def _fused_progs():
                 return prog(*a, **kw)
         return launch
 
-    _FUSED_PROGS["series"] = staged(series_prog)
-    _FUSED_PROGS["grouped"] = staged(grouped_prog)
-    _FUSED_PROGS["series_packed"] = staged(series_prog_packed)
-    _FUSED_PROGS["grouped_packed"] = staged(grouped_prog_packed)
-    _FUSED_PROGS["series_batch"] = staged(series_batch_prog)
-    _FUSED_PROGS["grouped_batch"] = staged(grouped_batch_prog)
+    # published whole: a request that arrives while the first one is
+    # still building sees no program or all six, never a part of them
+    # (entry by entry, it met a non-empty dict without "grouped_batch")
+    _FUSED_PROGS.update({
+        "series": staged(series_prog),
+        "grouped": staged(grouped_prog),
+        "series_packed": staged(series_prog_packed),
+        "grouped_packed": staged(grouped_prog_packed),
+        "series_batch": staged(series_batch_prog),
+        "grouped_batch": staged(grouped_batch_prog)})
     return _FUSED_PROGS
 
 

@@ -214,8 +214,8 @@ class PartKeyIndex:
     """One index per shard; partition ids are dense ints assigned by the shard."""
 
     def __init__(self, auto_apply: bool = True) -> None:
-        # auto_apply=False suppresses the background applier (bulk
-        # loads / benches that drain explicitly via apply_pending)
+        # auto_apply=False suppresses the background applier (tests
+        # that drain explicitly via apply_pending)
         self._auto_apply = auto_apply
         self._labels: dict[str, _Label] = {}
         self._tags: dict[int, dict[str, str]] = {}

@@ -542,9 +542,8 @@ def crc32c_verify(blobs, expected) -> "tuple[int, np.ndarray] | None":
     never-zero mapping applied).  Returns (mismatch_count, ok bool
     array), or None when the native library is unavailable.  This is
     the ODP page-in read-back verifier: no join/copy of the blob bytes,
-    and the C side interleaves three crc32 instruction streams — the
-    naive per-blob formulation cost ~30% of a cold ODP scan, this one
-    ~2% (BASELINE.md)."""
+    and the C side interleaves three crc32 instruction streams (one
+    Python-level call a blob is what the batch replaces)."""
     lib = _load()
     if lib is None:
         return None

@@ -1,9 +1,10 @@
 """ctypes binding for the C++ CPU baseline (src/baseline.cpp).
 
-This is the measurement side of BASELINE.md's protocol: a multithreaded
--O3 C++ implementation of the reference's per-series/per-window query
-iterator (the JVM proxy — no JVM exists in the bench environment), used
-by bench.py and benches/ to compute ``vs_baseline`` honestly.
+A multithreaded -O3 C++ implementation of the reference's per-series /
+per-window query iterator (a stand-in for the JVM's).  No serving path
+calls it: ``benchmark/run.py`` imports it to report whether the native
+sources build (``native_build_errors``), and ``tests/test_baseline.py``
+holds its semantics.
 """
 
 from __future__ import annotations

@@ -9,14 +9,13 @@
 // jmh/src/main/scala/filodb.jmh/QueryInMemoryBenchmark.scala:45-249).
 //
 // The JVM publishes no absolute numbers and no JVM exists in this
-// environment (BASELINE.md), so this -O3 C++ loop is the stand-in for the
+// environment, so this -O3 C++ loop is the stand-in for the
 // JVM's iterator path: same algorithm (binary search per window, one pass
 // per series), same data, scaled across hardware threads the way the
 // reference's query scheduler spreads range vectors across its pool.
 //
-// Semantics intentionally match bench.py's _numpy_rate_sum oracle
-// bit-for-bit (same correction and extrapolation formulas) so the
-// TPU-vs-CPU comparison is apples-to-apples.
+// Semantics match the per-series NumPy loop of tests/test_baseline.py
+// (same correction and extrapolation formulas).
 
 #include <algorithm>
 #include <cmath>
@@ -138,8 +137,8 @@ int baseline_rate_sum(const int64_t* ts, const double* vals, size_t S,
 }
 
 // sum_over_time variant (no correction/extrapolation): per window, sum of
-// samples in (st-window, st]. Used by the bench suite for a second
-// workload point (reference: AggrOverTimeFunctions.scala SumOverTime).
+// samples in (st-window, st] (reference: AggrOverTimeFunctions.scala
+// SumOverTime).
 int baseline_sum_over_time(const int64_t* ts, const double* vals, size_t S,
                            size_t R, const int32_t* ids, size_t G,
                            const int64_t* steps, size_t T,

@@ -14,14 +14,16 @@ This replaces the reference's per-window row iteration
 binarySearch; AggrOverRangeVectors.scala:161-277 fastReduce) with one
 fused pass: counter correction (prefix scan) -> per-window first/last
 finite sample extraction (K select passes) -> Prometheus extrapolated
-rate (RateFunctions.scala:37-80) -> grouped sum/count reduction, all in
-VMEM.  Measured 1.8e10 samples/s on one v5e chip for
-``sum by (g)(rate(m[5m]))`` over 1M series x 60 samples — ~25x the
-unaligned gather-free path.
+rate (RateFunctions.scala:37-80), all in VMEM.  The grouped sum/count
+reduction follows as an XLA one-hot reduce inside the serving program
+(memstore/devicestore.py ``_grouped_reduce_impl``).
 
 Two implementations with identical semantics:
 
-- :func:`rate_grid` / :func:`rate_grid_grouped` — Pallas TPU kernels.
+- :func:`rate_grid` (decoded planes) / :func:`rate_grid_packed`
+  (XOR-class packed planes, decode fused in) — Pallas TPU kernels;
+  :func:`rate_grid_auto` / :func:`rate_grid_batch_impl` are what the
+  serving programs trace.
 - :func:`rate_grid_ref` — pure-XLA reference (runs everywhere; used on
   CPU and as the numerical oracle in tests).
 
@@ -39,9 +41,6 @@ Layout contract (enforced by the caller / device store):
 - counter correction runs from input row 0, i.e. from the start of the
   scanned range — same scope as the general path, which corrects from
   the first scanned row (filodb_tpu/ops/windows.py counter_correct).
-- grouped variant: series pre-sorted by group, each group padded to
-  ``group_lanes`` columns (pad columns hold NaN vals), and the number
-  of groups padded to a multiple of 8.
 """
 
 from __future__ import annotations
@@ -806,38 +805,6 @@ def _series_kernel_phase(s0_ref, ph_ref, vals_ref, out_ref, *,
     out_ref[:] = jnp.where(live_row, out, jnp.nan)
 
 
-def _grouped_kernel(s0_ref, ts_ref, vals_ref, sum_ref, cnt_ref, *,
-                    q: GridQuery):
-    gi = pl.program_id(1)
-    r = _rate_block(ts_ref[:], vals_ref[:], s0_ref[0, 0], q)
-    ok = jnp.isfinite(r)
-    sum_ref[gi, :] = jnp.sum(jnp.where(ok, r, 0.0), axis=1)
-    cnt_ref[gi, :] = jnp.sum(ok.astype(jnp.float32), axis=1)
-
-
-def _grouped_kernel_free(s0_ref, vals_ref, sum_ref, cnt_ref, *,
-                         q: GridQuery):
-    gi = pl.program_id(1)
-    r = _rate_block(None, vals_ref[:], s0_ref[0, 0], q)
-    ok = jnp.isfinite(r)
-    sum_ref[gi, :] = jnp.sum(jnp.where(ok, r, 0.0), axis=1)
-    cnt_ref[gi, :] = jnp.sum(ok.astype(jnp.float32), axis=1)
-
-
-def _grouped_kernel_phase(s0_ref, ph_ref, vals_ref, sum_ref, cnt_ref, *,
-                          q: GridQuery):
-    """Grouped phase kernel: liveness is the [1, ns] row (dense), so the
-    per-window finite count is nlive — a constant row — and the sum mask
-    is a broadcast, not a [T, ns] isfinite pass."""
-    gi = pl.program_id(1)
-    roll = lambda x, s: pltpu.roll(x, s, axis=0)
-    out, live_row = _phase_block_raw(ph_ref[0:1, :], vals_ref[:], q, roll,
-                                     mxu=True)
-    sum_ref[gi, :] = jnp.sum(jnp.where(live_row, out, 0.0), axis=1)
-    nlive = jnp.sum(live_row.astype(jnp.float32))
-    cnt_ref[gi, :] = jnp.full((q.nsteps,), nlive, jnp.float32)
-
-
 def _smem():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
@@ -933,66 +900,6 @@ def rate_grid(ts, vals, steps0, q: GridQuery, lanes: int = 1024,
     )(_s0_tile(steps0), *extra, vals)
 
 
-_GPS = 8  # groups per output block (output sublane granularity)
-
-
-@functools.partial(devicewatch.jit, program="grid.rate_grid_grouped",
-                   static_argnames=("q", "group_lanes", "interpret"))
-@_x32
-def rate_grid_grouped(ts, vals, steps0, q: GridQuery,
-                      group_lanes: int = 1024, interpret: bool = False,
-                      phase=None):
-    """Fused ``sum by (group)(rate(...))``: [B, S] -> (sum, count) [G, T].
-
-    Series are pre-sorted by group and padded so group g occupies
-    columns [g*group_lanes, (g+1)*group_lanes); G must be a multiple
-    of 8 (host pads; padded groups come back with count 0).  ``phase``
-    as in :func:`rate_grid`.
-    """
-    nb, ns = vals.shape
-    ngroups = ns // group_lanes
-    if ns % group_lanes != 0 or ngroups == 0 or ngroups % _GPS != 0:
-        raise ValueError(
-            f"series count {ns} must be (groups x group_lanes) with the "
-            f"group count a non-zero multiple of {_GPS}; got "
-            f"{ngroups} x {group_lanes} (pad groups with NaN columns and "
-            f"the group list to a multiple of {_GPS})")
-    if nb < _rows_needed(q):
-        raise ValueError(f"grid has {nb} rows; need (nsteps-1)*stride+K = "
-                         f"{_rows_needed(q)}")
-    if q.stride > 1:
-        s, c = rate_grid_grouped(ts, vals, steps0, _fine_query(q),
-                                 group_lanes, interpret, phase)
-        return s[:, ::q.stride], c[:, ::q.stride]
-    mode = _mode_for(q, phase)
-    vspec = pl.BlockSpec((nb, group_lanes),
-                         lambda i, gi: (0, i * _GPS + gi),
-                         memory_space=pltpu.VMEM)
-    if mode == "free":
-        kern, extra, especs = _grouped_kernel_free, (), ()
-    elif mode == "phase":
-        kern = _grouped_kernel_phase
-        extra = (_phase8(phase),)
-        especs = (pl.BlockSpec((8, group_lanes),
-                               lambda i, gi: (0, i * _GPS + gi),
-                               memory_space=pltpu.VMEM),)
-    else:
-        kern, extra, especs = _grouped_kernel, (ts,), (vspec,)
-    s, c = pl.pallas_call(
-        functools.partial(kern, q=q),
-        interpret=interpret, compiler_params=_MOSAIC_PARAMS,
-        out_shape=(jax.ShapeDtypeStruct((ngroups, q.nsteps), jnp.float32),
-                   jax.ShapeDtypeStruct((ngroups, q.nsteps), jnp.float32)),
-        grid=(ngroups // _GPS, _GPS),
-        in_specs=[_smem(), *especs, vspec],
-        out_specs=(pl.BlockSpec((_GPS, q.nsteps), lambda i, gi: (i, 0),
-                                memory_space=pltpu.VMEM),
-                   pl.BlockSpec((_GPS, q.nsteps), lambda i, gi: (i, 0),
-                                memory_space=pltpu.VMEM)),
-    )(_s0_tile(steps0), *extra, vals)
-    return s, c
-
-
 # ---------------------------------------------------------------------------
 # Compressed-resident kernels: on-device XOR-class decode fused into the
 # grid compute, so one compiled program reads the ~2.5 B/sample packed
@@ -1048,24 +955,6 @@ def _series_kernel_packed(s0_ref, m_ref, p_ref, out_ref, *, q: GridQuery,
         out_ref[:] = jnp.where(live_row, out, jnp.nan)
     else:
         out_ref[:] = _rate_block(None, vals, s0_ref[0, 0], q)
-
-
-def _grouped_kernel_packed(s0_ref, m_ref, p_ref, sum_ref, cnt_ref, *,
-                           q: GridQuery, row0: int, use_phase: bool):
-    gi = pl.program_id(1)
-    vals = _decode_rows(p_ref, m_ref, q, row0)
-    if use_phase:
-        roll = lambda x, s: pltpu.roll(x, s, axis=0)
-        out, live_row = _phase_block_raw(m_ref[2:3, :], vals, q, roll,
-                                         mxu=True)
-        sum_ref[gi, :] = jnp.sum(jnp.where(live_row, out, 0.0), axis=1)
-        nlive = jnp.sum(live_row.astype(jnp.float32))
-        cnt_ref[gi, :] = jnp.full((q.nsteps,), nlive, jnp.float32)
-    else:
-        r = _rate_block(None, vals, s0_ref[0, 0], q)
-        ok = jnp.isfinite(r)
-        sum_ref[gi, :] = jnp.sum(jnp.where(ok, r, 0.0), axis=1)
-        cnt_ref[gi, :] = jnp.sum(ok.astype(jnp.float32), axis=1)
 
 
 def _packed_planes(packed: dict):
@@ -1164,82 +1053,6 @@ def rate_grid_packed(packed: dict, steps0, q: GridQuery, row0: int = 0,
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
-@functools.partial(devicewatch.jit,
-                   program="grid.rate_grid_grouped_packed",
-                   static_argnames=("q", "group_lanes", "row0", "interpret",
-                                    "use_phase"))
-@_x32
-def rate_grid_grouped_packed(packed: dict, steps0, q: GridQuery,
-                             group_lanes: int = 1024, row0: int = 0,
-                             interpret: bool = False,
-                             use_phase: bool = True):
-    """Fully fused ``sum by (group)(rate(...))`` over packed residents:
-    packed planes -> (sum, count) [G, T], decode + window + grouped
-    reduce in one kernel per class plane.
-
-    Requires the GROUP-ALIGNED pack contract: every class plane's lane
-    count is a multiple of ``group_lanes``, no group's lanes straddle a
-    class boundary, and the pack carries NO alignment-pad lanes (the
-    north-star layout packs whole groups via ``min_width``, so a
-    uniform workload keeps its group order; mixed-class or padded
-    layouts must use :func:`rate_grid_packed` + a segment reduce that
-    drops pads through the group map).  Groups come back in
-    packed-plane order.
-    """
-    _packed_check(packed, q, row0, use_phase)
-    inv = packed.get("inv")
-    if inv is not None and packed_width(packed) != inv.shape[0]:
-        # a zero pad lane decodes to a constant finite 0.0 series: with
-        # no group map to drop it, it would count as a live series in
-        # its group (+1 count, skewed avg) — reject rather than corrupt
-        raise ValueError(
-            f"pack carries {packed_width(packed) - inv.shape[0]} "
-            f"alignment-pad lanes; the fused grouped kernel has no "
-            f"group map to drop them — use rate_grid_packed + a "
-            f"segment reduce, or a min_width single-class pack")
-    if q.stride > 1:
-        s, c = rate_grid_grouped_packed(packed, steps0, _fine_query(q),
-                                        group_lanes, row0, interpret,
-                                        use_phase)
-        return s[:, ::q.stride], c[:, ::q.stride]
-    s0 = _s0_tile(steps0)
-    sums, cnts = [], []
-    for p, m in _packed_planes(packed):
-        nb, n = p.shape
-        ng = n // group_lanes
-        if n % group_lanes != 0 or ng == 0 or ng % _GPS != 0:
-            raise ValueError(
-                f"packed plane width {n} must be (groups x "
-                f"{group_lanes}) with the group count a multiple of "
-                f"{_GPS} — use the group-aligned pack layout")
-        s, c = pl.pallas_call(
-            functools.partial(_grouped_kernel_packed, q=q, row0=row0,
-                              use_phase=use_phase),
-            interpret=interpret, compiler_params=_MOSAIC_PARAMS,
-            out_shape=(jax.ShapeDtypeStruct((ng, q.nsteps), jnp.float32),
-                       jax.ShapeDtypeStruct((ng, q.nsteps), jnp.float32)),
-            grid=(ng // _GPS, _GPS),
-            in_specs=[_smem(),
-                      pl.BlockSpec((8, group_lanes),
-                                   lambda i, gi: (0, i * _GPS + gi),
-                                   memory_space=pltpu.VMEM),
-                      pl.BlockSpec((nb, group_lanes),
-                                   lambda i, gi: (0, i * _GPS + gi),
-                                   memory_space=pltpu.VMEM)],
-            out_specs=(pl.BlockSpec((_GPS, q.nsteps),
-                                    lambda i, gi: (i, 0),
-                                    memory_space=pltpu.VMEM),
-                       pl.BlockSpec((_GPS, q.nsteps),
-                                    lambda i, gi: (i, 0),
-                                    memory_space=pltpu.VMEM)),
-        )(s0, m, p)
-        sums.append(s)
-        cnts.append(c)
-    if len(sums) == 1:
-        return sums[0], cnts[0]
-    return jnp.concatenate(sums, axis=0), jnp.concatenate(cnts, axis=0)
-
-
 # ---------------------------------------------------------------------------
 # Compressed-resident HISTOGRAM kernels (ISSUE 14): decode bucket planes
 # in VMEM and reduce the bucket dimension with BANDED MXU matmuls.
@@ -1249,8 +1062,9 @@ def rate_grid_grouped_packed(packed: dict, steps0, q: GridQuery,
 # column ``s*hb + j`` holds series s's cumulative bucket j, a series'
 # ``hb`` columns classify together and stay contiguous in bucket order.
 # The fused grouped kernel additionally requires the group-aligned
-# single-class identity pack (min_width, no pads) — same contract as
-# :func:`rate_grid_grouped_packed`, with ``group_lanes % hb == 0``.
+# single-class identity pack (min_width, no pads: a zero pad lane
+# decodes to a constant finite 0.0 series, and with no group map to drop
+# it would count as a live series), with ``group_lanes % hb == 0``.
 #
 # The per-bucket window compute is the SAME code path as the scalar
 # kernels (each bucket column is an independent counter lane, incl. the
@@ -1378,33 +1192,6 @@ def hist_grid_grouped_packed(packed: dict, steps0, q: GridQuery, hb: int,
     return s, c
 
 
-@functools.partial(devicewatch.jit,
-                   program="grid.hist_quantile_grid_packed",
-                   static_argnames=("q", "phi", "hb", "group_lanes",
-                                    "row0", "interpret", "use_phase"))
-def hist_quantile_grid_packed(packed: dict, steps0, tops, q: GridQuery,
-                              phi: float, hb: int,
-                              group_lanes: int = 1024, row0: int = 0,
-                              interpret: bool = False,
-                              use_phase: bool = True):
-    """Fused ``histogram_quantile(phi, sum by (g)(rate(...)))``: the
-    packed hist kernel above feeds the le-interpolation IN THE SAME
-    compiled program, so only the final ``[G, T]`` quantile plane ever
-    leaves the device — no per-bucket partial crosses the host link.
-    ``tops`` is the [hb] cumulative bucket upper bounds (le values)."""
-    from filodb_tpu.ops import histogram_ops
-
-    s, c = hist_grid_grouped_packed(packed, steps0, q, hb, group_lanes,
-                                    row0, interpret, use_phase)
-    T = s.shape[1]
-    G = s.shape[0] // hb
-    hist_sum = s.reshape(G, hb, T).transpose(0, 2, 1)     # [G, T, hb]
-    out = histogram_ops.hist_quantile(jnp.asarray(tops), hist_sum,
-                                      phi)                # [G, T]
-    nlive = c.reshape(G, hb, T)[:, hb - 1, :]             # total bucket
-    return jnp.where(nlive > 0, out, jnp.nan)
-
-
 # ---------------------------------------------------------------------------
 # Generic columnar event scan -> filter -> topK (ISSUE 14): the GDELT
 # shape.  Each event stream is a lane of a (packed) numeric column
@@ -1452,8 +1239,8 @@ def event_topk_grid_packed(packed: dict, steps0, q: GridQuery, k: int,
       packed lanes (the banded layout: group g = lanes [g*W, (g+1)*W)),
       pass it and ``garr=None`` — the reduce becomes a reshape-sum with
       no [lanes, G] one-hot operand at all (the memory-free banded
-      form; the bench's 256k-lane table would otherwise stream a
-      multi-GiB one-hot).  A general ``garr`` uses the one-hot MXU
+      form; a 256k-lane table would otherwise stream a multi-GiB
+      one-hot).  A general ``garr`` uses the one-hot MXU
       matmul up to ``_TOPK_ONEHOT_MAX_G`` groups and segment_sum past
       it (the devicestore ``_grouped_reduce_impl`` policy).
     - ``filt_packed``/``filt_op``/``filt_thresh``: keep only lanes whose
@@ -1593,18 +1380,6 @@ def rate_grid_batch_impl(ts_b, vals_b, steps0s, q: GridQuery,
             None, v, s, q, lanes, phase=phase))(vals_b, steps0s)
     return jax.vmap(lambda t, v, s: rate_grid_auto(
         t, v, s, q, lanes, phase=phase))(ts_b, vals_b, steps0s)
-
-
-@functools.partial(devicewatch.jit, program="grid.rate_grid_batch",
-                   static_argnames=("q", "lanes"))
-def rate_grid_batch(ts_b, vals_b, steps0s, q: GridQuery,
-                    lanes: int = 1024, phase=None):
-    """Standalone jitted batched entry over already-materialized
-    planes (tests, direct grid users).  The serving path does NOT call
-    this — it inlines :func:`rate_grid_batch_impl` into the fused
-    device-store programs to avoid a second dispatch."""
-    return rate_grid_batch_impl(ts_b, vals_b, steps0s, q, lanes,
-                                phase=phase)
 
 
 MAX_K_BUCKETS = 64   # K-unrolled kernel passes; caps the compile cost

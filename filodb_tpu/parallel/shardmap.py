@@ -227,8 +227,8 @@ class ShardMapper:
                 f"replication_factor {replication_factor} must be >= 1")
         self.replication_factor = replication_factor
         # named mappers (cluster-managed) emit shard-health metrics and
-        # flight events on status changes; anonymous ones (benches,
-        # ad-hoc tests) stay silent
+        # flight events on status changes; anonymous ones (ad-hoc
+        # tests) stay silent
         self.dataset = dataset
         self._states = [ShardState() for _ in range(num_shards)]
         # ONE atomically-swapped object carries (serving count, total

@@ -327,9 +327,8 @@ class DiskColumnStore(_SqliteBase, ColumnStore):
         partial data, never unverified bytes); already-quarantined
         chunks are excluded the same way on every re-read.
 
-        Hot path: ONE batched native CRC pass over the joined blobs —
-        the per-row formulation cost ~30% of an ODP cold scan, this one
-        costs <3% (BASELINE.md)."""
+        Hot path: ONE batched native CRC pass over the joined blobs,
+        not a call a row."""
         quarantine = integrity.QUARANTINE
         if quarantine:
             rows = [r for r in rows

@@ -78,8 +78,7 @@ from typing import Callable, Optional
 _LOG = logging.getLogger("filodb.devicewatch")
 
 # kill switch: set_enabled(False) turns every wrapper into a pass-through
-# (used by the overhead bench to measure the instrumentation delta, and
-# by operators via the standalone "devicewatch" config block)
+# (operators set it via the standalone "devicewatch" config block)
 _ENABLED = True
 
 

@@ -770,7 +770,8 @@ def batch_metrics() -> dict:
     filodb_tpu/batching): realized vmapped group sizes next to the
     ledger's co-arrival headroom estimate, plus the fallback ladder —
     one place defines the names so the batcher, /admin/insights,
-    doc/observability.md, and the bench gates can never drift."""
+    doc/observability.md and the benchmark's ``device_dispatches``
+    (benchmark/run.py reads ``filodb_batch_members_total``) agree."""
     return {
         "groups": REGISTRY.counter(
             "filodb_batch_groups_total",

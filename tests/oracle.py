@@ -167,3 +167,32 @@ def range_fn(name: str, ts: np.ndarray, vals: np.ndarray, start: int, end: int,
         else:
             raise ValueError(f"unknown oracle function {name}")
     return out
+
+
+def grouped_reduce(stepped: np.ndarray, garr: np.ndarray, num_groups: int,
+                   op: str) -> np.ndarray:
+    """``<op> by (group)`` over a grid kernel's ``[T, lanes]`` output, one
+    lane at a time: what ``devicestore._grouped_reduce_impl`` must give.
+    A lane mapped to ``num_groups`` is dropped.  sum/avg/count ->
+    ``[2, G, T]`` (sum, count of finite cells), moments -> ``[3, G, T]``
+    (+ sum of squares), min/max -> ``[G, T]``, NaN where a group has no
+    finite cell."""
+    T = stepped.shape[0]
+    planes = np.zeros((3, num_groups, T))
+    lo = np.full((num_groups, T), np.inf)
+    hi = np.full((num_groups, T), -np.inf)
+    for lane, g in enumerate(garr):
+        if g == num_groups:
+            continue
+        col = stepped[:, lane].astype(np.float64)
+        fin = np.isfinite(col)
+        planes[0, g] += np.where(fin, col, 0.0)
+        planes[1, g] += fin
+        planes[2, g] += np.where(fin, col * col, 0.0)
+        lo[g] = np.minimum(lo[g], np.where(fin, col, np.inf))
+        hi[g] = np.maximum(hi[g], np.where(fin, col, -np.inf))
+    if op == "min":
+        return np.where(planes[1] > 0, lo, np.nan)
+    if op == "max":
+        return np.where(planes[1] > 0, hi, np.nan)
+    return planes[:3 if op == "moments" else 2]

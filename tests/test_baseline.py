@@ -1,9 +1,9 @@
-"""C++ CPU baseline (native/src/baseline.cpp) vs the bench.py numpy oracle.
+"""C++ CPU baseline (native/src/baseline.cpp) vs a per-series NumPy loop.
 
-The baseline is the honest stand-in for the JVM's per-row iterator path
-(BASELINE.md protocol; reference: jmh/QueryInMemoryBenchmark.scala:45-249),
-so its semantics must match the oracle bit-for-bit — counter correction,
-Prometheus extrapolation, group sum — including on gappy/reset data.
+The baseline is a stand-in for the JVM's per-row iterator path
+(reference: jmh/QueryInMemoryBenchmark.scala:45-249); ``benchmark/run.py``
+imports it, so it must build and keep its semantics — counter correction,
+extrapolation, group sum — including on gappy/reset data.
 """
 
 import numpy as np
@@ -19,10 +19,37 @@ WINDOW_MS = 300_000
 
 
 def _oracle_rate_sum(ts, vals, ids, n_groups, steps):
-    import bench
-    saved = bench.WINDOW_MS
-    assert saved == WINDOW_MS
-    return bench._numpy_rate_sum(ts, vals, ids, steps)
+    """Per-series, per-window iterator implementation — the reference's
+    ChunkedRateFunction shape (binary search + per-window pass)."""
+    out = np.zeros((n_groups, len(steps)))
+    cnt = np.zeros((n_groups, len(steps)))
+    for s in range(ts.shape[0]):
+        fin = np.isfinite(vals[s])
+        t_row, v_row = ts[s][fin], vals[s][fin]
+        if len(t_row) < 2:
+            continue
+        corr = np.concatenate([[0.0], np.cumsum(np.maximum(
+            v_row[:-1] - v_row[1:], 0.0))])
+        v_adj = v_row + corr
+        for j, st in enumerate(steps):
+            lo = np.searchsorted(t_row, st - WINDOW_MS, side="right")
+            hi = np.searchsorted(t_row, st, side="right")
+            if hi - lo < 2:
+                continue
+            t1, t2 = t_row[lo], t_row[hi - 1]
+            if t2 == t1:
+                continue
+            delta = v_adj[hi - 1] - v_adj[lo]
+            avg_dur = (t2 - t1) / (hi - lo - 1)
+            ext_start = min(st - WINDOW_MS + avg_dur / 2, float(t1)) \
+                if t1 - (st - WINDOW_MS) <= avg_dur * 1.1 else t1 - avg_dur / 2
+            ext_end = max(st - avg_dur / 2, float(t2)) \
+                if st - t2 <= avg_dur * 1.1 else t2 + avg_dur / 2
+            rate = (delta * ((ext_end - ext_start) / (t2 - t1))
+                    / (WINDOW_MS / 1000.0))
+            out[ids[s], j] += rate
+            cnt[ids[s], j] += 1
+    return np.where(cnt > 0, out, np.nan)
 
 
 def _gen(seed, S=37, R=64, n_groups=5, gap_frac=0.2, resets=True):

@@ -124,7 +124,7 @@ def _rate_grid(one_chip, mode, q, rows, ncols, lanes):
 
 @pytest.mark.parametrize("mode", ["ts", "phase", "free"])
 def test_rate_grid_small(one_chip, mode):
-    """The shape the old kernel bench used: [64, 4096], T=56, K=5."""
+    """A small panel: [64, 4096], T=56, K=5."""
     _rate_grid(one_chip, mode, _query(mode, 56, 5), 64, 4096, 1024)
 
 
@@ -177,14 +177,6 @@ def test_rate_grid_widest_tile(one_chip, mode, op, dense, k):
                rows, ncols, 1024)
 
 
-@pytest.mark.parametrize("mode", ["ts", "phase", "free"])
-def test_rate_grid_grouped(one_chip, mode):
-    rows, ncols = 64, 8 * 1024            # 8 groups x 1024 lanes
-    ts, vals, s0, phase = _series_args(mode, rows, ncols)
-    _compile(grid.rate_grid_grouped, one_chip, ts, vals, s0,
-             q=_query(mode, 56, 5), group_lanes=1024, phase=phase)
-
-
 def _packed_block(ncols: int, stride: int = 1):
     """One compressed-resident block as the device store builds it
     (BLOCK_BUCKETS rows of 16-bit-class counters in one identity
@@ -211,22 +203,12 @@ def test_rate_grid_packed(one_chip, use_phase, nsteps, k):
              _sds((), jnp.int32), q=q, row0=3, use_phase=use_phase)
 
 
-@pytest.mark.parametrize("use_phase", [True, False],
-                         ids=["phase", "free"])
-def test_rate_grid_grouped_packed(one_chip, use_phase):
-    q = _query("phase" if use_phase else "free", 56, 5)
-    _compile(grid.rate_grid_grouped_packed, one_chip,
-             _packed_block(8 * 1024), _sds((), jnp.int32), q=q,
-             group_lanes=1024, row0=3, use_phase=use_phase)
-
-
-def test_hist_quantile_grid_packed(one_chip):
+def test_hist_grid_grouped_packed(one_chip):
     """64 buckets — the reference's histogram width (doc/compression.md)."""
     hb, groups = 64, 4
-    _compile(grid.hist_quantile_grid_packed, one_chip,
+    _compile(grid.hist_grid_grouped_packed, one_chip,
              _packed_block(groups * 1024, stride=hb), _sds((), jnp.int32),
-             _sds((hb,), jnp.float32), q=_query("phase", 56, 5), phi=0.99,
-             hb=hb, group_lanes=1024, row0=3)
+             q=_query("phase", 56, 5), hb=hb, group_lanes=1024, row0=3)
 
 
 def test_event_topk_grid_packed(one_chip):
@@ -265,7 +247,7 @@ def _resident_parts(mode: str, nblocks: int, ncols: int):
 
 @pytest.mark.parametrize("prog,mode", [
     ("series", "ts"), ("series", "phase"), ("series", "free"),
-    ("grouped", "phase")])
+    ("grouped", "ts"), ("grouped", "phase"), ("grouped", "free")])
 def test_fused_program_multi_block(one_chip, as_tpu, prog, mode):
     """A 1 h panel over 15 s scrapes spans two 128-bucket blocks:
     decode + concat + slice + grid kernel (+ grouped reduce) as ONE
@@ -317,11 +299,13 @@ def test_fused_program_wide_mixed_classes_compiles_fast(one_chip, as_tpu):
     assert time.perf_counter() - t0 < 60
 
 
+@pytest.mark.parametrize("use_phase", [True, False],
+                         ids=["phase", "free"])
 @pytest.mark.parametrize("prog", ["series_packed", "grouped_packed"])
-def test_fused_program_packed(one_chip, as_tpu, prog):
+def test_fused_program_packed(one_chip, as_tpu, prog, use_phase):
     ncols = 4096
-    q = _query("phase", 100, 20)
-    kw = dict(q=q, row0=3, use_phase=True)
+    q = _query("phase" if use_phase else "free", 100, 20)
+    kw = dict(q=q, row0=3, use_phase=use_phase)
     fn = devicestore._fused_progs()[prog]
     if prog == "series_packed":
         _compile(fn, one_chip, _packed_block(ncols), _sds((), jnp.int64),

@@ -534,7 +534,7 @@ class TestShardMapperHealth:
     def test_anonymous_mapper_stays_silent(self):
         from filodb_tpu.utils.devicewatch import FLIGHT
         n_evs = len(FLIGHT.events(kind="shard.status"))
-        m = ShardMapper(2)  # no dataset: benches/ad-hoc tests
+        m = ShardMapper(2)  # no dataset: ad-hoc tests
         m.register_node([0], "n")
         m.update_status(0, ShardStatus.ACTIVE)
         assert len(FLIGHT.events(kind="shard.status")) == n_evs

@@ -1620,3 +1620,24 @@ class TestFrozenFrontierMemo:
         assert per_plan == [1 + 8] * 10
         assert walks() - walks0 <= 1
         assert cache.frontier_walks <= 2
+
+
+def test_fused_progs_are_published_whole(monkeypatch):
+    """A request that arrives while a process's first one is still
+    building the programs must see none of them or all.  Filled entry by
+    entry, the dict answered it as soon as it held one (``KeyError:
+    'grouped_batch'``, a 400 on a server's first panels, met by the
+    multi-server chaos tests on a loaded host)."""
+    from filodb_tpu.memstore import devicestore as dvs
+    names = sorted(dvs._fused_progs())
+    seen_partly = []
+
+    class Watched(dict):
+        def __setitem__(self, key, value):
+            seen_partly.append(sorted(self))    # what a reader finds now
+            super().__setitem__(key, value)
+
+    progs = Watched()
+    monkeypatch.setattr(dvs, "_FUSED_PROGS", progs)
+    assert dvs._fused_progs() is progs and sorted(progs) == names
+    assert not seen_partly

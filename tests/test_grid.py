@@ -15,9 +15,32 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from filodb_tpu.memstore.devicestore import (_ONEHOT_MAX_G,
+                                             _grouped_reduce_impl)
 from filodb_tpu.ops import windows
-from filodb_tpu.ops.grid import (GridQuery, rate_grid, rate_grid_grouped,
-                                 rate_grid_ref, supports_grid)
+from filodb_tpu.ops.grid import (GridQuery, rate_grid, rate_grid_ref,
+                                 supports_grid)
+from tests import oracle
+
+
+def _scattered_groups(n_lanes, num_groups, seed=5):
+    """lane -> group the way a served plan has it: groups interleaved
+    over the lanes, one lane in ten unrequested (the drop bucket)."""
+    rng = np.random.default_rng(seed)
+    garr = rng.integers(0, num_groups, n_lanes).astype(np.int32)
+    garr[rng.random(n_lanes) < 0.1] = num_groups
+    return garr
+
+
+def _assert_served_sum_by(stepped, ref, garr, num_groups, rtol):
+    """What serves ``sum by (g)``: the grid kernel's [T, lanes] output
+    through the device store's grouped reduce, against the portable
+    reference + a per-lane NumPy reduce."""
+    got = np.asarray(_grouped_reduce_impl(stepped, jnp.asarray(garr),
+                                          num_groups, "sum"))
+    want = oracle.grouped_reduce(np.asarray(ref), garr, num_groups, "sum")
+    np.testing.assert_allclose(got[0], want[0], rtol=rtol, atol=1e-5)
+    np.testing.assert_array_equal(got[1], want[1])
 
 
 def _clip(ts, vals):
@@ -165,8 +188,6 @@ class TestGridRef:
         q = GridQuery(len(steps), K, STEP, True)
         with pytest.raises(ValueError, match="multiple of lanes"):
             rate_grid(ts, vals, int(steps[0]), q, lanes=1024)
-        with pytest.raises(ValueError, match="group count"):
-            rate_grid_grouped(ts, vals, int(steps[0]), q, group_lanes=16)
         with pytest.raises(ValueError, match="rows"):
             rate_grid(ts[:3], vals[:3], int(steps[0]), q, lanes=16,
                       interpret=True)
@@ -196,19 +217,45 @@ class TestGridPallasInterpret:
 
     def test_grouped_kernel(self):
         ts, vals = self._data128()
-        # 8 groups x 16 lanes
         steps = _steps()
         q = GridQuery(len(steps), K, STEP, True)
-        s, c = rate_grid_grouped(ts, vals, int(steps[0]), q,
-                                 group_lanes=16, interpret=True)
-        r = np.asarray(rate_grid_ref(ts, vals, int(steps[0]), q))
-        s, c = np.asarray(s), np.asarray(c)
-        for g in range(8):
-            rg = r[:, g * 16:(g + 1) * 16]
-            ok = np.isfinite(rg)
-            np.testing.assert_allclose(s[g], np.where(ok, rg, 0).sum(axis=1),
-                                       rtol=1e-5, atol=1e-5)
-            np.testing.assert_array_equal(c[g], ok.sum(axis=1))
+        stepped = rate_grid(ts, vals, int(steps[0]), q, lanes=128,
+                            interpret=True)
+        ref = rate_grid_ref(ts, vals, int(steps[0]), q)
+        _assert_served_sum_by(stepped, ref, _scattered_groups(128, 8), 8,
+                              rtol=5e-5)
+
+
+class TestGroupedReduce:
+    """devicestore._grouped_reduce_impl, the XLA reduce every grouped
+    serving program ends in, against the per-lane NumPy reduce."""
+
+    # G + 1 <= _ONEHOT_MAX_G takes the one-hot matmul, past it the
+    # segment_sum fall-through; min/max take segment_min/max at any G
+    @pytest.mark.parametrize("num_groups", [8, _ONEHOT_MAX_G],
+                             ids=["onehot", "segment"])
+    @pytest.mark.parametrize("op", ["sum", "avg", "count", "moments",
+                                    "min", "max"])
+    def test_matches_numpy(self, op, num_groups):
+        rng = np.random.default_rng(17)
+        T, lanes = 6, 512
+        stepped = (rng.random((T, lanes)) * 200 - 50).astype(np.float32)
+        stepped[rng.random((T, lanes)) < 0.2] = np.nan
+        stepped[:, 40:48] = np.nan              # lanes with no answer
+        garr = _scattered_groups(lanes, num_groups)
+        garr[garr == 5] = num_groups
+        garr[40:44] = 5                         # group 5: NaN lanes only
+        garr[100:140] = 3
+        got = np.asarray(_grouped_reduce_impl(
+            jnp.asarray(stepped), jnp.asarray(garr), num_groups, op))
+        want = oracle.grouped_reduce(stepped, garr, num_groups, op)
+        assert got.shape == want.shape
+        if op in ("min", "max"):
+            assert np.isnan(want[5]).all() and np.isfinite(want[3]).all()
+        np.testing.assert_allclose(got, want, rtol=2e-5, equal_nan=True)
+        with pytest.raises(ValueError, match="unsupported grouped op"):
+            _grouped_reduce_impl(jnp.asarray(stepped), jnp.asarray(garr),
+                                 num_groups, "median")
 
 
 class TestGridAggOps:
@@ -656,20 +703,15 @@ class TestGridDense:
         steps = _steps()
         q = GridQuery(nsteps=len(steps), kbuckets=K, gstep_ms=STEP,
                       dense=True)
-        s, c = rate_grid_grouped(cts.astype(jnp.int32),
-                                 cvals.astype(jnp.float32),
-                                 int(steps[0]), q, group_lanes=16,
-                                 interpret=True)
-        r = np.asarray(rate_grid_ref(cts.astype(jnp.int32),
-                                     cvals.astype(jnp.float32),
-                                     int(steps[0]), q._replace(dense=False)))
-        s, c = np.asarray(s), np.asarray(c)
-        for g in range(8):
-            rg = r[:, g * 16:(g + 1) * 16]
-            ok = np.isfinite(rg)
-            np.testing.assert_allclose(s[g], np.where(ok, rg, 0).sum(axis=1),
-                                       rtol=1e-5, atol=1e-5)
-            np.testing.assert_array_equal(c[g], ok.sum(axis=1))
+        stepped = rate_grid(cts.astype(jnp.int32),
+                            cvals.astype(jnp.float32), int(steps[0]), q,
+                            lanes=128, interpret=True)
+        ref = rate_grid_ref(cts.astype(jnp.int32),
+                            cvals.astype(jnp.float32), int(steps[0]),
+                            q._replace(dense=False))
+        _assert_served_sum_by(stepped, ref,
+                              _scattered_groups(stepped.shape[1], 8), 8,
+                              rtol=5e-5)
 
     def test_strided_matches_unstrided_subsample(self):
         """stride=r output == every r-th step of the stride-1 output —
@@ -852,22 +894,14 @@ class TestPhaseMode:
         np.testing.assert_allclose(got[both], want[both], rtol=2e-5)
 
     def test_pallas_interpret_phase_grouped(self):
-        from filodb_tpu.ops.grid import rate_grid_grouped, rate_grid_ref
         cts, cvals, phase = _phase_data(n_series=128, n_empty=24)
         steps = _steps()
         q = GridQuery(len(steps), K, STEP, True, dense=True)
-        # 8 groups x 16 lanes
-        s, c = rate_grid_grouped(None, cvals.astype(jnp.float32),
-                                 int(steps[0]), q, group_lanes=16,
-                                 interpret=True, phase=phase)
-        per = np.asarray(rate_grid_ref(None, cvals, int(steps[0]), q,
-                                       phase=phase))   # [T, S]
-        for g in range(8):
-            seg = per[:, g*16:(g+1)*16]
-            want_s = np.nansum(np.where(np.isfinite(seg), seg, 0.0), axis=1)
-            want_c = np.isfinite(seg).sum(axis=1)
-            np.testing.assert_allclose(np.asarray(s)[g], want_s, rtol=2e-5)
-            np.testing.assert_array_equal(np.asarray(c)[g], want_c)
+        stepped = rate_grid(None, cvals.astype(jnp.float32), int(steps[0]),
+                            q, lanes=128, interpret=True, phase=phase)
+        ref = rate_grid_ref(None, cvals, int(steps[0]), q, phase=phase)
+        _assert_served_sum_by(stepped, ref, _scattered_groups(128, 8), 8,
+                              rtol=2e-5)
 
     def test_phase_mode_requires_dense(self):
         from filodb_tpu.ops.grid import _phase_mode

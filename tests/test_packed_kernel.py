@@ -1,5 +1,6 @@
 """Compressed-resident equivalence: the fused on-device XOR-class
-decode (ops/grid.py rate_grid_packed / rate_grid_grouped_packed) must be
+decode (ops/grid.py rate_grid_packed, and the device store's
+grouped_packed program over it) must be
 bit-identical to the CPU codec decode (codecs/xorgrid.py unpack_vals)
 and agree with the decoded-plane kernels across the layout's edge cases
 — NaN payloads, constant runs, sign flips, partial final tiles, mixed
@@ -7,7 +8,7 @@ classes, promote/pad alignment.  Pallas runs in interpret mode so the
 whole sweep executes in CPU CI (ISSUE 3 satellite).
 
 ISSUE 14 widens the sweep to the histogram bucket-plane substrate
-(stride packs + hist_grid_grouped_packed / hist_quantile_grid_packed),
+(stride packs + hist_grid_grouped_packed),
 the generic columnar scan-filter-topK program, and the devicestore
 mid-stream bucket-widening path (16 -> 20 buckets)."""
 
@@ -19,19 +20,20 @@ import jax.numpy as jnp
 
 from filodb_tpu.codecs.xorgrid import (LANE_BLOCK, UNPADDED_MAX, pack_vals,
                                        unpack_vals)
+from filodb_tpu.core.histogram import quantile_bulk
+from filodb_tpu.memstore import devicestore
 from filodb_tpu.ops import histogram_ops
 from filodb_tpu.ops.grid import (GridQuery, event_topk_grid_packed,
-                                 hist_grid_grouped_packed,
-                                 hist_quantile_grid_packed, packed_width,
-                                 rate_grid_grouped, rate_grid_grouped_packed,
+                                 hist_grid_grouped_packed, packed_width,
                                  rate_grid_packed, rate_grid_ref)
+from tests import oracle
 
 STEP = 60_000
 
 
 def _counters(rng, B, L, dtype=np.float32):
     """Integer-valued counters with a pinned f32 exponent: residuals
-    provably fit 16 bits (see bench.py gen_packed)."""
+    provably fit 16 bits."""
     start = (2 ** 23 + 128 * rng.integers(0, 2 ** 15, L)).astype(dtype)
     inc = 128 * rng.integers(1, 8, (B, L))
     return (start[None, :] + np.cumsum(inc, axis=0)).astype(dtype)
@@ -114,7 +116,7 @@ class TestPackRoundtrip:
                 assert n % LANE_BLOCK == 0 or n <= UNPADDED_MAX, (key, n)
 
     def test_min_width_forces_single_identity_plane(self):
-        """The bench's group-contiguity contract: class-16-guaranteed
+        """The hist kernel's group-contiguity contract: class-16-guaranteed
         counters with min_width=16 pack as ONE p16 plane in identity
         lane order."""
         rng = np.random.default_rng(3)
@@ -137,9 +139,22 @@ def _pack_dev(v, phase=None, **kw):
     return pk, {k: jnp.asarray(a) for k, a in pk.planes.items()}
 
 
+def _served_grouped_packed(dev, inv, garr, num_groups, q):
+    """``sum by (g)`` the way ``_dispatch_grouped`` serves a packed
+    plan: the group map scattered through the pack's ``inv`` (pad lanes
+    keep the drop bucket) into the ``devicestore.grouped_packed``
+    program.  -> (sum, count) [G, T]."""
+    garr_pk = np.full(packed_width(dev), num_groups, np.int32)
+    garr_pk[inv] = garr
+    both = devicestore._fused_progs()["grouped_packed"](
+        dev, 0, jnp.asarray(garr_pk), q=q, row0=0, use_phase=True,
+        num_groups=num_groups, op="sum", interpret=True)
+    return np.asarray(both)
+
+
 class TestFusedKernelEquivalence:
-    """rate_grid_packed / rate_grid_grouped_packed in interpret mode vs
-    the decoded-plane oracle kernels."""
+    """rate_grid_packed, alone and under the device store's grouped
+    reduce, in interpret mode vs the decoded-plane reference."""
 
     @pytest.mark.parametrize("row0", [0, 3, 9])
     def test_phase_rate_matches_ref(self, row0):
@@ -182,27 +197,29 @@ class TestFusedKernelEquivalence:
         assert (np.isfinite(out) == fin).all()
         np.testing.assert_allclose(out[fin], ref[fin], rtol=1e-6)
 
-    def test_grouped_packed_matches_grouped(self):
-        """The fully fused grouped kernel (the north-star variant) vs
-        the decoded-plane grouped phase kernel: identical partials."""
+    def test_grouped_packed_matches_decoded(self):
+        """The served grouped_packed program (decode in the kernel, the
+        XLA reduce after it) over a MIXED-class pack, where packed lane
+        order is not the request's, vs the decoded-plane reference + a
+        per-lane NumPy reduce."""
         rng = np.random.default_rng(13)
-        B, L, GL = 59, 1024, 128
+        B, L, G = 59, 1024, 8
         v = _counters(rng, B, L)
+        v[:, 256:384] = (rng.random((B, 128)) * 100).astype(np.float32)
         v[:, 500:520] = np.nan
         phase = rng.integers(1, STEP, L).astype(np.int32)
-        pk, dev = _pack_dev(v, phase=phase, min_width=16)
-        assert (pk.inv == np.arange(L)).all()
+        pk, dev = _pack_dev(v, phase=phase)
+        assert not (pk.inv == np.arange(L)).all()
         T, K = 20, 5
         q = GridQuery(nsteps=T, kbuckets=K, gstep_ms=STEP, is_rate=True,
                       dense=True)
-        s_pk, c_pk = rate_grid_grouped_packed(dev, 0, q, group_lanes=GL,
-                                              interpret=True)
-        s_ph, c_ph = rate_grid_grouped(None, jnp.asarray(v), 0, q,
-                                       group_lanes=GL, interpret=True,
-                                       phase=phase)
-        np.testing.assert_array_equal(np.asarray(c_pk), np.asarray(c_ph))
-        np.testing.assert_allclose(np.asarray(s_pk), np.asarray(s_ph),
-                                   rtol=1e-6)
+        garr = rng.integers(0, G + 1, L).astype(np.int32)   # G = dropped
+        got = _served_grouped_packed(dev, pk.inv, garr, G, q)
+        ref = np.asarray(rate_grid_ref(None, jnp.asarray(v[:T + K - 1]),
+                                       0, q, phase=phase))
+        want = oracle.grouped_reduce(ref, garr, G, "sum")
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
 
     def test_packed_width_and_validation(self):
         rng = np.random.default_rng(14)
@@ -219,10 +236,10 @@ class TestFusedKernelEquivalence:
             rate_grid_packed(dev, 0, qbad, interpret=True,
                              use_phase=False)
 
-    def test_grouped_packed_rejects_padded_packs(self):
-        """Alignment-pad lanes decode to finite 0.0 series; with no
-        group map to drop them the fused grouped kernel would count
-        them as live — it must refuse such packs."""
+    def test_grouped_packed_drops_pad_lanes(self):
+        """Alignment-pad lanes decode to finite 0.0 series: the served
+        grouped program must drop them through the group map, or each
+        would count as a live series of some group."""
         rng = np.random.default_rng(16)
         B, L = 64, 896
         v = _counters(rng, B, L)
@@ -236,10 +253,17 @@ class TestFusedKernelEquivalence:
         planes["first"] = np.pad(planes["first"], (0, 128))
         dev = {k: jnp.asarray(a) for k, a in planes.items()}
         assert packed_width(dev) == L + 128 > dev["inv"].shape[0]
-        q = GridQuery(nsteps=8, kbuckets=4, gstep_ms=STEP, dense=True)
-        with pytest.raises(ValueError, match="pad lanes"):
-            rate_grid_grouped_packed(dev, 0, q, group_lanes=128,
-                                     interpret=True)
+        T, K, G = 8, 4, 7
+        q = GridQuery(nsteps=T, kbuckets=K, gstep_ms=STEP, dense=True)
+        garr = (np.arange(L) // 128).astype(np.int32)
+        got = _served_grouped_packed(dev, pk.inv, garr, G, q)
+        ref = np.asarray(rate_grid_ref(
+            None, jnp.asarray(v[:T + K - 1]), 0, q,
+            phase=np.zeros(L, np.int32)))
+        want = oracle.grouped_reduce(ref, garr, G, "sum")
+        assert (want[1] == 128).all()
+        np.testing.assert_array_equal(got[1], want[1])
+        np.testing.assert_allclose(got[0], want[0], rtol=2e-5)
 
     def test_event_topk_matches_ref(self):
         """Generic columnar scan-filter-topK over a MIXED-class pack:
@@ -448,9 +472,9 @@ class TestHistStridePack:
 
 
 class TestHistFusedKernels:
-    """hist_grid_grouped_packed / hist_quantile_grid_packed in
-    interpret mode vs the decoded-plane reference + the shared
-    hist-quantile math (ISSUE 14 tentpole 2)."""
+    """hist_grid_grouped_packed in interpret mode vs the decoded-plane
+    reference, alone and under the hist-quantile interpolation
+    (ISSUE 14 tentpole 2)."""
 
     @pytest.mark.parametrize("hb,row0", [(4, 0), (8, 3), (20, 0)])
     def test_grouped_matches_ref(self, hb, row0):
@@ -483,7 +507,7 @@ class TestHistFusedKernels:
         np.testing.assert_allclose(s, want, rtol=2e-5)
         np.testing.assert_array_equal(c, wcnt)
 
-    def test_quantile_matches_shared_math(self):
+    def test_quantile_matches_numpy(self):
         rng = np.random.default_rng(32)
         hb, per, gh = 8, 16, 4
         v, phase = _hist_plane(rng, 64, per * gh, hb)
@@ -494,19 +518,26 @@ class TestHistFusedKernels:
         q = GridQuery(nsteps=T, kbuckets=K, gstep_ms=STEP, is_rate=True,
                       dense=True)
         tops = np.concatenate([2.0 ** np.arange(hb - 1), [np.inf]])
-        out = np.asarray(hist_quantile_grid_packed(
-            dev, 0, jnp.asarray(tops), q, 0.99, hb,
-            group_lanes=per * hb, interpret=True))
-        s, _c = hist_grid_grouped_packed(dev, 0, q, hb,
-                                         group_lanes=per * hb,
-                                         interpret=True, use_phase=True)
-        hist_sum = np.asarray(s).reshape(gh, hb, T).transpose(0, 2, 1)
-        want = np.asarray(histogram_ops.hist_quantile(
-            jnp.asarray(tops), jnp.asarray(hist_sum), 0.99))
-        # the fused program inlines the grouped kernel under one jit;
-        # XLA's reassociation shifts the f32 sums by ~1 ulp vs the
-        # standalone call, which the interpolation divides amplify
-        np.testing.assert_allclose(out, want, rtol=2e-5)
+        s, c = hist_grid_grouped_packed(dev, 0, q, hb,
+                                        group_lanes=per * hb,
+                                        interpret=True, use_phase=True)
+        hist_sum, count = devicestore.hist_planes_split(
+            jnp.stack([s, c]), gh, hb)
+        out = np.asarray(histogram_ops.hist_quantile(
+            jnp.asarray(tops), hist_sum, 0.99))
+        assert (np.asarray(count) == per).all()
+        # NumPy all the way: per-column rates, a per-lane bucket reduce
+        # (slot g*hb + j), the host's histogram_quantile
+        ref = np.asarray(rate_grid_ref(None, jnp.asarray(v[:T + K - 1]),
+                                       0, q, phase=phase))
+        cols = np.arange(per * gh * hb)
+        slots = (cols // (per * hb)) * hb + cols % hb
+        rows = oracle.grouped_reduce(ref, slots, gh * hb, "sum")[0]
+        rows = rows.reshape(gh, hb, T).transpose(0, 2, 1)
+        want = quantile_bulk(tops, rows.reshape(gh * T, hb),
+                             0.99).reshape(gh, T)
+        # the interpolation's divides amplify the f32 sums' last ulps
+        np.testing.assert_allclose(out, want, rtol=2e-4)
 
     def test_free_op_sum_over_time_no_phase(self):
         """TS_FREE hist shape (sum_over_time over buckets) takes the

@@ -1287,10 +1287,18 @@ class TestGroupHeadPromotion:
         n = 300
         for i in range(n):
             stream.push(_container(i, n_inst=37))
+        from filodb_tpu.coordinator.cluster import IngestionStarted
         events = []
+        promoted = threading.Event()
+
+        def sink(e):
+            events.append(e)
+            if isinstance(e, IngestionStarted):
+                promoted.set()
+
         ic = IngestionCoordinator(
             "n", "prom", DEFAULT_SCHEMAS, store, factory,
-            event_sink=events.append, recovery_report_interval=5,
+            event_sink=sink, recovery_report_interval=5,
             group_head_fn=lambda shard: n - 1)
         stop = threading.Event()
         churn_errors = []
@@ -1309,12 +1317,9 @@ class TestGroupHeadPromotion:
         t = threading.Thread(target=churn, daemon=True)
         t.start()
         ic.start_ingestion(0)
-        from filodb_tpu.coordinator.cluster import IngestionStarted
-        deadline = time.time() + 20
-        while time.time() < deadline:
-            if any(isinstance(e, IngestionStarted) for e in events):
-                break
-            time.sleep(0.01)
+        # the event itself, under a cap sized for a host that runs six
+        # test workers: promotion that never comes still fails below
+        promoted.wait(timeout=90)
         stop.set()
         t.join(timeout=5)
         ic.stop_all()

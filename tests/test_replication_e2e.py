@@ -296,19 +296,38 @@ class TestChaosKillFailoverRejoin:
         # ---- queries in flight while the node dies
         results = []
 
-        def query_loop(seconds):
-            t_end = time.time() + seconds
-            while time.time() < t_end:
+        # a COUNT of answers on each side of the kill, not a rate of this
+        # host: at least 5 before it, 16 after it, and the old 6 s
+        killed_at = []              # len(results) when the node died
+
+        def query_loop(seconds, after, wall):
+            t0 = time.time()
+            while time.time() < t0 + wall:
+                if (killed_at and len(results) >= killed_at[0] + after
+                        and time.time() >= t0 + seconds):
+                    return
                 q = (RATE_Q, COUNT_Q, SUM_Q)[len(results) % 3]
                 code, body, headers = _query(ports["ha-a"], q)
                 results.append((q, code, body, headers))
                 time.sleep(0.05)
 
-        qt = threading.Thread(target=query_loop, args=(6.0,), daemon=True)
+        qt = threading.Thread(target=query_loop, args=(6.0, 16, 180.0),
+                              daemon=True)
         qt.start()
-        time.sleep(0.8)            # mid-query, mid-ingest ...
-        chaos.kill("ha-b")         # ... hard node kill
-        qt.join(timeout=30)
+        t_kill = time.time() + 0.8
+        while qt.is_alive() and (time.time() < t_kill or len(results) < 5):
+            time.sleep(0.01)
+        # what the coordinator's routing saw at the kill, for the
+        # failover assertion's message: (node, status, head - watermark)
+        m = cluster["servers"]["ha-a"].manager.mapper("ha")
+        routes = {s: [(r.node, r.status.value,
+                       m.group_head(s) - r.watermark)
+                      for r in m.replicas(s)] for s in range(NUM_SHARDS)}
+        chaos.kill("ha-b")          # hard node kill, mid-query, mid-ingest
+        killed_at.append(len(results))
+        qt.join(timeout=240)
+        assert len(results) >= killed_at[0] + 16, \
+            f"{len(results) - killed_at[0]} answers in 180 s after the kill"
 
         assert len(results) > 20
         bad = [(q, code) for q, code, body, _h in results if code != 200
@@ -323,7 +342,9 @@ class TestChaosKillFailoverRejoin:
                 f"mid-kill result diverged from oracle for {q}"
         # and the kill actually exercised replica failover
         assert failover.total() > failover_before, \
-            "no failover happened — the kill never hit a routed replica"
+            f"no failover happened — the kill never hit a routed replica " \
+            f"({killed_at[0]} answers before it, {len(results)} in all; " \
+            f"replica groups at the kill: {routes})"
 
     def test_2_survivors_demote_dead_replicas(self, cluster):
         servers = cluster["servers"]

@@ -371,7 +371,7 @@ class TestTopologyGenerationLint:
     def test_off_serving_path_is_exempt(self):
         assert not A.unsuppressed(A.run_source(
             BAD_PUBLISHER, rules=["topology-generation"],
-            rel="benches/fake.py"))
+            rel="stress/fake.py"))
 
     def test_tree_is_clean(self):
         # the full-tree tier-1 gate in test_analysis covers every rule;

@@ -308,19 +308,32 @@ class TestChaosSplit:
         # ---- queries in flight while a child-holding node dies
         results = []
 
-        def query_loop(seconds):
-            t_end = time.time() + seconds
-            while time.time() < t_end:
+        # a COUNT of answers on each side of the kill, not a rate of this
+        # host: at least 5 before it, 16 after it, and the old 5 s
+        killed_at = []              # len(results) when the node died
+
+        def query_loop(seconds, after, wall):
+            t0 = time.time()
+            while time.time() < t0 + wall:
+                if (killed_at and len(results) >= killed_at[0] + after
+                        and time.time() >= t0 + seconds):
+                    return
                 q = (RATE_Q, COUNT_Q, SUM_Q)[len(results) % 3]
                 code, body, headers = _query(ports["sp-a"], q)
                 results.append((q, code, body, headers))
                 time.sleep(0.05)
 
-        qt = threading.Thread(target=query_loop, args=(5.0,), daemon=True)
+        qt = threading.Thread(target=query_loop, args=(5.0, 16, 180.0),
+                              daemon=True)
         qt.start()
-        time.sleep(0.8)
+        t_kill = time.time() + 0.8
+        while qt.is_alive() and (time.time() < t_kill or len(results) < 5):
+            time.sleep(0.01)
         chaos.kill("sp-b")          # hard kill mid-catch-up
-        qt.join(timeout=30)
+        killed_at.append(len(results))
+        qt.join(timeout=240)
+        assert len(results) >= killed_at[0] + 16, \
+            f"{len(results) - killed_at[0]} answers in 180 s after the kill"
 
         assert len(results) > 20
         bad = [(q, code) for q, code, body, _h in results if code != 200
