@@ -9,7 +9,6 @@ PromQueryResponse shapes (query/.../PromQueryResponse.scala).
 from __future__ import annotations
 
 import math
-from typing import Optional
 
 import numpy as np
 
@@ -31,21 +30,40 @@ def _fmt(v: float) -> str:
 def public_tags(tags: dict, metric_column: str = "_metric_") -> dict:
     """Internal metric column -> Prometheus ``__name__`` on the way out
     (reference: PrometheusModel metric-name conversion)."""
-    if metric_column in tags:
-        out = {k: v for k, v in tags.items() if k != metric_column}
-        out["__name__"] = tags[metric_column]
-        return out
-    return dict(tags)
+    out = dict(tags)
+    if metric_column in out:
+        out["__name__"] = out.pop(metric_column)
+    return out
 
 
-def _matrix_entry(tags: dict, ts_ms: np.ndarray, vals: np.ndarray,
-                  metric_column: str = "_metric_") -> Optional[dict]:
-    fin = ~np.isnan(vals)
-    if not fin.any():
-        return None
-    return {"metric": public_tags(tags, metric_column),
-            "values": [[ts_ms[i] / 1000.0, _fmt(float(vals[i]))]
-                       for i in np.flatnonzero(fin)]}
+# Arrays cross into Python lists once a batch (``.tolist()``), never a
+# cell: a NumPy scalar indexed, divided or ``float()``ed a cell costs
+# more than the rest of the answer (doc/observability.md, "The API edge").
+def _seconds(ts_ms) -> list:
+    return (np.asarray(ts_ms) / 1000.0).tolist()
+
+
+def _step_seconds(steps, upto_ms=None) -> list:
+    """The step grid in seconds (to ``upto_ms`` where given) from a
+    ``range``: the ``arange`` of ``steps.timestamps()`` would be the one
+    call of an answer that drops the interpreter lock."""
+    end = steps.end if upto_ms is None else min(steps.end, upto_ms)
+    return [t / 1000.0 for t in range(steps.start, end + 1, steps.step)]
+
+
+def _floats(vals) -> list:
+    a = np.asarray(vals)    # .tolist() widens a float of any width exactly
+    return (a if a.dtype.kind == "f" else a.astype(np.float64)).tolist()
+
+
+def _cells(secs: list, row: list) -> list:
+    """One series' ``values`` from two lists of Python floats: a
+    ``[seconds, text]`` for every cell that is not NaN, the text exactly
+    :func:`_fmt`'s.  Whole numbers and fractions are written in place;
+    only an infinity pays the call."""
+    return [[t, "%d" % v if v.is_integer() and -1e15 < v < 1e15
+             else repr(v) if -math.inf < v < math.inf else _fmt(v)]
+            for t, v in zip(secs, row) if v == v]
 
 
 def _attach_warnings(resp: dict, result: QueryResult) -> dict:
@@ -79,25 +97,28 @@ def to_prom_matrix(result: QueryResult,
                    metric_column: str = "_metric_") -> dict:
     """Range-query response (resultType=matrix)."""
     out = []
+
+    def series(tags: dict, secs: list, row: list, column: str) -> None:
+        values = _cells(secs, row)
+        if values:                      # an all-NaN series is omitted
+            out.append({"metric": public_tags(tags, column),
+                        "values": values})
+
     for b in result.batches:
         if isinstance(b, PeriodicBatch):
-            for tags, ts, vals in b.to_series():
-                e = _matrix_entry(tags, ts, vals, metric_column)
-                if e is not None:
-                    out.append(e)
+            secs = _step_seconds(b.steps)
+            for tags, row in zip(b.keys, _floats(b.np_values())):
+                series(tags, secs, row, metric_column)
         elif isinstance(b, ScalarResult):
-            ts = np.asarray(b.steps.timestamps())
-            e = _matrix_entry({}, ts, np.asarray(b.values))
-            if e is not None:
-                out.append(e)
+            series({}, _step_seconds(b.steps), _floats(b.values),
+                   metric_column)
         elif isinstance(b, RawBatch) and b.batch is not None:
-            for i, tags in enumerate(b.keys):
-                n = int(b.batch.row_counts[i])
-                e = _matrix_entry(tags,
-                                  np.asarray(b.batch.timestamps[i][:n]),
-                                  np.asarray(b.batch.values[i][:n]))
-                if e is not None:
-                    out.append(e)
+            # a raw export has always renamed the default column only
+            # (ROADMAP D14)
+            for tags, n, secs, row in zip(
+                    b.keys, np.asarray(b.batch.row_counts).tolist(),
+                    _seconds(b.batch.timestamps), _floats(b.batch.values)):
+                series(tags, secs[:n], row[:n], "_metric_")
     return _attach_warnings(
         {"status": "success",
          "data": {"resultType": "matrix", "result": out}}, result)
@@ -108,23 +129,23 @@ def to_prom_vector(result: QueryResult, time_ms: int,
     """Instant-query response (resultType=vector): last value at/before
     the evaluation timestamp."""
     out = []
+    at = time_ms / 1000.0
     for b in result.batches:
         if isinstance(b, PeriodicBatch):
-            for tags, ts, vals in b.to_series():
-                fin = np.flatnonzero(~np.isnan(vals) & (ts <= time_ms))
-                if len(fin):
-                    i = fin[-1]
+            upto = len(_step_seconds(b.steps, time_ms))
+            rows = _floats(b.np_values()[:, :upto])
+            for tags, row in zip(b.keys, rows):
+                last = next((v for v in reversed(row) if v == v), None)
+                if last is not None:
                     out.append({"metric": public_tags(tags, metric_column),
-                                "value": [time_ms / 1000.0,
-                                          _fmt(float(vals[i]))]})
+                                "value": [at, _fmt(last)]})
         elif isinstance(b, ScalarResult):
-            vals = np.asarray(b.values)
-            if len(vals):
+            vals = _floats(b.values)
+            if vals:
                 return _attach_warnings(
                     {"status": "success",
                      "data": {"resultType": "scalar",
-                              "value": [time_ms / 1000.0,
-                                        _fmt(float(vals[-1]))]}}, result)
+                              "value": [at, _fmt(vals[-1])]}}, result)
     return _attach_warnings(
         {"status": "success",
          "data": {"resultType": "vector", "result": out}}, result)

@@ -196,3 +196,113 @@ def grouped_reduce(stepped: np.ndarray, garr: np.ndarray, num_groups: int,
     if op == "max":
         return np.where(planes[1] > 0, hi, np.nan)
     return planes[:3 if op == "moments" else 2]
+
+
+# ---------------------------------------------------------------------------
+# The Prometheus JSON of a result, a cell at a time: ``http/model.py`` as it
+# was before PR 32, moved here unchanged as the reference that
+# ``to_prom_matrix`` / ``to_prom_vector`` are held to, byte for byte
+# (tests/test_http_model.py).  Only the warnings' counters are left out.
+# ---------------------------------------------------------------------------
+
+def prom_fmt(v: float) -> str:
+    """Prometheus value formatting: shortest repr, NaN as \"NaN\"."""
+    import math
+    if math.isnan(v):
+        return "NaN"
+    if math.isinf(v):
+        return "+Inf" if v > 0 else "-Inf"
+    if v == int(v) and abs(v) < 1e15:
+        return str(int(v))
+    return repr(v)
+
+
+def prom_public_tags(tags: dict, metric_column: str = "_metric_") -> dict:
+    if metric_column in tags:
+        out = {k: v for k, v in tags.items() if k != metric_column}
+        out["__name__"] = tags[metric_column]
+        return out
+    return dict(tags)
+
+
+def _prom_matrix_entry(tags: dict, ts_ms: np.ndarray, vals: np.ndarray,
+                       metric_column: str = "_metric_"):
+    fin = ~np.isnan(vals)
+    if not fin.any():
+        return None
+    return {"metric": prom_public_tags(tags, metric_column),
+            "values": [[ts_ms[i] / 1000.0, prom_fmt(float(vals[i]))]
+                       for i in np.flatnonzero(fin)]}
+
+
+def _prom_warnings(resp: dict, result) -> dict:
+    warnings = []
+    n = result.stats.corrupt_chunks_excluded
+    if n:
+        warnings.append(
+            f"partial data: {n} corrupt chunk(s) quarantined and "
+            f"excluded from results (see /admin/integrity)")
+    down = result.stats.shards_down
+    if down:
+        warnings.append(
+            f"partial data: {down} shard(s) unreachable; their series "
+            f"are missing from results (allow_partial_results)")
+    if warnings:
+        resp["warnings"] = warnings
+    return resp
+
+
+def prom_matrix(result, metric_column: str = "_metric_") -> dict:
+    """Range-query response (resultType=matrix)."""
+    from filodb_tpu.query.model import PeriodicBatch, RawBatch, ScalarResult
+    out = []
+    for b in result.batches:
+        if isinstance(b, PeriodicBatch):
+            for tags, ts, vals in b.to_series():
+                e = _prom_matrix_entry(tags, ts, vals, metric_column)
+                if e is not None:
+                    out.append(e)
+        elif isinstance(b, ScalarResult):
+            ts = np.asarray(b.steps.timestamps())
+            e = _prom_matrix_entry({}, ts, np.asarray(b.values))
+            if e is not None:
+                out.append(e)
+        elif isinstance(b, RawBatch) and b.batch is not None:
+            for i, tags in enumerate(b.keys):
+                n = int(b.batch.row_counts[i])
+                e = _prom_matrix_entry(tags,
+                                       np.asarray(b.batch.timestamps[i][:n]),
+                                       np.asarray(b.batch.values[i][:n]))
+                if e is not None:
+                    out.append(e)
+    return _prom_warnings(
+        {"status": "success",
+         "data": {"resultType": "matrix", "result": out}}, result)
+
+
+def prom_vector(result, time_ms: int, metric_column: str = "_metric_") -> dict:
+    """Instant-query response (resultType=vector): last value at/before
+    the evaluation timestamp."""
+    from filodb_tpu.query.model import PeriodicBatch, ScalarResult
+    out = []
+    for b in result.batches:
+        if isinstance(b, PeriodicBatch):
+            for tags, ts, vals in b.to_series():
+                fin = np.flatnonzero(~np.isnan(vals) & (ts <= time_ms))
+                if len(fin):
+                    i = fin[-1]
+                    out.append({"metric": prom_public_tags(tags,
+                                                           metric_column),
+                                "value": [time_ms / 1000.0,
+                                          prom_fmt(float(vals[i]))]})
+        elif isinstance(b, ScalarResult):
+            vals = np.asarray(b.values)
+            if len(vals):
+                return _prom_warnings(
+                    {"status": "success",
+                     "data": {"resultType": "scalar",
+                              "value": [time_ms / 1000.0,
+                                        prom_fmt(float(vals[-1]))]}}, result)
+    return _prom_warnings(
+        {"status": "success",
+         "data": {"resultType": "vector", "result": out}}, result)

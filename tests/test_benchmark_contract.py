@@ -106,3 +106,80 @@ def test_served_programs_are_devicestore_and_stacked_say_batch():
         assert name.startswith("devicestore."), (key, name)
         assert ("_batch" in name) == key.endswith("_batch"), (key, name)
     assert batch_metrics()["members"].name == "filodb_batch_members_total"
+
+
+_T0 = 1_700_000_000_000
+
+
+@pytest.fixture()
+def live_server():
+    """One shard, four whole-number series, a threaded server."""
+    from filodb_tpu.coordinator.planner import SingleClusterPlanner
+    from filodb_tpu.core.record import RecordBuilder, decode_container
+    from filodb_tpu.core.schemas import DEFAULT_SCHEMAS, DatasetOptions
+    from filodb_tpu.http.server import DatasetBinding, FiloHttpServer
+    from filodb_tpu.memstore.memstore import TimeSeriesMemStore
+    from filodb_tpu.parallel.shardmap import ShardMapper, ShardStatus
+    mapper = ShardMapper(1)
+    mapper.register_node([0], "local")
+    mapper.update_status(0, ShardStatus.ACTIVE)
+    ms = TimeSeriesMemStore()
+    ms.setup("prom", DEFAULT_SCHEMAS, 0)
+    builder = RecordBuilder(DEFAULT_SCHEMAS["prom-counter"])
+    for i in range(4):
+        tags = {"__name__": "heap_usage", "instance": f"i{i}",
+                "_ws_": "demo", "_ns_": "App-0"}
+        for row in range(60):
+            builder.add(_T0 + row * 10_000, [float(1000 * i + row)], tags)
+    for off, c in enumerate(builder.containers()):
+        ms.get_shard("prom", 0).ingest(
+            list(decode_container(c, DEFAULT_SCHEMAS)), off)
+    srv = FiloHttpServer()
+    srv.bind_dataset(DatasetBinding(
+        "prom", ms, SingleClusterPlanner("prom", mapper, DatasetOptions(),
+                                         spread_default=0)))
+    port = srv.start()
+    yield port
+    srv.shutdown()
+
+
+def _query_range(port: int) -> list:
+    import urllib.parse
+    import urllib.request
+    qs = urllib.parse.urlencode({
+        "query": 'heap_usage{_ws_="demo",_ns_="App-0"}',
+        "start": _T0 / 1000 + 100, "end": _T0 / 1000 + 500, "step": "50s"})
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}/promql/prom/api/v1/query_range?{qs}",
+            timeout=30) as resp:
+        return json.loads(resp.read())["data"]["result"]
+
+
+def test_answer_altered_still_alters_what_a_client_reads(live_server,
+                                                         monkeypatch):
+    """``selftest/broken_run.py`` plants ``answer_altered`` by wrapping
+    ``to_prom_matrix`` in ``http.model`` and ``http.server`` and editing
+    ``values[k]`` of the first series of the dict it returns.  So the
+    served path calls it through ``http/server.py``'s module global at
+    request time, gets lists it can assign into, and answers with
+    ``json.dumps`` of that dict: a writer that goes from arrays straight
+    to bytes would leave the self-test's fault with no teeth."""
+    import importlib.util
+    import sys
+
+    from filodb_tpu.http import model, server
+    sound = _query_range(live_server)
+    assert len(sound) == 4 and all(len(s["values"]) == 9 for s in sound)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    for mod in (model, server):               # put back when the test ends
+        monkeypatch.setattr(mod, "to_prom_matrix", mod.to_prom_matrix)
+    spec = importlib.util.spec_from_file_location(
+        "_broken_run", ROOT / "benchmark" / "selftest" / "broken_run.py")
+    broken_run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(broken_run)
+    broken_run.plant("answer_altered")
+    altered = _query_range(live_server)
+    t, v = sound[0]["values"][4]
+    assert altered[0]["values"][4] == [t, repr(float(v) * 1.0001)]
+    del altered[0]["values"][4], sound[0]["values"][4]
+    assert altered == sound                    # and nothing else moved
