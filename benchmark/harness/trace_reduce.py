@@ -6,14 +6,17 @@ trace is read.
 
 ``busy_s``      seconds in which an operation ran, union of intervals,
                 averaged over the device planes
+``busy_s_by_device``  the same of each device plane, [plane's name, seconds]
+``devices``     how many device planes the trace holds
 ``device_ops``  the ten operations with most device time, [name, seconds],
                 summed over the chips, under the trace's own names cut short:
                 ``<module>:<op>``, the module (``XLA Modules`` line, without
                 its fingerprint) that the operation ran inside and the
                 operation's name up to its `` = ``
-``idle_gaps``   the ten longest intervals in which nothing ran on the first
-                device, each named by the host event (TraceMe, not the Python
-                tracer) that covers most of it, where one covers half
+``idle_gaps``   the ten longest intervals in which nothing ran on ANY
+                device (what the planes' idle time has in common), each named
+                by the host event (TraceMe, not the Python tracer) that
+                covers most of it, where one covers half
 ``mosaic_s``    seconds of operations whose HLO text names ``tpu_custom_call``
                 (a Pallas kernel), summed over the chips
 ``planes``      an inventory, for a reader to check the names against
@@ -68,7 +71,7 @@ def reduce(path: str, window_s: float) -> dict:
     devices = [p for p in planes if p.name.startswith(DEVICE_PREFIX)]
     per_op: dict = {}
     mosaic = 0.0
-    busy, first_union = [], None
+    busy, everywhere = [], []
     for p in devices:
         spans = []
         modules = sorted(
@@ -91,18 +94,18 @@ def reduce(path: str, window_s: float) -> dict:
                 if MOSAIC in e.name:
                     mosaic += (b - a) / 1e9
         merged = union(spans)
-        if first_union is None:
-            first_union = merged
+        everywhere += merged
         busy.append(sum(b - a for a, b in merged) / 1e9)
     out = {"window_s": window_s, "devices": len(devices),
            "busy_s": (sum(busy) / len(busy)) if busy else None,
+           "busy_s_by_device": [[p.name, s] for p, s in zip(devices, busy)],
            "device_ops": [[k, v] for k, v in sorted(
                per_op.items(), key=lambda kv: -kv[1])[:10]],
            "idle_gaps": [], "mosaic_s": mosaic, "planes": inventory}
     if not devices:
         return out
     gaps, at = [], 0.0
-    for a, b in first_union + [(end_ns, end_ns)]:
+    for a, b in union(everywhere) + [(end_ns, end_ns)]:
         if a > at:
             gaps.append((at, a))
         at = max(at, b)

@@ -158,11 +158,19 @@ def warm_requests(traffic: dict, staging: list, seed: int, pop_spec: dict,
 
 def burst_requests(traffic: dict, seed: int, pop_spec: dict, dataset: str,
                    timeout_s: int, stats: bool, sizes) -> list:
-    """For each panel with a ``select`` and each size, that many requests of
-    the panel over different namespaces, then over one: what set-up sends
-    together (two sessions do ask one namespace at once now and then)."""
+    """For each panel and each size, that many requests of the panel: what
+    set-up sends together.  A panel with a ``select`` is sent over different
+    namespaces, then over one (two sessions do ask one namespace at once now
+    and then); a panel without a draw has ONE request, which sessions playing
+    it send together all the time: that many copies of it."""
     ns_map = namespace_map(traffic, seed, pop_spec)
-    return [[request_for(p, pi, ns_map[i * spread % len(ns_map)], pop_spec,
-                         dataset, timeout_s, stats) for i in range(k)]
-            for pi, p in enumerate(traffic["panels"]) if p.get("select")
-            for k in sizes for spread in (1, 0)]
+
+    def bursts_of(panel: dict, k: int) -> list:
+        if not panel.get("select"):
+            return [[-1] * k]
+        return [[ns_map[i * spread % len(ns_map)] for i in range(k)]
+                for spread in (1, 0)]
+    return [[request_for(p, pi, ns, pop_spec, dataset, timeout_s, stats)
+             for ns in burst]
+            for pi, p in enumerate(traffic["panels"])
+            for k in sizes for burst in bursts_of(p, k)]

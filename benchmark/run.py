@@ -70,6 +70,13 @@ BREAKERS = (
     ("filodb_tpu.batching.batcher", "batching_broken", lambda f: bool(f())),
 )
 MOSAIC = "tpu_custom_call"      # in the HLO text of a Pallas kernel's op
+# the device path's programs, by the name ``filodb_kernel_launches_total``
+# gives them: the per-shard rung's and the mesh fabric's.  A launch of one
+# answers a dispatch; a stacked one (``_batch`` in its name) answers several
+# and is counted by its members; a helper stages or pads and answers none.
+SERVING_FAMILIES = ("devicestore.", "meshgrid.")
+STACKED = "_batch"
+HELPERS = ("devicestore.mesh_stage", "meshgrid.pad")
 
 
 def say(msg: str) -> None:
@@ -135,21 +142,24 @@ def admin_device(port: int) -> dict:
     return doc.get("data", doc)
 
 
-def device_dispatches(port: int) -> float:
-    """How many leaf dispatches the device store has served, from /metrics:
-    the launches of its programs that serve one dispatch each, plus the
-    members of the stacked launches (``devicestore.*_batch`` serve several,
-    counted by ``filodb_batch_members_total``)."""
+def device_dispatches(port: int) -> dict:
+    """How many dispatches the device path has served, from /metrics, by who
+    took them: the launches of each family's programs that answer one
+    dispatch each (a leaf of the per-shard rung; a fused mesh launch, which
+    answers all of a request's shards at once), and the ``members`` of the
+    stacked launches (``filodb_batch_members_total``)."""
     _c, _h, body = http_get(port, "/metrics")
-    total = 0.0
+    served = dict.fromkeys(SERVING_FAMILIES + ("members",), 0.0)
     for ln in body.decode().splitlines():
-        if ln.startswith("filodb_kernel_launches_total{") \
-                and 'program="devicestore.' in ln \
-                and "_batch" not in ln.split('program="', 1)[1].split('"')[0]:
-            total += float(ln.rsplit(" ", 1)[1])
+        if ln.startswith("filodb_kernel_launches_total{"):
+            program = ln.split('program="', 1)[1].split('"')[0]
+            family = program.split(".")[0] + "."
+            if family in served and STACKED not in program \
+                    and program not in HELPERS:
+                served[family] += float(ln.rsplit(" ", 1)[1])
         elif ln.startswith("filodb_batch_members_total"):
-            total += float(ln.rsplit(" ", 1)[1])
-    return total
+            served["members"] += float(ln.rsplit(" ", 1)[1])
+    return served
 
 
 # ------------------------------------------------------------------- set-up
@@ -449,16 +459,16 @@ def device_checks(native_mods) -> dict:
     return out
 
 
-def bytes_in_use() -> int:
-    """What the chips hold, by the runtime's own count (not the program's
-    ledger): ``resident_bytes_per_sample``.  Read once every panel shape has
-    been answered twice and before any concurrent traffic: what is stacked or
-    memoized only when panels arrive together (2.4 MB or not, by arrival skew)
-    would make it two-valued."""
+def bytes_in_use() -> list:
+    """What each chip holds, by the runtime's own count (not the program's
+    ledger); their sum is ``resident_bytes_per_sample``.  Read once every
+    panel shape has been answered twice and before any concurrent traffic:
+    what is stacked or memoized only when panels arrive together (2.4 MB or
+    not, by arrival skew) would make it two-valued."""
     import jax
     gc.collect()          # answers' device buffers that only wait for it
-    return sum((d.memory_stats() or {}).get("bytes_in_use", 0)
-               for d in jax.local_devices())
+    return [(d.memory_stats() or {}).get("bytes_in_use", 0)
+            for d in jax.local_devices()]
 
 
 def main(argv=None) -> int:
@@ -484,7 +494,8 @@ def main(argv=None) -> int:
     try:
         # programs that exist only under concurrency (panels of one shape
         # arriving together are stacked into one launch) compile here
-        in_use = bytes_in_use()
+        by_chip = bytes_in_use()
+        in_use = sum(by_chip)
         t0 = time.perf_counter()
         warm = run_window(ctx, live, args.seed, WARM_WINDOW_S, False)["head"]
         ctx["phases"]["concurrent_warm"] = time.perf_counter() - t0
@@ -502,11 +513,14 @@ def main(argv=None) -> int:
                              bool(args.trace))
         window_shape("window", win["head"], watch)
         after = admin_device(port)
-        served = device_dispatches(port) - served_before
+        served = {k: v - served_before[k]
+                  for k, v in device_dispatches(port).items()}
+        say(f"device dispatches in the window: {json.dumps(served)}")
         import jax
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in jax.local_devices())
-        say(f"bytes in use: {in_use} warm, {bytes_in_use()} after the window")
+        say(f"bytes in use: {in_use} warm {by_chip}, "
+            f"{sum(bytes_in_use())} after the window")
         checks = device_checks(live["native"])
     except Failed as e:
         print(f"benchmark: {e}", file=sys.stderr)
@@ -534,9 +548,9 @@ def main(argv=None) -> int:
     limits = ctx["limits"]
     compared = {k: {"value": numbers[k], "limit": limits[k]} for k in limits}
     # the device served the window: every answered request is at least one
-    # leaf dispatch that a devicestore program took
+    # dispatch that a program of the device path took
     answered = sum(1 for r in reqs if r["status"] == 200)
-    compared["device_dispatches"] = {"value": served,
+    compared["device_dispatches"] = {"value": sum(served.values()),
                                      "at_least": max(1, answered)}
 
     bench = ctx["bench"]
@@ -578,6 +592,8 @@ def main(argv=None) -> int:
             if run["trace"]["busy_s"]:
                 result["device"]["busy_s"] = run["trace"]["busy_s"]
                 result["device"]["window_s"] = run["trace"]["window_s"]
+                result["device"]["busy_s_by_device"] = \
+                    run["trace"]["busy_s_by_device"]
                 result["breakdown"] = {
                     "device_ops": run["trace"]["device_ops"],
                     "idle_gaps": run["trace"]["idle_gaps"]}

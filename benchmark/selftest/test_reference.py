@@ -119,6 +119,34 @@ def test_every_seed_gets_the_same_work_in_another_order():
     assert [r.path for r in draw(1)] == [r.path for r in a]
 
 
+def test_set_up_bursts_every_panel_also_one_without_a_draw():
+    """For ``jmh-queries``, whose panels all draw a namespace, the list is
+    what PR 26-32's generator gave (spelled out here), so set-up sends both
+    accepted cells the same requests; a panel without a ``select`` has one
+    request, and is sent as that many copies of it."""
+    t = traffic.load(BENCH / "traffic" / "jmh-queries.json")
+    full, sizes, seed = dict(SPEC, namespaces=800), (8, 4, 2), 2 ** 31 + 9
+    ns_map = traffic.namespace_map(t, seed, full)
+    before = [[traffic.request_for(p, pi, ns_map[i * spread % len(ns_map)],
+                                   full, "prom", 30, True).path
+               for i in range(k)]
+              for pi, p in enumerate(t["panels"]) if p.get("select")
+              for k in sizes for spread in (1, 0)]
+    now = traffic.burst_requests(t, seed, full, "prom", 30, True, sizes)
+    assert [[r.path for r in burst] for burst in now] == before
+    assert len(before) == 4 * 3 * 2
+
+    wide = dict(t, panels=[{k: v for k, v in t["panels"][1].items()
+                            if k != "select"}])
+    bursts = traffic.burst_requests(wide, seed, full, "prom", 30, False,
+                                    sizes)
+    assert [len(b) for b in bursts] == [8, 4, 2]
+    only = traffic.warm_requests(wide, [], seed, full, "prom", 30,
+                                 False)[0][1]
+    assert {r.path for b in bursts for r in b} == {only.path}
+    assert {r.key for b in bursts for r in b} == {"0:-1"}
+
+
 def test_every_seed_loads_the_same_series_dealt_anew():
     a, b = Population(SPEC, 1), Population(SPEC, 2 ** 31 + 9)
     assert (a.vals != b.vals).any()

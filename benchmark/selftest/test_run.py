@@ -60,6 +60,7 @@ def test_a_broken_timed_path_comes_out_as_not_correct(fault):
     if fault == "served_by_the_host":
         # the answers are right; only the count of device dispatches fails
         assert failing == {"device_dispatches"}, out["compared"]
+        assert out["compared"]["device_dispatches"]["value"] == 0
     else:
         assert failing - {"device_dispatches"}, out["compared"]
 
@@ -83,6 +84,63 @@ def copy(tmp_path):
     return tmp_path
 
 
+def with_program(copy):
+    (copy / "filodb_tpu").symlink_to(ROOT / "filodb_tpu")
+    return copy / "benchmark", json.loads((copy / "BENCHMARK.json")
+                                          .read_text())
+
+
+def sum_panel(name: str, fn: str, steps: int, step_ms: int, **more) -> dict:
+    inner = "{metric}" + ("{{_ns_=\"{namespace}\"}}" if "select" in more
+                          else "")
+    return dict({"name": name, "weight": 1, "steps": steps,
+                 "step_ms": step_ms, "end": "newest",
+                 "query": f"sum({fn}({inner}[5m]))",
+                 "limits": {"rel_err": 1e-9},
+                 "reference": {"fn": fn, "window_ms": 300000,
+                               "aggregate": "sum"}}, **more)
+
+
+def dispatched_by(proc) -> dict:
+    """Who took the window's dispatches, from the harness's own line."""
+    mark = "device dispatches in the window: "
+    return json.loads(next(ln for ln in proc.stdout.splitlines()
+                           if ln.startswith(mark))[len(mark):])
+
+
+def test_four_devices_count_what_the_fabric_dispatched(copy):
+    """Every panel a ``sum`` over two or more shards of ``dev-4shard``, one
+    of them over all four with no draw: on four devices the planner roots
+    each in the mesh fabric, whose launches are ``meshgrid.*`` and no
+    ``devicestore.*`` program at all."""
+    bench, doc = with_program(copy)
+    (bench / "traffic" / "sum-panels.json").write_text(json.dumps({
+        "name": "sum-panels", "loop": "closed", "sessions": 4, "think_ms": 0,
+        "cycle": 3, "timeout_s": 30, "panels": [
+            sum_panel("wide_rate", "rate", 23, 150000),
+            sum_panel("ns_rate", "rate", 23, 150000,
+                      select={"draw": "uniform", "over": "namespaces"}),
+            sum_panel("ns_sot", "sum_over_time", 50, 30000,
+                      select={"draw": "uniform", "over": "namespaces"})]}))
+    doc["workloads"].append({"name": "dev4.sums", "config": "dev-4shard",
+                             "traffic": "sum-panels", "chips": 4,
+                             "why": "a test"})
+    (copy / "BENCHMARK.json").write_text(json.dumps(doc))
+    p = run_child([bench / "run.py", "--workload", "dev4.sums", "--seed", 7,
+                   "--seconds", 3, "--trace", 0, "--rehearse"], cwd=copy,
+                  devices=4)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    out = last_json(p)
+    assert out["correct"] is True and out["device"]["count"] == 4
+    served = out["compared"]["device_dispatches"]
+    assert served["value"] >= served["at_least"] > 8
+    # who took them: the fabric; the one family that PR 26-32's harness
+    # counted (and the stacked members) stays below the answered requests
+    by = dispatched_by(p)
+    assert by["meshgrid."] >= served["at_least"]
+    assert by["devicestore."] + by["members"] < served["at_least"]
+
+
 def test_only_the_benchmark_in_the_directory_prints_no_result(copy):
     p = run_child([copy / "benchmark" / "run.py", "--workload", CELLS[0],
                    "--seed", 1, "--seconds", 1, "--trace", 0, "--rehearse"],
@@ -92,9 +150,10 @@ def test_only_the_benchmark_in_the_directory_prints_no_result(copy):
 
 
 def test_a_later_pr_adds_files_and_entries_and_edits_nothing(copy):
-    (copy / "filodb_tpu").symlink_to(ROOT / "filodb_tpu")
-    bench = copy / "benchmark"
-    doc = json.loads((copy / "BENCHMARK.json").read_text())
+    """Also a cell on four chips whose panels are all aggregates, two of
+    them with no draw: the fabric serves it (four virtual devices here), and
+    nothing the benchmark has is edited for it."""
+    bench, doc = with_program(copy)
     # a configuration: two shards of the same population
     conf = json.loads((bench / "configs" / "jmh-inmem-1shard.json")
                       .read_text())
@@ -125,7 +184,7 @@ def test_a_later_pr_adds_files_and_entries_and_edits_nothing(copy):
              "reference": {"fn": "rate", "window_ms": 300000,
                            "aggregate": "sum"}}]}))
     doc["workloads"].append({"name": "two.avg", "config": "two-shards",
-                             "traffic": "avg-panels", "chips": 1,
+                             "traffic": "avg-panels", "chips": 4,
                              "why": "a test"})
     # a per-layer metric with a reader of its own, in this cell alone
     (bench / "readers" / "slowest.py").write_text(
@@ -140,10 +199,12 @@ def test_a_later_pr_adds_files_and_entries_and_edits_nothing(copy):
                              "workloads": ["two.avg"]})
     (copy / "BENCHMARK.json").write_text(json.dumps(doc))
     p = run_child([bench / "run.py", "--workload", "two.avg", "--seed", 5,
-                   "--seconds", 3, "--trace", 1, "--rehearse"], cwd=copy)
+                   "--seconds", 3, "--trace", 1, "--rehearse"], cwd=copy,
+                  devices=4)
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
     out = last_json(p)
     assert out["correct"] is True and "slowest_ms" in out["metric_names"]
+    assert out["device"]["count"] == 4 and dispatched_by(p)["meshgrid."] > 0
     # and the old cells do not report the new cell's metric
     p = run_child([bench / "run.py", "--workload", CELLS[0], "--seed", 5,
                    "--seconds", 2, "--trace", 1, "--rehearse"], cwd=copy)
