@@ -1005,20 +1005,25 @@ class DeviceGridCache:
             if plan is None or not plan.segs:
                 return None
             _note_hbm(plan)
-            # phase mode never stages the ts plane: the SPMD program's
-            # phase kernels reconstruct the geometry from the phase row
-            phase_mode = plan.phase is not None
-            key = (plan.row0, plan.nrows, phase_mode)
+            # no ts plane is staged where the SPMD program streams none:
+            # the phase kernels reconstruct the geometry from the phase
+            # row, the ts-free ops (sum, last, ...) never read it — so
+            # one dashboard's rate, sum_over_time and instant panels
+            # over one range share ONE staged value plane
+            no_ts = plan.phase is not None or op in TS_FREE_OPS
+            key = (plan.row0, plan.nrows, no_ts)
             parts_id = tuple(id(b) for b in plan.segs)
             memo = self._mesh_stage_memo.get(key)
             if memo is not None and memo[0] == parts_id:
                 _, ts_st, val_st, segs_ref = memo
             else:
-                ts_st, val_st = _mesh_stage(
-                    None if phase_mode
-                    else tuple(b.ts_seg for b in plan.segs),
-                    tuple(b.vals for b in plan.segs),
-                    plan.row0, nrows=plan.nrows)
+                with TRACER.stage("mesh.stage", rows=plan.nrows,
+                                  lanes=plan.ncols):
+                    ts_st, val_st = _mesh_stage(
+                        None if no_ts
+                        else tuple(b.ts_seg for b in plan.segs),
+                        tuple(b.vals for b in plan.segs),
+                        plan.row0, nrows=plan.nrows)
                 # the staged planes are HBM residents held by the memo:
                 # they belong on the ledger like any committed block
                 LEDGER.track(ts_st, owner=self.owner, fmt="mesh-staged")

@@ -31,9 +31,16 @@ from filodb_tpu.core.filters import ColumnFilter
 from filodb_tpu.ops.windows import StepRange
 from filodb_tpu.query import rangefns
 from filodb_tpu.query.aggregators import AggPartialBatch, grouping_key
-from filodb_tpu.query.exec import ExecContext, ExecPlan
+from filodb_tpu.query.exec import ExecContext, ExecPlan, leaf_scan
 from filodb_tpu.query.logical import (AggregationOperator, RangeFunctionId)
 from filodb_tpu.query.model import QueryContext
+from filodb_tpu.utils.observability import TRACER
+
+# the steps of one fabric request (doc/observability.md "Stage spans"):
+# a request the fabric served carries all five, 0.0 for a step its rung
+# skipped; ``mesh.stage`` and ``mesh.assemble`` only where a memo missed
+MESH_STAGES = ("mesh.collect", "mesh.dispatch", "mesh.device_wait",
+               "mesh.readback", "mesh.present")
 
 # aggregates with a distributive psum/pmin/pmax form (mesh.partial_state_names)
 MESH_OPS = (AggregationOperator.SUM, AggregationOperator.COUNT,
@@ -41,8 +48,9 @@ MESH_OPS = (AggregationOperator.SUM, AggregationOperator.COUNT,
             AggregationOperator.MAX, AggregationOperator.STDDEV,
             AggregationOperator.STDVAR, AggregationOperator.GROUP)
 # aggregates with a non-psum mesh partial: k-heap merge (topk/bottomk),
-# t-digest merge (quantile), member pass-through (count_values) — the
-# full RowAggregator family (reference RowAggregator.scala:114-141)
+# member pass-through (count_values; quantile up to ``exact_members`` a
+# group, a t-digest merge past it) — the full RowAggregator family
+# (reference RowAggregator.scala:114-141)
 _K_OPS = (AggregationOperator.TOPK, AggregationOperator.BOTTOMK)
 _MEMBER_OPS = (AggregationOperator.QUANTILE,
                AggregationOperator.COUNT_VALUES)
@@ -184,6 +192,15 @@ class MeshAggregateExec(ExecPlan):
                 f"{self.function.name if self.function else None}")
 
     def do_execute(self, ctx: ExecContext) -> list:
+        # the mesh node is its plan's one data leaf, all local shards at
+        # once: it owns the "scan" stage as a per-shard leaf does, and
+        # the fabric's own stage spans (``mesh.*``) that end inside it
+        # land in this query's timings under their names
+        with leaf_scan(ctx):
+            ctx.ensure_timings(MESH_STAGES)
+            return self._serve(ctx)
+
+    def _serve(self, ctx: ExecContext) -> list:
         from filodb_tpu.parallel import mesh as meshmod
         from filodb_tpu.parallel import meshgrid
 
@@ -211,34 +228,36 @@ class MeshAggregateExec(ExecPlan):
 
         grid_eligible = self.operator in meshgrid.GRID_MESH_ALL_OPS
         place = mesh_placement(self.planned_generation or 0, len(devices))
-        entries = []                       # (shard, shard_num, lookup)
-        for shard_num in self.shards:
-            shard = ctx.memstore.get_shard(self.dataset, shard_num)
-            if grid_eligible:
-                # mesh placement BEFORE any grid staging: blocks build
-                # on the device the SPMD program reads them from.  Only
-                # grid-capable queries pin — a host-path query must not
-                # invalidate resident state it will never use.
-                shard.pin_grid_device(devices[place(shard_num)])
-            lookup = shard.lookup_partitions(self.filters,
-                                             self.scan_start_ms,
-                                             self.scan_end_ms)
-            if len(lookup.part_ids) == 0:
-                continue
-            entries.append((shard, shard_num, lookup))
-
-        # -- phase 1: the HBM-resident grid x mesh path (VERDICT r3 #1):
-        # every shard that can stage its scan in place — scalar AND
-        # first-class histogram columns — contributes a MeshShardPlan;
-        # ONE shard_map program serves them all with zero per-query
-        # host->device upload.  Shards that can't (irregular layouts,
-        # cold data, mixed bucket schemes) fall back per-shard to the
-        # host-batch mesh below.
         limit = ctx.query_context.group_by_cardinality_limit
-        host_entries = entries
-        if grid_eligible:
-            plans, planned = [], []
-            for ent in entries:
+        entries = []                       # (shard, shard_num, lookup)
+        plans, planned = [], []
+        with TRACER.stage("mesh.collect", shards=len(self.shards)) as sp:
+            for shard_num in self.shards:
+                shard = ctx.memstore.get_shard(self.dataset, shard_num)
+                if grid_eligible:
+                    # mesh placement BEFORE any grid staging: blocks
+                    # build on the device the SPMD program reads them
+                    # from.  Only grid-capable queries pin — a host-path
+                    # query must not invalidate resident state it will
+                    # never use.
+                    shard.pin_grid_device(devices[place(shard_num)])
+                lookup = shard.lookup_partitions(self.filters,
+                                                 self.scan_start_ms,
+                                                 self.scan_end_ms)
+                if len(lookup.part_ids) == 0:
+                    continue
+                entries.append((shard, shard_num, lookup))
+            sp.tag(lanes_requested=sum(len(e[2].part_ids)
+                                       for e in entries))
+
+            # -- phase 1: the HBM-resident grid x mesh path (VERDICT r3
+            # #1): every shard that can stage its scan in place — scalar
+            # AND first-class histogram columns — contributes a
+            # MeshShardPlan; ONE shard_map program serves them all with
+            # zero per-query host->device upload.  Shards that can't
+            # (irregular layouts, cold data, mixed bucket schemes) fall
+            # back per-shard to the host-batch mesh below.
+            for ent in entries if grid_eligible else ():
                 shard, _num, lookup = ent
                 gids = self._grid_group_ids(shard, lookup.part_ids, union)
                 if len(union) > limit:
@@ -254,6 +273,8 @@ class MeshAggregateExec(ExecPlan):
                 if plan is not None:
                     plans.append(plan)
                     planned.append(ent)
+        host_entries = entries
+        if grid_eligible:
             if plans:
                 num_grid_groups = len(union)
                 state = meshgrid.serve_grid_mesh(engine, plans,
@@ -339,13 +360,10 @@ class MeshAggregateExec(ExecPlan):
                     engine, shard_batches, group_ids, tags_lists, keys,
                     steps, report, window))
             elif self.operator is Agg.QUANTILE:
-                m, w = engine.window_quantile_partials(
-                    shard_batches, group_ids, G, steps, window,
-                    range_fn=self.function,
-                    extra_args=self.function_args)
                 out.append(AggPartialBatch(
                     self.operator, self.params, keys, report,
-                    {"td_means": m, "td_weights": w}))
+                    self._quantile_state(engine, shard_batches, group_ids,
+                                         tags_lists, G, steps, window)))
             elif self.operator is Agg.COUNT_VALUES:
                 out.append(self._count_values_partial(
                     engine, shard_batches, group_ids, tags_lists, keys,
@@ -379,6 +397,26 @@ class MeshAggregateExec(ExecPlan):
                                {"values": v, "sidx": si},
                                series_keys=series_keys)
 
+    @staticmethod
+    def _member_ids(group_ids, tags_lists) -> np.ndarray:
+        """[series] group ids of the host-fed shards' real series."""
+        return np.concatenate(
+            [gid[:len(tl)] for tl, gid in zip(tags_lists, group_ids)]) \
+            if tags_lists else np.empty(0, np.int64)
+
+    def _member_values(self, engine, shard_batches, tags_lists, steps,
+                       window) -> np.ndarray:
+        """scan+window on the host-fed mesh, every real series' stepped
+        values read back [series, T], in ``_member_ids``' order."""
+        stepped, (Kp, S) = engine.window_values(
+            shard_batches, steps, window, range_fn=self.function,
+            extra_args=self.function_args)
+        rows = np.concatenate(
+            [np.arange(len(tl), dtype=np.int64) + kk * S
+             for kk, tl in enumerate(tags_lists)]) \
+            if tags_lists else np.empty(0, np.int64)
+        return stepped[rows]
+
     def _count_values_partial(self, engine, shard_batches, group_ids,
                               tags_lists, keys, steps, report,
                               window) -> AggPartialBatch:
@@ -387,19 +425,32 @@ class MeshAggregateExec(ExecPlan):
         through like the reference's CountValuesRowAggregator, without
         a per-series loop or a dense member cube."""
         from filodb_tpu.query.aggregators import count_values_state
-        stepped, (Kp, S) = engine.window_values(
-            shard_batches, steps, window, range_fn=self.function,
-            extra_args=self.function_args)
-        rows = np.concatenate(
-            [np.arange(len(tl), dtype=np.int64) + kk * S
-             for kk, tl in enumerate(tags_lists)]) \
-            if tags_lists else np.empty(0, np.int64)
-        ids = np.concatenate(
-            [gid[:len(tl)] for tl, gid in zip(tags_lists, group_ids)]) \
-            if tags_lists else np.empty(0, np.int64)
-        state = count_values_state(stepped[rows], ids, max(len(keys), 1))
+        state = count_values_state(
+            self._member_values(engine, shard_batches, tags_lists, steps,
+                                window),
+            self._member_ids(group_ids, tags_lists), max(len(keys), 1))
         return AggPartialBatch(self.operator, self.params, keys, report,
                                state)
+
+    def _quantile_state(self, engine, shard_batches, group_ids, tags_lists,
+                        G: int, steps, window) -> dict:
+        """quantile on the host-fed mesh: the members themselves while
+        the largest group has at most ``exact_members`` of them over the
+        shards here (the per-shard rung's rule, the resident fabric's
+        too: meshgrid._exact_width), the merged t-digests past it."""
+        from filodb_tpu.query.aggregators import (QuantileAggregator,
+                                                  members_state)
+        ids = self._member_ids(group_ids, tags_lists)
+        if np.bincount(ids).max(initial=0) \
+                <= QuantileAggregator.exact_members:
+            return members_state(
+                self._member_values(engine, shard_batches, tags_lists,
+                                    steps, window), ids, G)
+        m, w = engine.window_quantile_partials(
+            shard_batches, group_ids, G, steps, window,
+            range_fn=self.function, extra_args=self.function_args,
+            compression=QuantileAggregator.compression)
+        return {"td_means": m, "td_weights": w}
 
     def _resolve_k_lanes(self, state: dict, plans, planned) -> list[dict]:
         """Map the resident k-slot program's GLOBAL lane indices back to
@@ -517,27 +568,31 @@ class MeshAggregateExec(ExecPlan):
         limit = ctx.query_context.group_by_cardinality_limit
         union: dict[tuple, int] = {}
         plans = []
-        for shard_num in self.shards:
-            shard = ctx.memstore.get_shard(self.dataset, shard_num)
-            shard.pin_grid_device(devices[place(shard_num)])
-            lookup = shard.lookup_partitions(self.filters,
-                                             self.scan_start_ms,
-                                             self.scan_end_ms)
-            if len(lookup.part_ids) == 0:
-                continue
-            gids = self._grid_group_ids(shard, lookup.part_ids, union)
-            if len(union) > limit:
-                self._cardinality_error(ctx, len(union))
-            plan = None
-            if gids is not None:
-                plan = shard.mesh_grid_plan(
-                    lookup.part_ids, self.function, steps.start,
-                    steps.num_steps, steps.step, window, gids,
-                    fargs=self.function_args)
-            if plan is None:
-                meshgrid._fallback("shape")
-                return None
-            plans.append(plan)
+        with TRACER.stage("mesh.collect", shards=len(self.shards)) as sp:
+            lanes = 0
+            for shard_num in self.shards:
+                shard = ctx.memstore.get_shard(self.dataset, shard_num)
+                shard.pin_grid_device(devices[place(shard_num)])
+                lookup = shard.lookup_partitions(self.filters,
+                                                 self.scan_start_ms,
+                                                 self.scan_end_ms)
+                if len(lookup.part_ids) == 0:
+                    continue
+                lanes += len(lookup.part_ids)
+                gids = self._grid_group_ids(shard, lookup.part_ids, union)
+                if len(union) > limit:
+                    self._cardinality_error(ctx, len(union))
+                plan = None
+                if gids is not None:
+                    plan = shard.mesh_grid_plan(
+                        lookup.part_ids, self.function, steps.start,
+                        steps.num_steps, steps.step, window, gids,
+                        fargs=self.function_args)
+                if plan is None:
+                    meshgrid._fallback("shape")
+                    return None
+                plans.append(plan)
+            sp.tag(lanes_requested=lanes)
         return engine, plans, union, report
 
 
@@ -567,21 +622,28 @@ class MeshReduceExec(MeshAggregateExec):
         phi = f", phi={self.hist_phi}" if self.hist_phi is not None else ""
         return super()._args_str() + phi
 
-    def do_execute(self, ctx: ExecContext) -> list:
+    def _serve(self, ctx: ExecContext) -> list:
+        rung, batches = self._rung(ctx)
+        # what is left for the host once a rung has answered: nothing on
+        # the fused one (the program presented), reduce + present of the
+        # partials on the other two
+        with TRACER.stage("mesh.present", rung=rung):
+            return batches if rung == "fused" \
+                else self._present_host(batches)
+
+    def _rung(self, ctx: ExecContext) -> tuple:
+        """(the rung of the ladder that served, its batches: presented
+        on ``fused``, partials on ``partial`` and ``per_shard``)."""
         from filodb_tpu.parallel import meshgrid
         from filodb_tpu.utils.devicewatch import FLIGHT
 
-        stale = self._topology_stale()
-        if stale is not None:
-            meshgrid._fallback(stale)
+        reason = self._topology_stale() \
+            or ("breaker" if FABRIC_BREAKER["open"] else None)
+        if reason is not None:
+            meshgrid._fallback(reason)
             FLIGHT.record("mesh.fallback", dataset=self.dataset,
-                          reason=stale, shards=len(self.shards))
-            return self._present_host(self._per_shard_fallback(ctx))
-        if FABRIC_BREAKER["open"]:
-            meshgrid._fallback("breaker")
-            FLIGHT.record("mesh.fallback", dataset=self.dataset,
-                          reason="breaker", shards=len(self.shards))
-            return self._present_host(self._per_shard_fallback(ctx))
+                          reason=reason, shards=len(self.shards))
+            return "per_shard", self._per_shard_fallback(ctx)
         if self.operator in meshgrid._PRESENT_AGGS and not self.params:
             try:
                 fused = self._fused(ctx)
@@ -590,13 +652,13 @@ class MeshReduceExec(MeshAggregateExec):
                 # correctness dependency: trip the breaker and serve
                 # this (and every later) query scatter-gather
                 trip_fabric_breaker(e)
-                return self._present_host(self._per_shard_fallback(ctx))
+                return "per_shard", self._per_shard_fallback(ctx)
             if fused is not None:
-                return fused
+                return "fused", fused
         # partial-tier rung: the mesh partial program(s) + host
         # reduce/present — exactly what ReduceAggregateExec +
         # AggregatePresenter compose over a MeshAggregateExec child
-        return self._present_host(super().do_execute(ctx))
+        return "partial", super()._serve(ctx)
 
     def _fused(self, ctx: ExecContext) -> Optional[list]:
         """The single-dispatch rung; None demotes to the partial tier."""
@@ -693,7 +755,7 @@ class EventTopKExec(MeshAggregateExec):
         return (super()._args_str()
                 + f", k={self.k}, largest={self.largest}")
 
-    def do_execute(self, ctx: ExecContext) -> list:
+    def _serve(self, ctx: ExecContext) -> list:
         from filodb_tpu.parallel import meshgrid
         from filodb_tpu.utils.devicewatch import FLIGHT
 

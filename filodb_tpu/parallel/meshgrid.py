@@ -26,6 +26,7 @@ on the already-assembled residents.
 from __future__ import annotations
 
 import functools
+import threading
 from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -34,6 +35,7 @@ from filodb_tpu.ops.grid import lane_tile
 from filodb_tpu.query.logical import AggregationOperator as Agg
 from filodb_tpu.utils import devicewatch
 from filodb_tpu.utils.devicewatch import LEDGER
+from filodb_tpu.utils.observability import TRACER
 
 # aggregate ops with a fused grid-mesh form.  Round 5 (VERDICT r4 #2):
 # the WHOLE RowAggregator family now serves from resident lanes —
@@ -98,28 +100,85 @@ def _fallback(reason: str) -> None:
     STATS["fallbacks"] += 1
     _mm()["fallbacks"].inc(reason=reason)
 
-# (mesh, layout, garr) -> assembled global arrays; holds the plan arrays
-# so the id()-keys stay unambiguous while an entry lives.  LRU with BOTH
-# a count cap and a byte budget: ingest invalidations (note_freeze /
-# note_repin) retire the staged pieces, orphaning old entries' keys —
-# without the byte bound, generations of full padded dataset copies
-# would pin HBM until the count cap finally cleared them.
+# What the fabric keeps assembled between queries, in two memos under
+# one lock.
+#
+# _ASSEMBLY_MEMO: what a DEVICE holds for the SPMD programs, a piece a
+# device, keyed by what the piece is made of (mesh, device, layout, the
+# identity of the staged planes) and by nothing the query asks:
+# ("planes", ...) -> (ts, vals, s0 pieces), the padded [ksub, nrows,
+# lmax] copy of the device's shard slices (27 MB a chip at 26 368 lanes
+# x 255 rows), and ("phase", ...) -> (phase piece,).  The global arrays
+# are put together from the pieces a request (no device work: the
+# buffers are wrapped, not copied).  A device none of the query's shards
+# lives on lends whatever piece of that layout it already holds (every
+# lane of it goes to the drop bucket), so a namespace on shards {0, 1}
+# builds nothing on the chips of shards 2 and 3.  An entry holds the
+# planes it was made of, so the id()-keys stay unambiguous while it
+# lives.  LRU with BOTH a count cap (a device) and a byte budget: ingest
+# invalidations (note_freeze / note_repin) retire the staged pieces,
+# orphaning old entries' keys — without the byte bound, generations of
+# full padded dataset copies would pin HBM until the count cap finally
+# cleared them.
+#
+# _ROWS_MEMO: the query's own rows ([Kp, lmax] int32, 105 KB a chip):
+# which group each lane reduces into, which lanes an exact quantile
+# gathers.  Keyed by the lanes asked, so 800 namespaces miss here and
+# never in the planes: what a chip holds does not follow the namespaces
+# asked (PERF.md section 6, PR 34: the planes were keyed by the lanes
+# and the shards asked, a padded copy of the dataset a namespace, 215 MB
+# a chip).
 from collections import OrderedDict
 
 _ASSEMBLY_MEMO: "OrderedDict[tuple, tuple]" = OrderedDict()
-_ASSEMBLY_MEMO_CAP = 8
+_ASSEMBLY_MEMO_CAP = 8                # entries a device
 _ASSEMBLY_MEMO_BYTES = 1 << 31        # 2 GiB of assembled residents
+_ROWS_MEMO: "OrderedDict[tuple, tuple]" = OrderedDict()
+_ROWS_MEMO_CAP = 16
+_MEMO_LOCK = threading.Lock()
 
 
-def _memo_insert(key, value, nbytes: int) -> None:
-    _ASSEMBLY_MEMO[key] = (*value, nbytes)
-    total = sum(v[-1] for v in _ASSEMBLY_MEMO.values())
-    while _ASSEMBLY_MEMO and (len(_ASSEMBLY_MEMO) > _ASSEMBLY_MEMO_CAP
-                              or total > _ASSEMBLY_MEMO_BYTES):
-        if len(_ASSEMBLY_MEMO) == 1:
-            break                      # never evict the entry just added
-        _k, v = _ASSEMBLY_MEMO.popitem(last=False)
-        total -= v[-1]
+def _memo_get(memo: OrderedDict, key, like=None):
+    """The entry under ``key``; failing that, where ``like`` is given,
+    the newest entry whose key starts with it."""
+    with _MEMO_LOCK:
+        hit = memo.get(key)
+        if hit is None and like is not None:
+            key = next((k for k in reversed(memo)
+                        if k[:len(like)] == like), None)
+            hit = memo.get(key)
+        if hit is not None:
+            memo.move_to_end(key)
+    return hit
+
+
+def _memo_insert(memo: OrderedDict, key, value: tuple, nbytes: int,
+                 cap: int, budget: Optional[int] = None) -> None:
+    with _MEMO_LOCK:
+        memo[key] = (*value, nbytes)
+        total = sum(v[-1] for v in memo.values())
+        while len(memo) > 1 and (len(memo) > cap or (
+                budget is not None and total > budget)):
+            _k, v = memo.popitem(last=False)   # never the one just added
+            total -= v[-1]
+
+
+def assembled_bytes() -> int:
+    """Bytes the assembly memo holds (all devices)."""
+    with _MEMO_LOCK:
+        return sum(v[-1] for v in _ASSEMBLY_MEMO.values())
+
+
+def _staged(prog):
+    """``prog`` as the ``mesh.dispatch`` stage: the jit call until it
+    returns (operand handling, the enqueue on every device)."""
+    name = getattr(prog, "_program", "")
+
+    @functools.wraps(prog)           # keeps _program and _jitted
+    def launch(*operands):
+        with TRACER.stage("mesh.dispatch", program=name):
+            return prog(*operands)
+    return launch
 
 
 def _jax():
@@ -210,7 +269,7 @@ def _grid_mesh_program(mesh_key, q, mode: str, ksub: int, nrows: int,
     from filodb_tpu.parallel.mesh import _MESHES
     fn, _ = _grouped_inner(_MESHES[mesh_key], q, mode, ksub, nrows, lmax,
                            num_groups, op)
-    return devicewatch.jit(fn, program="meshgrid.grouped")
+    return _staged(devicewatch.jit(fn, program="meshgrid.grouped"))
 
 
 # AggregationOperator -> the fused present epilogue it rides; mirrors
@@ -256,7 +315,7 @@ def _grid_mesh_present_program(mesh_key, q, mode: str, ksub: int,
             var = jnp.sqrt(var)
         return jnp.where(n > 0, var, jnp.nan)
 
-    return devicewatch.jit(fn, program="meshgrid.fused")
+    return _staged(devicewatch.jit(fn, program="meshgrid.fused"))
 
 
 @functools.lru_cache(maxsize=64)
@@ -285,7 +344,7 @@ def _grid_mesh_histq_program(mesh_key, q, mode: str, ksub: int,
         hist = jnp.where(n[..., None] > 0, hist, jnp.nan)
         return hist_quantile(tops, hist, phi)       # [G, T]
 
-    return devicewatch.jit(fn, program="meshgrid.fused_histq")
+    return _staged(devicewatch.jit(fn, program="meshgrid.fused_histq"))
 
 
 @functools.lru_cache(maxsize=64)
@@ -315,7 +374,7 @@ def _grid_mesh_event_topk_program(mesh_key, q, mode: str, ksub: int,
         return (jnp.where(found, topv * sign, jnp.nan),
                 jnp.where(found, topg, -1))
 
-    return devicewatch.jit(fn, program="meshgrid.event_topk")
+    return _staged(devicewatch.jit(fn, program="meshgrid.event_topk"))
 
 
 def _shard_map_unchecked(local, **kw):
@@ -398,7 +457,7 @@ def _grid_mesh_topk_program(mesh_key, q, mode: str, ksub: int, nrows: int,
     fn = _shard_map_unchecked(local, mesh=mesh, in_specs=in_specs,
                               out_specs=(P(None, None, None),
                                          P(None, None, None)))
-    return devicewatch.jit(fn, program="meshgrid.topk")
+    return _staged(devicewatch.jit(fn, program="meshgrid.topk"))
 
 
 @functools.lru_cache(maxsize=64)
@@ -445,7 +504,7 @@ def _grid_mesh_quantile_program(mesh_key, q, mode: str, ksub: int,
     fn = _shard_map_unchecked(local, mesh=mesh, in_specs=in_specs,
                               out_specs=(P(None, None, None),
                                          P(None, None, None)))
-    return devicewatch.jit(fn, program="meshgrid.quantile")
+    return _staged(devicewatch.jit(fn, program="meshgrid.quantile"))
 
 
 @functools.lru_cache(maxsize=64)
@@ -475,7 +534,47 @@ def _grid_mesh_values_program(mesh_key, q, mode: str, ksub: int,
                 P(_AXES, None), P(_AXES))
     fn = _shard_map_unchecked(local, mesh=mesh, in_specs=in_specs,
                               out_specs=P(_AXES, None, None))
-    return devicewatch.jit(fn, program="meshgrid.values")
+    return _staged(devicewatch.jit(fn, program="meshgrid.values"))
+
+
+@functools.lru_cache(maxsize=64)
+def _grid_mesh_members_program(mesh_key, q, mode: str, ksub: int,
+                               nrows: int, lmax: int, width: int):
+    """The exact quantile's leaf over resident lanes: scan+window as
+    ``meshgrid.values``, then every slice keeps the ``width`` lanes its
+    ``sel`` row names (-1 beyond the members: NaN) and ONE all_gather
+    over the mesh hands every device all of them, so the readback is
+    [slices, T, width] from one device and never every lane.  Served
+    while the largest group has at most
+    ``QuantileAggregator.exact_members`` members over all shards; the
+    quantile itself is the host's one sort in f64
+    (``QuantileAggregator.present``), the arithmetic the per-shard rung
+    answers with."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from filodb_tpu.ops.grid import rate_grid_auto
+    from filodb_tpu.parallel.mesh import _MESHES
+    mesh = _MESHES[mesh_key]
+    lanes = lane_tile(lmax, nrows)
+
+    def local(ts, vals, phase, s0, sel):
+        outs = []
+        for kk in range(ksub):
+            stepped = rate_grid_auto(
+                ts[kk] if mode == "ts" else None, vals[kk], s0[kk], q,
+                lanes, phase=phase[kk] if mode == "phase" else None)
+            idx = sel[kk]                              # [width]
+            picked = jnp.take(stepped, jnp.maximum(idx, 0), axis=1)
+            outs.append(jnp.where(idx[None, :] >= 0, picked, jnp.nan))
+        allm = _mesh_gather(jnp.stack(outs), mesh)     # [ndev, ksub, T, w]
+        return allm.reshape((-1,) + allm.shape[2:])    # [Kp, T, width]
+
+    in_specs = (P(_AXES, None, None), P(_AXES, None, None),
+                P(_AXES, None), P(_AXES), P(_AXES, None))
+    fn = _shard_map_unchecked(local, mesh=mesh, in_specs=in_specs,
+                              out_specs=P(None, None, None))
+    return _staged(devicewatch.jit(fn, program="meshgrid.members"))
 
 
 def _pad_piece(arr, lmax: int, fill):
@@ -506,8 +605,8 @@ def _garr_fp(garr: np.ndarray) -> int:
 def _compose(plans: Sequence, operator: Agg):
     """Validate that the per-shard plans run under ONE program signature.
     Returns (q, mode) or None to fall back."""
-    from filodb_tpu.ops.grid import (DENSE_ONLY_OPS, max_k_for,
-                                     phase_eligible)
+    from filodb_tpu.ops.grid import (DENSE_ONLY_OPS, TS_FREE_OPS,
+                                     max_k_for, phase_eligible)
     op = GRID_MESH_ALL_OPS.get(operator)
     if op is None or not plans:
         return None
@@ -533,9 +632,14 @@ def _compose(plans: Sequence, operator: Agg):
         # meet downgrade must re-check the non-dense bound
         return None
     q = q0._replace(dense=dense)
-    mode = "phase" if (phase_eligible(q)
-                       and all(p.phase is not None for p in plans)) \
-        else "ts"
+    if q.op in TS_FREE_OPS:
+        # the kernel never streams a ts plane for these (sum, last, ...):
+        # none is staged (devicestore.mesh_plan) and none assembled
+        mode = "free"
+    elif phase_eligible(q) and all(p.phase is not None for p in plans):
+        mode = "phase"
+    else:
+        mode = "ts"
     if mode == "ts" and any(p.ts is None for p in plans):
         # a uniform-phase shard staged NO ts plane (ISSUE 3); if the
         # composition meets down to ts mode it cannot serve — fall back
@@ -573,7 +677,7 @@ class _Prepared(NamedTuple):
     per-op programs need, independent of WHICH program then dispatches
     (partial planes, fused present, fused quantile, event topk)."""
     q: object
-    mode: str
+    mode: str              # "ts", "phase" or "free" (no ts plane at all)
     op: str
     stride: int            # hb bucket lanes per series slot (1 = scalar)
     groups_total: int      # num_groups * stride segments in the reduce
@@ -582,7 +686,78 @@ class _Prepared(NamedTuple):
     lmax: int
     Kp: int
     by_dev: list
-    arrays: tuple          # (g_ts, g_vals, g_ph, g_s0, g_garr)
+    multiproc: bool
+    planes: tuple          # (g_ts, g_vals, g_ph, g_s0): what is resident
+    rows: object           # builds a query's [Kp, lmax] int32 row
+
+    @property
+    def arrays(self) -> tuple:
+        """(g_ts, g_vals, g_ph, g_s0, g_garr): the grouped programs'
+        operands, the query's group row last."""
+        return (*self.planes, self.rows(
+            ("garr", self.groups_total),
+            _garr_row(self.groups_total, self.lmax)))
+
+
+def _garr_row(groups_total: int, lmax: int):
+    """A shard slice's lane -> group row: -1 marks unrequested lanes
+    (devicestore.mesh_plan); rewritten to THIS query's drop bucket,
+    where the filler slices' and the pad's lanes go too."""
+    def row(garr: np.ndarray) -> np.ndarray:
+        g = np.full(lmax, groups_total, np.int32)
+        g[:len(garr)] = np.where(garr < 0, groups_total, garr)
+        return g
+    return row
+
+
+def _sel_row(width: int):
+    """A shard slice's member row for the exact quantile: the lanes the
+    query selected, in lane order, -1 beyond them."""
+    def row(garr: np.ndarray) -> np.ndarray:
+        lanes = np.flatnonzero(garr >= 0)
+        sel = np.full(width, -1, np.int32)
+        sel[:len(lanes)] = lanes
+        return sel
+    return row
+
+
+def _pieces(kind: tuple, mine: list, by_dev: list, made_of, build) -> tuple:
+    """(a device's piece of the assembly memo for each of ``mine``
+    [(index, device)], whether any had to be built): the key is ``kind``
+    (mesh, layout), the device and ``made_of(plan)`` of the plans
+    resident there.  A device with no plan of this query lends any piece
+    of that kind it holds.  On a miss (the ``mesh.assemble`` stage)
+    ``build(dev, plans)`` gives the device-local arrays and what they
+    must keep alive."""
+    out, missed = [], []
+    for d, dev in mine:
+        lst = by_dev[d]
+        key = (*kind, d, tuple(made_of(p) for p in lst))
+        hit = _memo_get(_ASSEMBLY_MEMO, key,
+                        like=None if lst else (*kind, d))
+        out.append(hit[0] if hit is not None else None)
+        if hit is None:
+            missed.append((len(out) - 1, key, dev, lst))
+    if not missed:
+        return out, False
+    with TRACER.stage("mesh.assemble", devices=len(missed)) as sp:
+        total = 0
+        for at, key, dev, lst in missed:
+            arrays, holds = build(dev, lst)
+            nbytes = sum(int(a.nbytes) for a in arrays)
+            total += nbytes
+            # the memoized pieces are what actually pins HBM between
+            # queries — ledger them (what they were stacked and padded
+            # from is transient, or the shard's own staged plane)
+            for a in arrays:
+                LEDGER.track(a, owner="meshgrid:assembly",
+                             fmt="mesh-staged")
+            _memo_insert(_ASSEMBLY_MEMO, key, (arrays, holds), nbytes,
+                         _ASSEMBLY_MEMO_CAP * len(mine),
+                         _ASSEMBLY_MEMO_BYTES)
+            out[at] = arrays
+        sp.tag(bytes=total)
+    return out, True
 
 
 def _prepare(engine, plans: Sequence, num_groups: int,
@@ -602,10 +777,10 @@ def _prepare(engine, plans: Sequence, num_groups: int,
     q, mode = composed
     op = GRID_MESH_ALL_OPS[operator]
     nrows = plans[0].vals.shape[0]
-    # phase mode serves WITHOUT a ts plane (uniform-phase shards never
-    # stage one): the program's ts input collapses to a 1-row dummy, so
-    # assembly ships half the resident bytes of the ts-streaming form
-    ts_rows = 1 if mode == "phase" else nrows
+    # only ts mode streams a ts plane: uniform-phase shards and the
+    # ts-free ops never stage one, the program's ts input collapses to
+    # a 1-row dummy, so assembly ships half the resident bytes
+    ts_rows = nrows if mode == "ts" else 1
     # histogram plans: hb bucket lanes per series slot; group slots are
     # gid*hb + bucket, so the program reduces num_groups*hb segments
     stride = plans[0].hb or 1
@@ -658,97 +833,132 @@ def _prepare(engine, plans: Sequence, num_groups: int,
                 f"(ksub/lmax/groups/nrows per process: {allv.tolist()}) "
                 "— shard layouts must be symmetric across processes")
 
-    # op-INDEPENDENT key: the assembled residents serve every aggregator
-    # family, so a dashboard switching sum -> topk re-uses the assembly
-    memo_key = (engine._key, q, mode, groups_total, nrows, lmax, ksub,
-                tuple((d, id(p.ts), id(p.vals),
-                       id(p.phase) if p.phase is not None else 0,
-                       p.steps0_rel, _garr_fp(p.garr))
-                      for d, lst in enumerate(by_dev) for p in lst))
-    memo = _ASSEMBLY_MEMO.get(memo_key)
-    if memo is not None:
-        STATS["memo_hits"] += 1
-        _ASSEMBLY_MEMO.move_to_end(memo_key)
-        g_ts, g_vals, g_ph, g_s0, g_garr = memo[:5]
-    else:
-        STATS["assembles"] += 1
+    # that process stages its own pieces
+    mine = [(d, dev) for d, dev in enumerate(devices)
+            if not multiproc or dev.process_index == proc]
+    slots = [(d, p) for d, _dev in mine for p in by_dev[d]]
+
+    def assemble(pieces):
+        """The global array of a piece a device (wrapped, not copied)."""
+        return jax.make_array_from_single_device_arrays(
+            (Kp, *pieces[0].shape[1:]),
+            NamedSharding(mesh, P(_AXES, *([None] * (pieces[0].ndim - 1)))),
+            pieces)
+
+    def build_planes(dev, lst):
+        # device-side stack and pad only; device_put of an already-
+        # resident array is a no-op.  The filler shard slices (a device
+        # with fewer than ksub shards of this query, or none and no
+        # piece to lend) are NaN planes nothing selects from
         vdt = plans[0].vals.dtype
-        # per-device local pieces, assembled in place (device-side pads
-        # only; device_put of an already-resident array is a no-op)
-        ts_pieces, val_pieces, ph_pieces, s0_pieces, g_pieces = \
-            [], [], [], [], []
-        for d, dev in enumerate(devices):
-            if multiproc and dev.process_index != proc:
-                continue          # that process stages its own pieces
-            ts_k, val_k, ph_k, s0_k, g_k = [], [], [], [], []
-            for p in by_dev[d]:
-                if mode == "phase":
-                    # no shard staged a ts plane; ship the 1-row dummy
-                    ts_k.append(_stage_put(
-                        np.zeros((1, lmax), np.int32), dev))
-                else:
-                    ts_d = _stage_put(p.ts, dev)
-                    ts_k.append(_pad_piece(ts_d, lmax, 0))
-                val_d = _stage_put(p.vals, dev)
-                val_k.append(_pad_piece(val_d, lmax, np.nan))
-                if mode == "phase":
-                    ph = _stage_put(p.phase, dev)
-                    ph_k.append(jnp.pad(ph, (0, lmax - ph.shape[0]),
-                                        constant_values=1)
-                                if ph.shape[0] != lmax else ph)
-                s0_k.append(int(p.steps0_rel))
-                # -1 marks unrequested lanes (devicestore.mesh_plan);
-                # rewrite to THIS query's drop bucket
-                g = np.full(lmax, groups_total, np.int32)
-                g[:len(p.garr)] = np.where(p.garr < 0, groups_total,
-                                           p.garr)
-                g_k.append(g)
-            while len(ts_k) < ksub:                # filler shard slices
+        ts_k, val_k, s0_k = [], [], []
+        for p in lst:
+            if mode == "ts":
+                ts_k.append(_pad_piece(_stage_put(p.ts, dev), lmax, 0))
+            val_k.append(_pad_piece(_stage_put(p.vals, dev), lmax, np.nan))
+            s0_k.append(int(p.steps0_rel))
+        while len(val_k) < ksub:
+            if mode == "ts":
                 ts_k.append(_stage_put(
-                    np.zeros((ts_rows, lmax), np.int32), dev))
-                val_k.append(_stage_put(
-                    np.full((nrows, lmax), np.nan, vdt), dev))
-                if mode == "phase":
-                    ph_k.append(_stage_put(np.ones(lmax, np.int32),
-                                               dev))
-                s0_k.append(0)
-                g_k.append(np.full(lmax, groups_total, np.int32))
-            ts_pieces.append(jnp.stack(ts_k))
-            val_pieces.append(jnp.stack(val_k))
-            if mode == "phase":
-                ph_pieces.append(jnp.stack(ph_k))
-            else:
-                ph_pieces.append(_stage_put(
-                    np.ones((ksub, lmax), np.int32), dev))
-            s0_pieces.append(_stage_put(
-                np.asarray(s0_k, np.int32), dev))
-            g_pieces.append(_stage_put(np.stack(g_k), dev))
+                    np.zeros((nrows, lmax), np.int32), dev))
+            val_k.append(_stage_put(
+                np.full((nrows, lmax), np.nan, vdt), dev))
+            s0_k.append(0)
+        ts = jnp.stack(ts_k) if mode == "ts" else _stage_put(
+            np.zeros((ksub, 1, lmax), np.int32), dev)
+        return ((ts, jnp.stack(val_k),
+                 _stage_put(np.asarray(s0_k, np.int32), dev)),
+                tuple((p.ts, p.vals) for p in lst))
 
-        def assemble(pieces, trailing_shape):
-            shape = (Kp, *trailing_shape)
-            sharding = NamedSharding(
-                mesh, P(_AXES, *([None] * len(trailing_shape))))
-            return jax.make_array_from_single_device_arrays(
-                shape, sharding, pieces)
+    def build_phase(dev, lst):
+        ph_k = []
+        for p in lst if mode == "phase" else ():
+            ph = _stage_put(p.phase, dev)
+            ph_k.append(jnp.pad(ph, (0, lmax - ph.shape[0]),
+                                constant_values=1)
+                        if ph.shape[0] != lmax else ph)
+        while len(ph_k) < ksub:
+            ph_k.append(_stage_put(np.ones(lmax, np.int32), dev))
+        return (jnp.stack(ph_k),), tuple(p.phase for p in lst)
 
-        g_ts = assemble(ts_pieces, (ts_rows, lmax))
-        g_vals = assemble(val_pieces, (nrows, lmax))
-        g_ph = assemble(ph_pieces, (lmax,))
-        g_s0 = assemble(s0_pieces, ())
-        g_garr = assemble(g_pieces, (lmax,))
-        nbytes = sum(int(a.nbytes)
-                     for a in (g_ts, g_vals, g_ph, g_s0, g_garr))
-        # the memoized assembled residents are what actually pins HBM
-        # between queries — ledger them (the per-piece staging arrays
-        # above are transient and die once assembly completes)
-        for a in (g_ts, g_vals, g_ph, g_s0, g_garr):
-            LEDGER.track(a, owner="meshgrid:assembly", fmt="mesh-staged")
-        _memo_insert(memo_key,
-                     (g_ts, g_vals, g_ph, g_s0, g_garr, tuple(plans)),
-                     nbytes)
+    # keyed by what the pieces are made of, never by what the query
+    # asks: the assembled residents serve every aggregator family over
+    # every selection of lanes and of shards (a dashboard switching sum
+    # -> topk, a namespace panel after a workspace-wide one, re-use them)
+    layout = (engine._key, str(plans[0].vals.dtype), ts_rows, nrows, lmax,
+              ksub)
+    planes, built_planes = _pieces(
+        ("planes", *layout), mine, by_dev,
+        lambda p: (id(p.ts) if mode == "ts" else 0, id(p.vals),
+                   p.steps0_rel), build_planes)
+    phases, built_phases = _pieces(
+        ("phase", *layout, mode == "phase"), mine, by_dev,
+        lambda p: id(p.phase) if mode == "phase" else 0, build_phase)
+    STATS["assembles" if built_planes or built_phases
+          else "memo_hits"] += 1
+    g_ts, g_vals, g_s0 = (assemble([pc[i] for pc in planes])
+                          for i in range(3))
+    g_ph = assemble([pc[0] for pc in phases])
+
+    def rows(kind: tuple, row_of):
+        """The query's own [Kp, width] int32 row, a slice a shard plan
+        (``row_of(plan.garr)``; a filler slice is a plan that asked for
+        no lane); memoized on the lanes asked, beside the planes and
+        never with them."""
+        key = (engine._key, kind, lmax, ksub,
+               tuple((d, _garr_fp(p.garr)) for d, p in slots))
+        hit = _memo_get(_ROWS_MEMO, key)
+        if hit is not None:
+            return hit[0]
+        nothing = np.empty(0, np.int32)
+        arr = assemble([
+            _stage_put(np.stack(
+                [row_of(p.garr) for p in by_dev[d]]
+                + [row_of(nothing)] * (ksub - len(by_dev[d]))), dev)
+            for d, dev in mine])
+        _memo_insert(_ROWS_MEMO, key, (arr,), int(arr.nbytes),
+                     _ROWS_MEMO_CAP)
+        return arr
 
     return _Prepared(q, mode, op, stride, groups_total, ksub, nrows,
-                     lmax, Kp, by_dev, (g_ts, g_vals, g_ph, g_s0, g_garr))
+                     lmax, Kp, by_dev, multiproc,
+                     (g_ts, g_vals, g_ph, g_s0), rows)
+
+
+def _fetch(out, dtype=np.float64) -> np.ndarray:
+    """Wait for a launch's result and copy it to the host, as the
+    ``mesh.device_wait`` (a wait: no annotation) and ``mesh.readback``
+    stages; as ``devicestore._fetch`` the explicit wait adds no sync, and
+    the sync is declared where it is asked for (``# host-sync-ok``)."""
+    jax, _ = _jax()
+    with TRACER.stage("mesh.device_wait", leaf=False):
+        jax.block_until_ready(out)
+    with TRACER.stage("mesh.readback") as sp:
+        host = np.asarray(out, dtype=dtype)
+        sp.tag(bytes=int(host.nbytes))
+    return host
+
+
+def _exact_width(prep: _Prepared, plans: Sequence) -> Optional[int]:
+    """How many lanes a slice gathers for the EXACT quantile, or None
+    where the sketch serves: the largest group's member count over all
+    shards (known here, before the launch: every plan's group row) is
+    at most ``QuantileAggregator.exact_members``, the one number that
+    separates exact from sketch on the per-shard rung too.  Across
+    processes a group's members on the other hosts cannot be counted
+    here: the sketch, whose partials merge over the wire."""
+    from filodb_tpu.query.aggregators import QuantileAggregator
+    if prep.multiproc or prep.stride > 1:
+        return None
+    asked = [p.garr[p.garr >= 0] for p in plans]
+    counts = np.bincount(np.concatenate(asked))
+    if counts.max(initial=0) > QuantileAggregator.exact_members:
+        return None
+    widest = max(len(a) for a in asked)
+    width = QuantileAggregator.exact_members
+    while width < widest:          # a program a power of two, not a count
+        width *= 2
+    return min(width, prep.lmax)
 
 
 def serve_grid_mesh(engine, plans: Sequence, num_groups: int,
@@ -758,7 +968,8 @@ def serve_grid_mesh(engine, plans: Sequence, num_groups: int,
     Returns the mergeable partial state dict — moment planes
     ({"sum","count"[,"sumsq"]} / {"min"} / {"max"}), k-slots
     ({"values","sidx"} plus the private "_slots"/"_lmax" lane-resolution
-    keys the caller maps to series tags), t-digests
+    keys the caller maps to series tags), the quantile's exact members
+    ({"members"}) or, past ``exact_members``, its t-digests
     ({"td_means","td_weights"}), or value counts
     ({"cv_vals","cv_counts"}) — or None when the plans cannot compose
     (mixed query shapes, unsupported op)."""
@@ -769,41 +980,57 @@ def serve_grid_mesh(engine, plans: Sequence, num_groups: int,
     stride, groups_total = prep.stride, prep.groups_total
     ksub, nrows, lmax, Kp = prep.ksub, prep.nrows, prep.lmax, prep.Kp
     by_dev = prep.by_dev
-    g_ts, g_vals, g_ph, g_s0, g_garr = prep.arrays
 
     if op in ("topk", "bottomk"):
         k = int(float(params[0]))
         prog = _grid_mesh_topk_program(engine._key, q, mode, ksub, nrows,
                                        lmax, groups_total, k,
                                        op == "bottomk")
-        v, si = prog(g_ts, g_vals, g_ph, g_s0, g_garr)
+        v, si = prog(*prep.arrays)
         STATS["serves"] += 1
         pos = {id(p): i for i, p in enumerate(plans)}
         slots = tuple(pos.get(id(lst[kk]), -1) if kk < len(lst) else -1
                       for lst in by_dev for kk in range(ksub))
-        return {"values": np.asarray(v, dtype=np.float64),  # host-sync-ok: topk partial values land on host for cross-shard merge
-                "sidx": np.asarray(si, dtype=np.int64),  # host-sync-ok: topk partial indices ride back with the values
+        return {"values": _fetch(v),  # host-sync-ok: topk partial values land on host for cross-shard merge
+                "sidx": _fetch(si, np.int64),  # host-sync-ok: topk partial indices ride back with the values
                 "_slots": slots, "_lmax": lmax}
     if op == "quantile":
+        from filodb_tpu.query.aggregators import (QuantileAggregator,
+                                                  members_state)
+        width = _exact_width(prep, plans)
+        if width is not None:
+            prog = _grid_mesh_members_program(engine._key, q, mode, ksub,
+                                              nrows, lmax, width)
+            out = prog(*prep.planes,
+                       prep.rows(("sel", width), _sel_row(width)))
+            STATS["serves"] += 1
+            picked = _fetch(out)  # host-sync-ok: the selected members [Kp, T, width], never every lane
+            vals, gids = [], []
+            for d, lst in enumerate(by_dev):
+                for kk, p in enumerate(lst):
+                    asked = p.garr[p.garr >= 0]
+                    vals.append(picked[d * ksub + kk, :, :len(asked)].T)
+                    gids.append(asked)
+            return members_state(np.concatenate(vals),
+                                 np.concatenate(gids), num_groups)
         # same compression as the host QuantileAggregator: mesh and host
         # digests merge at matched accuracy
-        from filodb_tpu.query.aggregators import QuantileAggregator
         prog = _grid_mesh_quantile_program(engine._key, q, mode, ksub,
                                            nrows, lmax, groups_total,
                                            QuantileAggregator.compression)
-        m, w = prog(g_ts, g_vals, g_ph, g_s0, g_garr)
+        m, w = prog(*prep.arrays)
         STATS["serves"] += 1
-        return {"td_means": np.asarray(m, dtype=np.float64),  # host-sync-ok: t-digest means partial lands on host for merge
-                "td_weights": np.asarray(w, dtype=np.float64)}  # host-sync-ok: t-digest weights partial lands on host for merge
+        return {"td_means": _fetch(m),  # host-sync-ok: t-digest means partial lands on host for merge
+                "td_weights": _fetch(w)}  # host-sync-ok: t-digest weights partial lands on host for merge
     if op == "values":
         from filodb_tpu.query.aggregators import count_values_state
         prog = _grid_mesh_values_program(engine._key, q, mode, ksub,
                                          nrows, lmax)
-        out = prog(g_ts, g_vals, g_ph, g_s0)
+        out = prog(*prep.planes)
         STATS["serves"] += 1
         # only the [lanes, T] stepped matrix crosses the host link — the
         # raw [nrows, lanes] residents never re-upload or read back
-        stepped = np.asarray(out, dtype=np.float64)    # [Kp, lmax, T]  # host-sync-ok: only the [lanes, T] stepped matrix crosses the host link (comment below)
+        stepped = _fetch(out)  # host-sync-ok: count_values reads the [Kp, lmax, T] stepped matrix; the raw residents never read back
         garr_all = np.full((Kp, lmax), -1, np.int32)
         for d, lst in enumerate(by_dev):
             for kk, p in enumerate(lst):
@@ -815,22 +1042,22 @@ def serve_grid_mesh(engine, plans: Sequence, num_groups: int,
 
     prog = _grid_mesh_program(engine._key, q, mode, ksub, nrows, lmax,
                               groups_total, op)
-    out = prog(g_ts, g_vals, g_ph, g_s0, g_garr)
+    out = prog(*prep.arrays)
     STATS["serves"] += 1
     if stride > 1:
         # histogram: [2, G*hb, T] -> the MomentAggregator hist state
         from filodb_tpu.memstore.devicestore import hist_state_from_planes
-        both = np.asarray(out, dtype=np.float64)  # host-sync-ok: hist planes [2, G*hb, T] — the designed readback for hist state
+        both = _fetch(out)  # host-sync-ok: hist planes [2, G*hb, T] — the designed readback for hist state
         return hist_state_from_planes(both, num_groups, stride,
                                       np.asarray(plans[0].bucket_tops))
     if op in ("sum", "avg", "count", "moments"):
-        both = np.asarray(out, dtype=np.float64)       # [2|3, G, T]  # host-sync-ok: ONE readback of the stacked [2|3, G, T] partials
+        both = _fetch(out)  # host-sync-ok: ONE readback of the stacked [2|3, G, T] partials
         if op == "count":
             return {"count": both[1]}
         if op == "moments":
             return {"sum": both[0], "count": both[1], "sumsq": both[2]}
         return {"sum": both[0], "count": both[1]}
-    a = np.asarray(out, dtype=np.float64)  # host-sync-ok: single readback of the [G, T] reduced partial
+    a = _fetch(out)  # host-sync-ok: single readback of the [G, T] reduced partial
     return {op: np.where(np.isfinite(a), a, np.nan)}
 
 
@@ -852,7 +1079,6 @@ def serve_grid_mesh_presented(engine, plans: Sequence, num_groups: int,
     prep = _prepare(engine, plans, num_groups, operator)
     if prep is None:
         return None
-    g_ts, g_vals, g_ph, g_s0, g_garr = prep.arrays
     if prep.stride > 1:
         if hist_phi is None:
             return None    # hist sum presents host-side (hist batch out)
@@ -861,7 +1087,7 @@ def serve_grid_mesh_presented(engine, plans: Sequence, num_groups: int,
             prep.lmax, num_groups, prep.stride, float(hist_phi))
         _, jnp = _jax()
         tops = jnp.asarray(np.asarray(plans[0].bucket_tops))
-        out = prog(g_ts, g_vals, g_ph, g_s0, g_garr, tops)
+        out = prog(*prep.arrays, tops)
         program = "meshgrid.fused_histq"
     else:
         if hist_phi is not None:
@@ -869,12 +1095,12 @@ def serve_grid_mesh_presented(engine, plans: Sequence, num_groups: int,
         prog = _grid_mesh_present_program(
             engine._key, prep.q, prep.mode, prep.ksub, prep.nrows,
             prep.lmax, num_groups, prep.op, agg)
-        out = prog(g_ts, g_vals, g_ph, g_s0, g_garr)
+        out = prog(*prep.arrays)
         program = "meshgrid.fused"
     STATS["serves"] += 1
     STATS["fused_serves"] += 1
     _mm()["fused_serves"].inc(program=program)
-    return np.asarray(out, dtype=np.float64)  # host-sync-ok: THE single [G, T] readback of the fused fabric answer
+    return _fetch(out)  # host-sync-ok: THE single [G, T] readback of the fused fabric answer
 
 
 def serve_event_topk(engine, plans: Sequence, num_groups: int, k: int,
@@ -896,10 +1122,9 @@ def serve_event_topk(engine, plans: Sequence, num_groups: int, k: int,
     prog = _grid_mesh_event_topk_program(
         engine._key, prep.q, prep.mode, prep.ksub, prep.nrows, prep.lmax,
         num_groups, kk, bool(largest))
-    g_ts, g_vals, g_ph, g_s0, g_garr = prep.arrays
-    v, gi = prog(g_ts, g_vals, g_ph, g_s0, g_garr)
+    v, gi = prog(*prep.arrays)
     STATS["serves"] += 1
     STATS["fused_serves"] += 1
     _mm()["fused_serves"].inc(program="meshgrid.event_topk")
-    return (np.asarray(v, dtype=np.float64),  # host-sync-ok: [T, k] selected event-group values, the designed readback
-            np.asarray(gi, dtype=np.int64))  # host-sync-ok: [T, k] selected group ids ride back with the values
+    return (_fetch(v),  # host-sync-ok: [T, k] selected event-group values, the designed readback
+            _fetch(gi, np.int64))  # host-sync-ok: [T, k] selected group ids ride back with the values

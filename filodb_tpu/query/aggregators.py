@@ -475,6 +475,26 @@ def _nan_quantile(members: np.ndarray, q: float) -> np.ndarray:
     return vals.reshape(G, T)
 
 
+def members_state(vals2d: np.ndarray, gids: np.ndarray,
+                  num_groups: int) -> dict:
+    """The exact quantile's partial from windowed series values:
+    ``vals2d`` [S, T] stepped values, ``gids`` [S] group per series ->
+    {"members": [G, M, T]}, NaN beyond a group's own members (the state
+    :func:`_dense_members_map` builds a series at a time).  For the mesh
+    paths, which hold every shard's lanes at once."""
+    gids = np.asarray(gids, dtype=np.int64)
+    G = max(int(num_groups), 1)
+    counts = np.bincount(gids, minlength=G)
+    order = np.argsort(gids, kind="stable")
+    # a member's place within its group, in the groups' order
+    place = np.arange(len(gids)) - np.repeat(np.cumsum(counts) - counts,
+                                             counts)
+    dense = np.full((G, max(int(counts.max(initial=0)), 1),
+                     vals2d.shape[1]), np.nan)
+    dense[gids[order], place] = vals2d[order]
+    return {"members": dense}
+
+
 # count_values guards: the (group, value, step) count cube is bounded by
 # the response itself (one output series per distinct (group, value)), so
 # exceeding these is a cardinality error, not an OOM (the reference's

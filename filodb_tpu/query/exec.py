@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures
+import contextlib
 import dataclasses
 import threading
 from typing import Optional, Sequence
@@ -60,6 +61,23 @@ _ACTIVE = threading.local()
 
 def active_exec_ctx() -> Optional["ExecContext"]:
     return getattr(_ACTIVE, "ctx", None)
+
+
+@contextlib.contextmanager
+def leaf_scan(ctx: "ExecContext"):
+    """What a data leaf runs under (a per-shard leaf, the mesh node that
+    scans every local shard at once): the leaf owns the "scan" stage
+    bucket; lower layers without a ctx parameter (ODP page-in,
+    predecode) attribute theirs through the active-ctx thread-local
+    installed here, and the stage spans that end inside the scan land
+    in this query's timings under their own names."""
+    prev = getattr(_ACTIVE, "ctx", None)
+    _ACTIVE.ctx = ctx
+    try:
+        with TRACER.stage("scan", leaf=False, timings=ctx):
+            yield
+    finally:
+        _ACTIVE.ctx = prev
 
 
 # the steps of one grid call (doc/observability.md "Stage spans"): a
@@ -521,32 +539,21 @@ class MultiSchemaPartitionsExec(LeafExecPlan):
         self.reshard_to = tuple(reshard_to) if reshard_to else None
 
     def do_execute(self, ctx: ExecContext) -> list:
-        # the leaf owns the "scan" stage bucket; lower layers without a
-        # ctx parameter (ODP page-in, predecode) attribute theirs
-        # through the active-ctx thread-local installed here
-        prev = getattr(_ACTIVE, "ctx", None)
-        _ACTIVE.ctx = ctx
-        try:
-            # ... and the stage spans that end inside the scan land in
-            # this query's timings under their own names
-            with TRACER.stage("scan", leaf=False, timings=ctx):
-                shard = ctx.memstore.get_shard(self.dataset, self.shard)
-                lookup = shard.lookup_partitions(
-                    self.filters, self.start_ms, self.end_ms)
-                if self.reshard_to is not None:
-                    lookup = shard.filter_resharded(lookup,
-                                                    *self.reshard_to)
-                try:
-                    batches = self._do_scan(ctx, shard, lookup)
-                    self._note_batch_counts(ctx, batches)
-                    return batches
-                finally:
-                    # AFTER the scan, so corruption detected by this
-                    # very query already counts toward its own
-                    # partial-data warning
-                    self._note_quarantined(ctx, shard, lookup.part_ids)
-        finally:
-            _ACTIVE.ctx = prev
+        with leaf_scan(ctx):
+            shard = ctx.memstore.get_shard(self.dataset, self.shard)
+            lookup = shard.lookup_partitions(
+                self.filters, self.start_ms, self.end_ms)
+            if self.reshard_to is not None:
+                lookup = shard.filter_resharded(lookup, *self.reshard_to)
+            try:
+                batches = self._do_scan(ctx, shard, lookup)
+                self._note_batch_counts(ctx, batches)
+                return batches
+            finally:
+                # AFTER the scan, so corruption detected by this very
+                # query already counts toward its own partial-data
+                # warning
+                self._note_quarantined(ctx, shard, lookup.part_ids)
 
     @staticmethod
     def _note_batch_counts(ctx: ExecContext, batches) -> None:

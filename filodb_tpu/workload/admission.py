@@ -8,7 +8,8 @@ door: every query the HTTP layer is about to schedule first passes
 
 - the query's **estimated cost** (workload/cost.py) and **remaining
   deadline budget** (workload/deadline.py),
-- the node's **calibrated throughput** (cost units/second x workers),
+- the node's **calibrated cost of a query** (seconds = a fixed part +
+  a per-unit part, workload/cost.py) and its workers,
 - what is already **in flight** globally, per tenant, and per priority
   class.
 
@@ -16,7 +17,8 @@ A query is shed with HTTP 429 + ``Retry-After`` (never queued to rot)
 when any of these hold:
 
 - its deadline already expired (reason ``expired``);
-- the estimated queue delay — inflight cost over calibrated throughput —
+- the estimated queue delay — the predicted seconds of what is in
+  flight and of the query itself, over the workers —
   exceeds the remaining budget (reason ``deadline``): executing it
   would be dead work by construction;
 - admitting it would push inflight cost past its priority class's
@@ -132,10 +134,13 @@ class AdmissionController:
     # -------------------------------------------------------------- admission
 
     def queue_delay_est_s(self, extra_cost: float = 0.0) -> float:
-        """Expected wait before ``extra_cost`` units would COMPLETE,
-        given what is already in flight and the calibrated rate."""
-        rate = self.cost_model.units_per_second() * self.workers
-        return (self._inflight_cost + extra_cost) / max(rate, 1e-9)
+        """Expected wait before a query of ``extra_cost`` units would
+        COMPLETE (0: before a worker is free), given what is already in
+        flight: the model's seconds for all of it, each query's fixed
+        part and every unit's share, spread over the workers."""
+        return self.cost_model.estimate_seconds(
+            self._inflight_cost + extra_cost,
+            self._inflight_queries + (extra_cost > 0)) / self.workers
 
     def admit(self, qctx: QueryContext, cost: float):
         """Admit or raise :class:`AdmissionRejected`.  Returns a context
@@ -164,10 +169,10 @@ class AdmissionController:
             ceiling = share * self.max_inflight_cost
             if self._inflight_cost + cost > ceiling:
                 over = self._inflight_cost + cost - ceiling
-                rate = self.cost_model.units_per_second() * self.workers
                 self._reject(
                     qctx, tenant, priority, "overload",
-                    math.ceil(over / max(rate, 1e-9)),
+                    math.ceil(self.cost_model.estimate_seconds(over, 0)
+                              / self.workers),
                     f"inflight cost {self._inflight_cost:.0f} + "
                     f"{cost:.0f} exceeds the {priority!r} ceiling "
                     f"{ceiling:.0f} (of {self.max_inflight_cost:.0f})")
@@ -234,7 +239,8 @@ class AdmissionController:
                 "inflight_queries": self._inflight_queries,
                 "tenant_inflight_cost": dict(self._tenant_cost),
                 "tenant_running": dict(self._tenant_running),
-                "sec_per_unit": 1.0 / self.cost_model.units_per_second(),
+                "sec_per_unit": self.cost_model.sec_per_unit,
+                "sec_fixed": self.cost_model.fixed_seconds,
                 "calibration_observations": self.cost_model.observations,
             }
 

@@ -26,11 +26,19 @@ leaves — scatter-gather children are near-uniform by construction
 (spread-sharded), so this is the right prior.
 
 **Online calibration** (ISSUE 5 tentpole): every admitted query reports
-its observed wall time back via :meth:`observe`; an EWMA of
-seconds-per-unit converts abstract cost into predicted seconds and a
-sustainable units/second rate — the admission controller's queue-delay
-estimate.  The PR 7 per-stage QueryStats timings feed this loop: the
-HTTP layer observes with the query's measured total.
+its observed wall time back via :meth:`observe`, and the model predicts
+seconds from units as ``fixed + per_unit x units``, both parts learned
+from those (units, seconds) pairs: exponentially weighted means, the
+variance of the units and their covariance with the seconds are all the
+state.  A query the device path serves costs about the same whatever it
+selects (a namespace sum of ~770 units and a workspace-wide sum of
+~614 000 both take tens of milliseconds: launch, readback and the
+interpreter, not the lanes), so a line through the origin learned on the
+small ones priced the large ones at half a minute and admission shed
+them with the chips idle (PERF.md section 6, PR 33 and PR 34).  Where
+every observed query costs the same, or the seconds really are
+proportional to the units, the fixed part is 0 and the model is the
+through-the-origin one it was.
 """
 
 from __future__ import annotations
@@ -78,12 +86,16 @@ class CostModel:
     def __init__(self, chunk_window_ms: int = DEFAULT_CHUNK_WINDOW_MS,
                  sec_per_unit: float = 2e-5, alpha: float = 0.2):
         self.chunk_window_ms = max(int(chunk_window_ms), 1)
-        # EWMA state: seconds one cost unit takes on THIS node, seeded
-        # with a deliberately optimistic prior so cold admission never
-        # sheds; a few observed queries converge it
-        self._sec_per_unit = float(sec_per_unit)
+        # seconds = _fixed + _per_unit x units on THIS node.  Before any
+        # query has been observed the line goes through the origin at a
+        # deliberately optimistic slope, so cold admission never sheds;
+        # a few observed queries converge it
+        self._fixed = 0.0
+        self._per_unit = float(sec_per_unit)
         self._alpha = float(alpha)
         self._observed = 0
+        # exponentially weighted moments of the observed (units, seconds)
+        self._mean_x = self._mean_y = self._var_x = self._cov_xy = 0.0
         self._lock = threading.Lock()
 
     # ------------------------------------------------------------ estimation
@@ -102,11 +114,18 @@ class CostModel:
             total += h * self._chunks(leaf) * self._weight(leaf)
         return max(total, 1.0)
 
-    def estimate_seconds(self, cost: float) -> float:
-        return cost * self._sec_per_unit
+    def estimate_seconds(self, cost: float, queries: int = 1) -> float:
+        """Predicted seconds of ``queries`` queries of ``cost`` units in
+        all: each pays the fixed part, every unit the per-unit part."""
+        return queries * self._fixed + cost * self._per_unit
 
-    def units_per_second(self) -> float:
-        return 1.0 / self._sec_per_unit
+    @property
+    def sec_per_unit(self) -> float:
+        return self._per_unit
+
+    @property
+    def fixed_seconds(self) -> float:
+        return self._fixed
 
     @property
     def observations(self) -> int:
@@ -116,27 +135,50 @@ class CostModel:
 
     def observe(self, cost: float, seconds: float) -> None:
         """Fold one completed query's (estimated cost, measured wall
-        seconds) into the EWMA; drives units_per_second toward the
-        node's real throughput.
+        seconds) into the moments and refit the line.
 
-        UPWARD moves are rate-limited to 4x per observation: shed
-        queries never observe, so a single compile-inflated cold-start
-        sample that overshoots the shed threshold could otherwise wedge
-        admission into rejecting a whole traffic class with nothing
-        left to pull the estimate back down.  A genuinely slow node
-        still converges geometrically; downward (faster-than-believed)
-        moves are unrestricted."""
+        UPWARD moves are rate-limited: shed queries never observe, so a
+        single compile-inflated cold-start sample that overshoots the
+        shed threshold could otherwise wedge admission into rejecting a
+        whole traffic class with nothing left to pull the estimate back
+        down.  The seconds are held to what moves the prediction AT
+        THIS COST by at most 4x a step while every query costs the same
+        (the first sample: 4x the prior).  A genuinely slow node still
+        converges geometrically; downward (faster-than-believed) moves
+        are unrestricted."""
         if cost <= 0 or seconds < 0:
             return
-        obs = seconds / cost
+        a = self._alpha
         with self._lock:
-            prev = self._sec_per_unit
+            believed = self._fixed + cost * self._per_unit
             if self._observed == 0:
-                nxt = obs
+                self._mean_x, self._mean_y = cost, min(seconds,
+                                                       4.0 * believed)
             else:
-                nxt = prev + self._alpha * (obs - prev)
-            self._sec_per_unit = min(nxt, prev * 4.0)
+                seconds = min(seconds, believed * (3.0 + a) / a)
+                dx, dy = cost - self._mean_x, seconds - self._mean_y
+                self._mean_x += a * dx
+                self._mean_y += a * dy
+                self._var_x = (1.0 - a) * (self._var_x + a * dx * dx)
+                self._cov_xy = (1.0 - a) * (self._cov_xy + a * dx * dy)
             self._observed += 1
+            self._fit()
+
+    def _fit(self) -> None:  # holds-lock: _lock
+        """The weighted least-squares line through the moments, held to
+        fixed >= 0 and per_unit >= 0: a slope below 0 (the larger
+        queries were the faster ones) reads as all fixed cost, a line
+        that would cross below 0 seconds as none.  One cost observed so
+        far says nothing about the split: through the origin, as the
+        prior is."""
+        through_origin = self._mean_y / self._mean_x
+        if self._var_x <= 1e-12 * self._mean_x * self._mean_x:
+            slope = through_origin
+        else:
+            slope = min(max(self._cov_xy / self._var_x, 0.0),
+                        through_origin)
+        self._per_unit = slope
+        self._fixed = max(self._mean_y - slope * self._mean_x, 0.0)
 
     # -------------------------------------------------------------- internals
 

@@ -108,6 +108,57 @@ def test_served_programs_are_devicestore_and_stacked_say_batch():
     assert batch_metrics()["members"].name == "filodb_batch_members_total"
 
 
+# every ``devicewatch.jit(..., program="<name>")`` of a source file
+_NAMED = re.compile(r"""devicewatch\.jit,?[^)]*?program=["']([\w.]+)["']""",
+                    re.S)
+_FAMILY_SOURCES = {
+    "devicestore.": ROOT / "filodb_tpu" / "memstore" / "devicestore.py",
+    "meshgrid.": ROOT / "filodb_tpu" / "parallel" / "meshgrid.py"}
+_HELPERS = _run_py_constant("HELPERS")
+_STACKED = _run_py_constant("STACKED")
+
+
+def test_run_py_counts_the_two_families_with_a_source():
+    assert set(_run_py_constant("SERVING_FAMILIES")) == set(_FAMILY_SOURCES)
+    assert sorted(_HELPERS) == ["devicestore.mesh_stage", "meshgrid.pad"]
+
+
+@pytest.mark.parametrize("family", sorted(_FAMILY_SOURCES))
+def test_every_program_of_a_family_is_named_for_it(family):
+    """``device_dispatches`` finds a family's launches by the prefix of
+    ``filodb_kernel_launches_total{program=...}``, leaves out the helper
+    that answers no request by its exact name, and counts a stacked
+    launch by its members: every serving program of ``meshgrid.py`` is
+    ``meshgrid.*`` through ``devicewatch.jit``, none of them stacked (a
+    fused mesh launch answers one request), and each family's helper is
+    there once under the name ``run.py`` leaves out (the cases of
+    ``benchmark/selftest/test_program_names.py``, held by tier-1)."""
+    names = _NAMED.findall(_FAMILY_SOURCES[family].read_text())
+    assert len(names) >= 7, names
+    assert all(n.startswith(family) for n in names), names
+    helper, = [h for h in _HELPERS if h.startswith(family)]
+    assert names.count(helper) == 1, names
+    stacked = sorted(n for n in names if _STACKED in n)
+    if family == "meshgrid.":
+        assert not stacked
+        # the rungs dev4.mesh-wide is served by, and the sketch past
+        # ``exact_members``
+        assert {"meshgrid.fused", "meshgrid.grouped", "meshgrid.members",
+                "meshgrid.quantile"} <= set(names)
+    else:
+        assert stacked == ["devicestore.grouped_batch",
+                           "devicestore.series_batch"]
+
+
+@pytest.mark.parametrize("span", [
+    "mesh.collect", "mesh.stage", "mesh.assemble", "mesh.dispatch",
+    "mesh.device_wait", "mesh.readback", "mesh.present"])
+def test_fabric_stage_span_is_emitted(span, emitted):
+    """The mesh fabric's stage spans (PR 34; doc/observability.md "Stage
+    spans"), read by the ``mesh_*`` metrics of ``dev4.mesh-wide``."""
+    assert span in emitted
+
+
 _T0 = 1_700_000_000_000
 
 

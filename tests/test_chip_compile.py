@@ -362,3 +362,66 @@ def test_fused_mesh_program_four_chips(topo, as_tpu):
         on_mesh((ndev * ksub, lmax), jnp.int32, None)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+# the width dev-4shard's four caches agree on (26 368 lanes a chip) and the
+# rows a 23-step panel at a 150 s step covers: what dev4.mesh-wide launches
+_DEV4_LANES, _DEV4_ROWS = 26_368, 240
+
+
+def _dev4_query(op: str, dense: bool) -> GridQuery:
+    return GridQuery(23, 20, GSTEP, op=op, dense=dense, stride=10)
+
+
+def _on_mesh(mesh, shape, dtype, *rest):
+    from filodb_tpu.parallel import meshgrid
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P(meshgrid._AXES, *rest)))
+
+
+@pytest.mark.parametrize("mode,op", [("phase", "rate"), ("free", "sum")])
+def test_fused_mesh_program_at_the_deployments_width(topo, as_tpu, mode, op):
+    """``meshgrid.fused`` as ``dev4.mesh-wide`` launches it: a shard a
+    chip at the caches' common width, the workspace-wide ``sum(rate)``
+    (phase mode) and ``sum(sum_over_time)`` (no ts plane and no phase:
+    ``free``)."""
+    from filodb_tpu.parallel import mesh as pmesh
+    from filodb_tpu.parallel import meshgrid
+    mesh = pmesh.make_mesh(list(topo.devices))
+    q = _dev4_query(op, dense=True)
+    fn = meshgrid._grid_mesh_present_program(
+        pmesh._mesh_key(mesh), q, mode, 1, _DEV4_ROWS, _DEV4_LANES, 1,
+        "sum", "sum")
+    compiled = fn._jitted.lower(
+        _on_mesh(mesh, (4, 1, _DEV4_LANES), jnp.int32, None, None),
+        _on_mesh(mesh, (4, _DEV4_ROWS, _DEV4_LANES), jnp.float32, None, None),
+        _on_mesh(mesh, (4, _DEV4_LANES), jnp.int32, None),
+        _on_mesh(mesh, (4,), jnp.int32),
+        _on_mesh(mesh, (4, _DEV4_LANES), jnp.int32, None)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+def test_mesh_members_program_four_chips(topo, as_tpu):
+    """``meshgrid.members``, the exact quantile's launch: every chip
+    steps all of its lanes, keeps the 128 its ``sel`` row names and
+    all-gathers them, so what comes back is [4, T, 128] and never
+    [4, 26 368, T]."""
+    from filodb_tpu.parallel import mesh as pmesh
+    from filodb_tpu.parallel import meshgrid
+    mesh = pmesh.make_mesh(list(topo.devices))
+    q = _dev4_query("last", dense=False)
+    fn = meshgrid._grid_mesh_members_program(
+        pmesh._mesh_key(mesh), q, "free", 1, _DEV4_ROWS, _DEV4_LANES, 128)
+    compiled = fn._jitted.lower(
+        _on_mesh(mesh, (4, 1, _DEV4_LANES), jnp.int32, None, None),
+        _on_mesh(mesh, (4, _DEV4_ROWS, _DEV4_LANES), jnp.float32, None, None),
+        _on_mesh(mesh, (4, _DEV4_LANES), jnp.int32, None),
+        _on_mesh(mesh, (4,), jnp.int32),
+        _on_mesh(mesh, (4, 128), jnp.int32, None)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    out, = compiled.output_shardings if isinstance(
+        compiled.output_shardings, (list, tuple)) \
+        else (compiled.output_shardings,)
+    assert out.is_fully_replicated

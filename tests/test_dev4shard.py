@@ -45,8 +45,9 @@ PANELS = [pytest.param(i, id=p["name"])
 def boot(conf: dict, pop: Population) -> FiloServer:
     """The configuration's server block, booted as its cell runs it: on
     ONE device.  The tests' eight virtual devices would turn the mesh
-    fabric on (``standalone``: auto-on with more than one device), whose
-    cross-shard quantile is a t-digest sketch by design."""
+    fabric on (``standalone``: auto-on with more than one device) and
+    make every aggregate a ``MeshReduceExec``: that deployment is
+    ``dev-4shard-4chip``'s, held by ``tests/test_dev4mesh.py``."""
     block = json.loads(json.dumps(conf["server"]))
     for ds in block["datasets"]:
         ds["mesh"] = False

@@ -6,6 +6,7 @@ shows ONE stitched span tree including the remote shard's spans
 (propagated via the X-FiloDB-Trace-Id header + execplan-wire field)."""
 
 import json
+import time
 import urllib.parse
 import urllib.request
 
@@ -553,8 +554,17 @@ class TestStageClockEndpoints:
         _c, before, _h = _get(grid, "/admin/device")
         code, _b, _h = _grid_query(grid, self.QUERY)
         assert code == 200
-        _c, after, _h = _get(grid, "/admin/device")
-        b, a = before["data"]["stages"], after["data"]["stages"]
+        b = before["data"]["stages"]
+        # the handler thread hands its stages over once its request has
+        # ended, which is after the client has read the answer: a table
+        # read right then may still lack the query's ``serialize``
+        for _ in range(100):
+            _c, after, _h = _get(grid, "/admin/device")
+            a = after["data"]["stages"]
+            if a.get("serialize", {"count": 0})["count"] \
+                    > b.get("serialize", {"count": 0})["count"]:
+                break
+            time.sleep(0.02)
         for name in ("http.request", "http.encode", "http.write",
                      "query.plan", "scan", "device_compute", "serialize",
                      "grid.resolve", "grid.lock_wait", "grid.plan",
