@@ -50,6 +50,7 @@ from filodb_tpu.ops.grid import (DENSE_ONLY_OPS, PHASE_OPS, TS_FREE_OPS,
                                  supports_grid)
 from filodb_tpu.query.logical import RangeFunctionId as F
 from filodb_tpu.utils import devicewatch
+from filodb_tpu.utils.costmemo import CostMemo
 from filodb_tpu.utils.devicewatch import FLIGHT, LEDGER
 from filodb_tpu.utils.observability import TRACER
 
@@ -171,8 +172,17 @@ def hist_slot_garr(garr: np.ndarray, lane_idx: np.ndarray,
     segment reduce sums each bucket lane independently (the bucket-wise
     hist sum).  ONE definition — the single-device fused path and the
     mesh staging must never drift on this layout."""
-    cols = lane_idx[:, None] * hb + np.arange(hb)[None, :]
-    garr[cols] = gid_arr[:, None] * hb + np.arange(hb)
+    cols, slots = hist_slot_pairs(lane_idx, gid_arr, hb)
+    garr[cols] = slots
+
+
+def hist_slot_pairs(lane_idx: np.ndarray, gid_arr: np.ndarray,
+                    hb: int) -> tuple:
+    """The same layout as (column, group slot) pairs, series-major: what
+    the mesh path keeps in place of a dense map (MeshShardPlan)."""
+    buckets = np.arange(hb, dtype=np.int32)      # int32 slots stay int32
+    return ((lane_idx[:, None] * hb + buckets).ravel(),
+            (gid_arr[:, None] * hb + buckets).ravel())
 
 
 def hist_planes_split(both, num_groups: int, hb: int):
@@ -522,26 +532,73 @@ class _GridPlan(NamedTuple):
     hbm_dense: int = 0
     hbm_comp: int = 0
     hbm_comp_hist: int = 0
+    # the MeshShardPlans made of this plan, by grouping (mesh_plan): they
+    # live and die with the plan, so whatever retires the plan (version,
+    # ingest and removal epochs in its memo key; freeze, quarantine,
+    # repin, a lane-width change clearing the memo) retires them too
+    mesh: Optional[dict] = None
 
 
 class MeshShardPlan(NamedTuple):
-    """One shard's device-resident contribution to a mesh grid query."""
+    """One shard's device-resident contribution to a mesh grid query:
+    the staged planes, and the lanes the query asked of them as (column,
+    group slot) pairs.  Nothing here is as wide as the lanes RESIDENT:
+    the fabric scatters the pairs into its ``[Kp, lmax]`` rows itself
+    (parallel/meshgrid.py ``_prepare``)."""
 
     ts: object            # [nrows, ncols] int32, on this shard's device
     vals: object          # [nrows, ncols] f32/f64, same device
     phase: object         # [ncols] int32 device array or None
-    garr: np.ndarray      # [ncols] int32 col -> group slot (-1 = drop;
-    #                       hist: slot = gid*hb + bucket)
+    cols: np.ndarray      # [n] the columns asked, ascending
+    slots: np.ndarray     # [n] int32 group slot of each (hist: slot =
+    #                       gid*hb + bucket)
+    rows_fp: tuple        # content fingerprint of (cols, slots), made
+    #                       once with the plan: the fabric's rows memo
+    #                       keys on it and hashes no row a request
     q: "GridQuery"
     steps0_rel: int
     ncols: int
     device: object
     hb: int = 0           # bucket lanes per series (0 = scalar column)
     bucket_tops: object = None     # [hb] np array (hist only)
-    col_pids: object = None        # [ncols] int64 partition id per lane
-    #                                (-1 = unassigned); lets the k-slot
-    #                                mesh path resolve selected lanes back
-    #                                to series tags (scalar columns only)
+    part_ids: object = None        # the lookup result the columns came
+    order: object = None           # from, and cols[i]'s place in it
+    #                                (None: in request order): lets the
+    #                                k-slot mesh path resolve a selected
+    #                                lane back to its series (scalar
+    #                                columns only)
+
+    def pid_of_lane(self, lane: int) -> int:
+        """The partition id a lane was asked for, -1 where the query
+        asked for none there (or the column is a histogram's)."""
+        if self.hb or self.part_ids is None:
+            return -1
+        i = int(np.searchsorted(self.cols, lane))
+        if i >= len(self.cols) or self.cols[i] != lane:
+            return -1
+        return int(self.part_ids[i if self.order is None
+                                 else self.order[i]])
+
+
+def _mesh_lanes(plan: "_GridPlan", group_ids, hb: int) -> tuple:
+    """(cols ascending, their group slots, the columns' places in the
+    request or None where it was in lane order) of one mesh plan: every
+    lane-wide array a fabric request needs from a shard, made once a
+    (plan, grouping) and outside the grid lock (it reads the plan's
+    ``lane_idx`` and the caller's group ids, nothing the lock guards).
+    ``group_ids`` is one int where every series falls into one group."""
+    lane_idx = plan.lane_idx
+    n = len(lane_idx)
+    if isinstance(group_ids, (int, np.integer)):
+        gids = np.full(n, group_ids, dtype=np.int32)
+    else:
+        gids = np.asarray(group_ids, dtype=np.int32)
+    if hb:
+        lane_idx, gids = hist_slot_pairs(lane_idx, gids, hb)
+    if n < 2 or bool((lane_idx[1:] > lane_idx[:-1]).all()):
+        return lane_idx, gids, None
+    order = np.argsort(lane_idx, kind="stable")
+    return lane_idx[order], gids[order], order
 
 
 _MESH_STAGE_FN = None
@@ -699,7 +756,8 @@ class DeviceGridCache:
         self.disabled_until_version = -1
         self._disable_count = 0        # exponential re-try backoff
         self._disk_floor: Optional[tuple[int, int]] = None  # (ver, floor_ms)
-        self._preps: dict[int, dict] = {}   # id(part_ids) -> prep
+        # id(part_ids) -> prep, priced by the ids walked (_prep_for)
+        self._preps = CostMemo(16)
         # large-K shapes that failed the dense proof: deny until data
         # changes, so a refreshing dashboard doesn't re-pay speculative
         # block staging every cycle
@@ -717,8 +775,11 @@ class DeviceGridCache:
         # small: its key holds ``steps0``, and a dashboard whose ``end``
         # advances never hits.  Keys carry every invalidation axis
         # (cache version, ingest epoch, removal epoch, id-list
-        # fingerprint); cleared on freeze/repin/reclaim
-        self._plan_memo: dict[tuple, "_GridPlan"] = {}
+        # fingerprint); cleared on freeze/repin/reclaim.  When full ONE
+        # entry leaves, the cheapest to prove again first (its price is
+        # the lanes it asked for): 800 namespaces of 64 lanes turn over
+        # beside a workspace-wide plan of 25 000 and never push it out
+        self._plan_memo = CostMemo(8)
         # the last frozen-frontier walk: (state key, earliest buffered
         # row's timestamp or None) — see _frozen_high
         self._frontier: tuple = (None, None)
@@ -979,17 +1040,23 @@ class DeviceGridCache:
 
     def mesh_plan(self, part_ids: Sequence[int], func: F, steps0: int,
                   nsteps: int, step_ms: int, window_ms: int,
-                  group_ids: Sequence[int], fargs: tuple = ()):
+                  group_ids, fargs: tuple = ()):
         """Plan + device-RESIDENT staging for the SPMD mesh serving path
         (parallel/meshgrid.py): the composition of the device grid with
         the shard-axis mesh (VERDICT r2 #1).  Returns a MeshShardPlan
         whose staged arrays live on this shard's pinned device — the
         mesh program reads them in place, zero per-query host upload —
         or None to fall back to the host-batch mesh path.
+        ``group_ids``: a group id a series of ``part_ids``, or ONE int
+        where they all fall into one group (an aggregate with no ``by``).
 
         Staging (block concat + row slice) runs once per (range,
-        version) and is memoized by block identity, so a repeat
-        dashboard query performs no device work here at all."""
+        version) and is memoized by block identity, and the
+        MeshShardPlan is kept with its grid plan, one a grouping: a
+        repeat dashboard query performs no device work here, makes no
+        array as wide as its lanes, and is handed the SAME object (the
+        fabric's memos key on it).  Only where one is BUILT does the
+        ``mesh.plan_build`` stage open, outside the grid lock."""
         if func not in _GRID_OPS:
             return None
         if self.hist and func not in _HIST_GRID_FNS:
@@ -997,6 +1064,11 @@ class DeviceGridCache:
         op = _GRID_OPS[func]
         if op in _REBASE_OPS or len(fargs) != _ARG_OPS.get(op, 0):
             return None
+        if isinstance(group_ids, (int, np.integer)):
+            grouping = int(group_ids)
+        else:
+            group_ids = np.asarray(group_ids, dtype=np.int32)
+            grouping = (len(group_ids), hash(group_ids.tobytes()))
         waited = TRACER.stage("grid.lock_wait", leaf=False).begin()
         with self._lock:
             waited.end()
@@ -1030,30 +1102,46 @@ class DeviceGridCache:
                 LEDGER.track(val_st, owner=self.owner, fmt="mesh-staged")
                 if len(self._mesh_stage_memo) > 4:
                     self._mesh_stage_memo.clear()
+                    # ... and with them the shard plans made of them: a
+                    # kept plan must not pin a plane this memo let go
+                    for kept_plan in self._plan_memo.values():
+                        kept_plan.mesh.clear()
                 # hold the block refs: id() stays unambiguous while the
                 # memo entry lives
                 self._mesh_stage_memo[key] = (parts_id, ts_st, val_st,
                                               plan.segs)
-            # -1 = unrequested lane; serve_grid_mesh rewrites it to the
-            # query's drop bucket (num_groups isn't final until every
-            # shard's group ids are assigned)
-            garr = np.full(plan.ncols, -1, dtype=np.int32)
-            gid_arr = np.asarray(group_ids, dtype=np.int32)
-            col_pids = None
-            if self.hist:
-                hb = self.hb
-                hist_slot_garr(garr, plan.lane_idx, gid_arr, hb)
-                tops = np.asarray(self.bucket_tops)
-            else:
-                garr[plan.lane_idx] = gid_arr
-                hb, tops = 0, None
-                col_pids = np.full(plan.ncols, -1, dtype=np.int64)
-                col_pids[plan.lane_idx] = np.asarray(part_ids,
-                                                     dtype=np.int64)
-            return MeshShardPlan(ts_st, val_st, plan.phase, garr, plan.q,
-                                 plan.steps0_rel, plan.ncols,
-                                 self._shard.grid_device, hb=hb,
-                                 bucket_tops=tops, col_pids=col_pids)
+            def kept():
+                # ... of the planes staged NOW: one made of planes the
+                # stage memo has since let go is made again
+                got = plan.mesh.get(grouping)
+                return got if got is not None and got.ts is ts_st \
+                    and got.vals is val_st else None
+            if kept() is not None:
+                return kept()
+            why = "rows" if plan.mesh else "plan"
+            hb = self.hb if self.hist else 0
+            tops = np.asarray(self.bucket_tops) if self.hist else None
+            device = self._shard.grid_device
+        # what is made a (plan, grouping) is made OUTSIDE the grid lock:
+        # the lock guards the blocks and the plan, not a query's own
+        # rows (``hb``, ``tops`` and the device were read under it)
+        with TRACER.stage("mesh.plan_build", why=why,
+                          lanes_requested=len(plan.lane_idx)):
+            cols, slots, order = _mesh_lanes(plan, group_ids, hb)
+            built = MeshShardPlan(
+                ts_st, val_st, plan.phase, cols, slots,
+                (len(cols), hash(cols.tobytes()), hash(slots.tobytes())),
+                plan.q, plan.steps0_rel, plan.ncols, device, hb=hb,
+                bucket_tops=tops, part_ids=part_ids, order=order)
+        with self._lock:
+            if kept() is not None:
+                return kept()          # another worker built it meanwhile
+            if len(plan.mesh) >= 4:
+                # a dashboard groups one selection a few ways; past that
+                # the oldest grouping is made again when next asked
+                del plan.mesh[next(iter(plan.mesh))]
+            plan.mesh[grouping] = built
+        return built
 
     def _plan_staged(self, part_ids, func, steps0, nsteps, step_ms,  # holds-lock: _lock
                      window_ms, fargs):
@@ -1220,10 +1308,21 @@ class DeviceGridCache:
         # unambiguous for the entry's lifetime (no address reuse)
         prep = {"epoch": epoch, "fp": fp, "obj": part_ids, "ids": ids,
                 "lane_idx": lane_idx}
-        if len(self._preps) > 16:
-            self._preps.clear()
-        self._preps[key] = prep
+        self._preps.put(key, prep, n)
         return prep
+
+    def _fingerprint_of(self, part_ids) -> int:
+        """``_ids_fingerprint`` of a lookup result, hashed once for the
+        object: a result the shard's lookup cache hands out is read-only
+        and its prep keeps it alive, so the prep's ``fp`` IS this
+        array's and a repeated request reaches the plan memo without a
+        call over every id.  Anything else (a list, a writable array: an
+        ODP lookup, a test's own ids) is hashed as before."""
+        if isinstance(part_ids, np.ndarray) and not part_ids.flags.writeable:
+            prep = self._preps.get(id(part_ids))
+            if prep is not None and prep["obj"] is part_ids:
+                return prep["fp"]
+        return _ids_fingerprint(part_ids)
 
     def _plan_locked(self, part_ids, func, steps0, nsteps, step_ms,
                      window_ms, fargs=()):
@@ -1272,14 +1371,16 @@ class DeviceGridCache:
         if not supports_grid(window_ms, step_ms, g, nsteps,
                              max_k=max_k_for(_GRID_OPS[func], dense=True)):
             return None
-        ids_fp = _ids_fingerprint(part_ids)
+        ids_fp = self._fingerprint_of(part_ids)
         deny_key = (func, window_ms, step_ms, ids_fp)
         if self._bigk_deny.get(deny_key) == \
                 (self.version, shard.ingest_epoch):
             return None     # dense proof failed for this shape; data unchanged
-        pkey = (func, steps0, nsteps, step_ms, window_ms, fargs, ids_fp,
-                self.version, shard.ingest_epoch, shard.removal_epoch)
-        cached = self._plan_memo.get(pkey)
+        shape = (func, steps0, nsteps, step_ms, window_ms, fargs, ids_fp)
+        # the epochs are read once, before the plan: one that moves while
+        # the plan is made leaves it under a key nothing asks for again
+        epochs = (shard.ingest_epoch, shard.removal_epoch)
+        cached = self._plan_memo.get((*shape, self.version, *epochs))
         if cached is not None:
             self._seq += 1
             for blk in cached.segs:
@@ -1487,10 +1588,11 @@ class DeviceGridCache:
                          packed_use_phase=packed_phase,
                          packed_inv=packed_inv,
                          hbm_dense=hbm_dense, hbm_comp=hbm_comp,
-                         hbm_comp_hist=hbm_hist)
-        if len(self._plan_memo) > 8:
-            self._plan_memo.clear()
-        self._plan_memo[pkey] = plan
+                         hbm_comp_hist=hbm_hist, mesh={})
+        # ``version`` as it stands NOW: a block this plan built bumped it
+        # (under the lock this plan holds), and the next request asks
+        # under the new one
+        self._plan_memo.put((*shape, self.version, *epochs), plan, len(req))
         return plan
 
     def _phase_device(self, ph_req, req, ncols: int, key) -> object:  # holds-lock: _lock

@@ -38,6 +38,7 @@ from filodb_tpu.memstore.partition import TimeSeriesPartition
 from filodb_tpu.store.columnstore import ColumnStore, NullColumnStore, PartKeyRecord
 from filodb_tpu.store.metastore import InMemoryMetaStore, MetaStore
 from filodb_tpu.utils.bloom import BloomFilter
+from filodb_tpu.utils.costmemo import CostMemo
 from filodb_tpu.utils.observability import TRACER
 from filodb_tpu.workload.quota import SeriesQuotaExceeded
 
@@ -129,7 +130,10 @@ class TimeSeriesShard:
         self.store = column_store or NullColumnStore()
         self.meta = meta_store or InMemoryMetaStore()
         self.index = PartKeyIndex()
-        self._lookup_cache: dict = {}
+        # lookup results by (filters, range, index state), each priced by
+        # the ids it holds: a workspace-wide result (a Python walk over
+        # every id to make again) outlives the namespaces' turnover
+        self._lookup_cache = CostMemo(64)
         # bumped whenever a partition leaves the in-memory map (evict /
         # purge): lets the device grid cache skip re-validating every
         # requested pid per query (20k dict walks otherwise dominate
@@ -823,9 +827,12 @@ class TimeSeriesShard:
             return cached
         result = self._lookup_partitions_uncached(filters, start_time,
                                                   end_time, limit)
-        if len(self._lookup_cache) > 64:
-            self._lookup_cache.clear()
-        self._lookup_cache[key] = result
+        # handed out by identity to every later request and never
+        # mutated: what is derived from it (devicestore's lane
+        # resolution, its content fingerprint, the fabric's rows) is
+        # kept by that identity, and read-only is how they can tell
+        result.part_ids.setflags(write=False)
+        self._lookup_cache.put(key, result, len(result.part_ids) + 1)
         return result
 
     def _lookup_partitions_uncached(self, filters, start_time, end_time,
@@ -1009,9 +1016,10 @@ class TimeSeriesShard:
 
     def mesh_grid_plan(self, part_ids: Sequence[int], func, steps0: int,
                        nsteps: int, step_ms: int, window_ms: int,
-                       group_ids: Sequence[int], fargs: tuple = ()):
+                       group_ids, fargs: tuple = ()):
         """Device-resident staging for the SPMD mesh serving path
-        (devicestore.mesh_plan); None -> host-batch mesh fallback."""
+        (devicestore.mesh_plan; ``group_ids`` one id a series, or one
+        int for them all); None -> host-batch mesh fallback."""
         got = self._grid_resolve(part_ids, None)
         if got is None:
             return None

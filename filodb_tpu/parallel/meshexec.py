@@ -37,10 +37,11 @@ from filodb_tpu.query.model import QueryContext
 from filodb_tpu.utils.observability import TRACER
 
 # the steps of one fabric request (doc/observability.md "Stage spans"):
-# a request the fabric served carries all five, 0.0 for a step its rung
-# skipped; ``mesh.stage`` and ``mesh.assemble`` only where a memo missed
-MESH_STAGES = ("mesh.collect", "mesh.dispatch", "mesh.device_wait",
-               "mesh.readback", "mesh.present")
+# a request the fabric served carries all six, 0.0 for a step its rung
+# skipped; ``mesh.plan_build``, ``mesh.stage`` and ``mesh.assemble`` only
+# where a memo missed
+MESH_STAGES = ("mesh.collect", "mesh.prepare", "mesh.dispatch",
+               "mesh.device_wait", "mesh.readback", "mesh.present")
 
 # aggregates with a distributive psum/pmin/pmax form (mesh.partial_state_names)
 MESH_OPS = (AggregationOperator.SUM, AggregationOperator.COUNT,
@@ -455,8 +456,8 @@ class MeshAggregateExec(ExecPlan):
     def _resolve_k_lanes(self, state: dict, plans, planned) -> list[dict]:
         """Map the resident k-slot program's GLOBAL lane indices back to
         series tags: sidx value g decodes to (mesh slot g // lmax, lane
-        g % lmax); the slot's MeshShardPlan carries the lane -> partition
-        id map (col_pids), and the slot's shard resolves tags.  The state
+        g % lmax); the slot's MeshShardPlan knows which partition a lane
+        was asked for (pid_of_lane), and the slot's shard resolves tags.  The state
         is rewritten in place to compact indices into the returned
         series-key list (the AggPartialBatch contract the host k-path
         uses).  Unresolvable lanes (partition concurrently evicted) are
@@ -475,12 +476,11 @@ class MeshAggregateExec(ExecPlan):
             if pi >= 0:
                 plan = plans[pi]
                 shard = planned[pi][0]
-                if plan.col_pids is not None and lane < len(plan.col_pids):
-                    pid = int(plan.col_pids[lane])
-                    if pid >= 0:
-                        part = shard.grid_partition(pid)
-                        if part is not None:
-                            tags = part.tags
+                pid = plan.pid_of_lane(lane)
+                if pid >= 0:
+                    part = shard.grid_partition(pid)
+                    if part is not None:
+                        tags = part.tags
             if tags is None:
                 remap[g] = -1
                 continue
@@ -508,14 +508,13 @@ class MeshAggregateExec(ExecPlan):
 
     def _grid_group_ids(self, shard, part_ids, union: dict):
         """Group ids for the resident grid path, in ``part_ids`` order
-        (the order devicestore assigns lanes).  Grows ``union`` in
-        place; returns None when a partition vanished mid-query (the
-        host path re-resolves via scan_batch)."""
-        n = len(part_ids)
-        gids = np.empty(n, dtype=np.int32)
+        (the order devicestore assigns lanes); with no grouping the ONE
+        id every series shares, as an int, and no array as long as the
+        lookup.  Grows ``union`` in place; returns None when a partition
+        vanished mid-query (the host path re-resolves via scan_batch)."""
         if not self.by and not self.without:
-            gids[:] = union.setdefault((), len(union))
-            return gids
+            return union.setdefault((), len(union))
+        gids = np.empty(len(part_ids), dtype=np.int32)
         for i, pid in enumerate(part_ids):
             part = shard.grid_partition(int(pid))
             if part is None:

@@ -598,10 +598,6 @@ def _pad_fn():
     return pad
 
 
-def _garr_fp(garr: np.ndarray) -> int:
-    return hash(garr.tobytes())
-
-
 def _compose(plans: Sequence, operator: Agg):
     """Validate that the per-shard plans run under ONE program signature.
     Returns (q, mode) or None to fall back."""
@@ -686,39 +682,31 @@ class _Prepared(NamedTuple):
     lmax: int
     Kp: int
     by_dev: list
-    multiproc: bool
     planes: tuple          # (g_ts, g_vals, g_ph, g_s0): what is resident
-    rows: object           # builds a query's [Kp, lmax] int32 row
+    row: object            # the query's own [Kp, width] int32 row: which
+    #                        group each lane reduces into, or (``width``
+    #                        set) which lanes an exact quantile gathers;
+    #                        None for count_values, which reduces nothing
+    width: Optional[int] = None
 
     @property
     def arrays(self) -> tuple:
-        """(g_ts, g_vals, g_ph, g_s0, g_garr): the grouped programs'
-        operands, the query's group row last."""
-        return (*self.planes, self.rows(
-            ("garr", self.groups_total),
-            _garr_row(self.groups_total, self.lmax)))
+        """(g_ts, g_vals, g_ph, g_s0, g_row): the programs' operands,
+        the query's own row last."""
+        return (*self.planes, self.row)
 
 
-def _garr_row(groups_total: int, lmax: int):
-    """A shard slice's lane -> group row: -1 marks unrequested lanes
-    (devicestore.mesh_plan); rewritten to THIS query's drop bucket,
-    where the filler slices' and the pad's lanes go too."""
-    def row(garr: np.ndarray) -> np.ndarray:
-        g = np.full(lmax, groups_total, np.int32)
-        g[:len(garr)] = np.where(garr < 0, groups_total, garr)
-        return g
-    return row
+def _group_row(row: np.ndarray, plan) -> None:
+    """A shard slice's lane -> group row from the plan's (column, group
+    slot) pairs; what the query did not ask for keeps the row's fill,
+    THIS query's drop bucket (the filler slices and the pad too)."""
+    row[plan.cols] = plan.slots
 
 
-def _sel_row(width: int):
+def _member_row(row: np.ndarray, plan) -> None:
     """A shard slice's member row for the exact quantile: the lanes the
-    query selected, in lane order, -1 beyond them."""
-    def row(garr: np.ndarray) -> np.ndarray:
-        lanes = np.flatnonzero(garr >= 0)
-        sel = np.full(width, -1, np.int32)
-        sel[:len(lanes)] = lanes
-        return sel
-    return row
+    plan's query selected, in lane order; -1, the fill, beyond them."""
+    row[:len(plan.cols)] = plan.cols
 
 
 def _pieces(kind: tuple, mine: list, by_dev: list, made_of, build) -> tuple:
@@ -764,9 +752,17 @@ def _prepare(engine, plans: Sequence, num_groups: int,
              operator: Agg) -> Optional[_Prepared]:
     """Compose + place + assemble one fabric query: validates the plans
     share one program signature, groups them by resident device, and
-    assembles (or memo-recalls) the global input arrays.  Returns None
-    to fall back; shared by the partial and fully-fused serve paths so
-    an op switch on the same residents re-uses the assembly."""
+    assembles (or memo-recalls) the global input arrays and the query's
+    own row.  Returns None to fall back; shared by the partial and
+    fully-fused serve paths so an op switch on the same residents
+    re-uses the assembly.  The ``mesh.prepare`` stage (tag ``rows``:
+    ``memo``, ``built``, or ``none`` where nothing is reduced)."""
+    with TRACER.stage("mesh.prepare", shards=len(plans)) as sp:
+        return _prepare_staged(sp, engine, plans, num_groups, operator)
+
+
+def _prepare_staged(sp, engine, plans: Sequence, num_groups: int,
+                    operator: Agg) -> Optional[_Prepared]:
     jax, jnp = _jax()
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -836,7 +832,9 @@ def _prepare(engine, plans: Sequence, num_groups: int,
     # that process stages its own pieces
     mine = [(d, dev) for d, dev in enumerate(devices)
             if not multiproc or dev.process_index == proc]
-    slots = [(d, p) for d, _dev in mine for p in by_dev[d]]
+    # (the slice of the [Kp, ...] operands a plan's shard is, the plan)
+    slices = [(d * ksub + kk, p) for d, _dev in mine
+              for kk, p in enumerate(by_dev[d])]
 
     def assemble(pieces):
         """The global array of a piece a device (wrapped, not copied)."""
@@ -900,29 +898,45 @@ def _prepare(engine, plans: Sequence, num_groups: int,
                           for i in range(3))
     g_ph = assemble([pc[0] for pc in phases])
 
-    def rows(kind: tuple, row_of):
-        """The query's own [Kp, width] int32 row, a slice a shard plan
-        (``row_of(plan.garr)``; a filler slice is a plan that asked for
-        no lane); memoized on the lanes asked, beside the planes and
-        never with them."""
+    def rows(kind: tuple, width: int, fill: int, fill_row):
+        """The query's own [Kp, width] int32 row: ONE host array, a slice
+        a shard plan filled from its (column, slot) pairs
+        (``fill_row(row, plan)``; a filler slice keeps ``fill``), put on
+        the chips in one batched call; memoized on the lanes asked (each plan's
+        own fingerprint, made with the plan: no row is hashed here),
+        beside the planes and never with them."""
         key = (engine._key, kind, lmax, ksub,
-               tuple((d, _garr_fp(p.garr)) for d, p in slots))
+               tuple((k, p.rows_fp) for k, p in slices))
         hit = _memo_get(_ROWS_MEMO, key)
+        sp.tag(rows="memo" if hit is not None else "built")
         if hit is not None:
             return hit[0]
-        nothing = np.empty(0, np.int32)
-        arr = assemble([
-            _stage_put(np.stack(
-                [row_of(p.garr) for p in by_dev[d]]
-                + [row_of(nothing)] * (ksub - len(by_dev[d]))), dev)
-            for d, dev in mine])
+        host = np.full((Kp, width), fill, np.int32)
+        for k, p in slices:
+            fill_row(host[k], p)
+        # every process hands over the slices of its own devices (the
+        # others' it never filled): one batched put, not one a device
+        arr = jax.make_array_from_callback(
+            host.shape, NamedSharding(mesh, P(_AXES, None)),
+            host.__getitem__)
+        LEDGER.track(arr, owner="meshgrid:assembly", fmt="mesh-staged")
         _memo_insert(_ROWS_MEMO, key, (arr,), int(arr.nbytes),
                      _ROWS_MEMO_CAP)
         return arr
 
+    width = None
+    if op == "values":
+        sp.tag(rows="none")
+        row = None
+    else:
+        if op == "quantile" and not multiproc and stride == 1:
+            width = _exact_width(plans, lmax)
+        row = rows(("garr", groups_total), lmax, groups_total,
+                   _group_row) if width is None \
+            else rows(("sel", width), width, -1, _member_row)
     return _Prepared(q, mode, op, stride, groups_total, ksub, nrows,
-                     lmax, Kp, by_dev, multiproc,
-                     (g_ts, g_vals, g_ph, g_s0), rows)
+                     lmax, Kp, by_dev, (g_ts, g_vals, g_ph, g_s0), row,
+                     width)
 
 
 def _fetch(out, dtype=np.float64) -> np.ndarray:
@@ -939,26 +953,24 @@ def _fetch(out, dtype=np.float64) -> np.ndarray:
     return host
 
 
-def _exact_width(prep: _Prepared, plans: Sequence) -> Optional[int]:
+def _exact_width(plans: Sequence, lmax: int) -> Optional[int]:
     """How many lanes a slice gathers for the EXACT quantile, or None
     where the sketch serves: the largest group's member count over all
-    shards (known here, before the launch: every plan's group row) is
+    shards (known here, before the launch: every plan's group slots) is
     at most ``QuantileAggregator.exact_members``, the one number that
-    separates exact from sketch on the per-shard rung too.  Across
+    separates exact from sketch on the per-shard rung too.  (Across
     processes a group's members on the other hosts cannot be counted
-    here: the sketch, whose partials merge over the wire."""
+    here, and the caller asks for the sketch, whose partials merge over
+    the wire.)"""
     from filodb_tpu.query.aggregators import QuantileAggregator
-    if prep.multiproc or prep.stride > 1:
-        return None
-    asked = [p.garr[p.garr >= 0] for p in plans]
-    counts = np.bincount(np.concatenate(asked))
+    counts = np.bincount(np.concatenate([p.slots for p in plans]))
     if counts.max(initial=0) > QuantileAggregator.exact_members:
         return None
-    widest = max(len(a) for a in asked)
+    widest = max(len(p.slots) for p in plans)
     width = QuantileAggregator.exact_members
     while width < widest:          # a program a power of two, not a count
         width *= 2
-    return min(width, prep.lmax)
+    return min(width, lmax)
 
 
 def serve_grid_mesh(engine, plans: Sequence, num_groups: int,
@@ -997,20 +1009,17 @@ def serve_grid_mesh(engine, plans: Sequence, num_groups: int,
     if op == "quantile":
         from filodb_tpu.query.aggregators import (QuantileAggregator,
                                                   members_state)
-        width = _exact_width(prep, plans)
-        if width is not None:
+        if prep.width is not None:
             prog = _grid_mesh_members_program(engine._key, q, mode, ksub,
-                                              nrows, lmax, width)
-            out = prog(*prep.planes,
-                       prep.rows(("sel", width), _sel_row(width)))
+                                              nrows, lmax, prep.width)
+            out = prog(*prep.arrays)
             STATS["serves"] += 1
             picked = _fetch(out)  # host-sync-ok: the selected members [Kp, T, width], never every lane
             vals, gids = [], []
             for d, lst in enumerate(by_dev):
                 for kk, p in enumerate(lst):
-                    asked = p.garr[p.garr >= 0]
-                    vals.append(picked[d * ksub + kk, :, :len(asked)].T)
-                    gids.append(asked)
+                    vals.append(picked[d * ksub + kk, :, :len(p.slots)].T)
+                    gids.append(p.slots)
             return members_state(np.concatenate(vals),
                                  np.concatenate(gids), num_groups)
         # same compression as the host QuantileAggregator: mesh and host
@@ -1034,7 +1043,7 @@ def serve_grid_mesh(engine, plans: Sequence, num_groups: int,
         garr_all = np.full((Kp, lmax), -1, np.int32)
         for d, lst in enumerate(by_dev):
             for kk, p in enumerate(lst):
-                garr_all[d * ksub + kk, :len(p.garr)] = p.garr
+                garr_all[d * ksub + kk, p.cols] = p.slots
         rows = garr_all.ravel() >= 0
         vals2d = stepped.reshape(Kp * lmax, -1)[rows]
         return count_values_state(vals2d, garr_all.ravel()[rows],

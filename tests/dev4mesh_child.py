@@ -48,6 +48,13 @@ def launches(port: int) -> dict:
     return out
 
 
+def plan_builds(port: int) -> int:
+    """How many ``mesh.plan_build`` spans have finished: the shard plans
+    the fabric BUILT (a reuse opens none)."""
+    stages = json.loads(get(port, "/admin/device"))["data"]["stages"]
+    return stages.get("mesh.plan_build", {}).get("count", 0)
+
+
 def launched(before: dict, after: dict) -> dict:
     return {k: v - before.get(k, 0.0) for k, v in after.items()
             if v != before.get(k, 0.0)}
@@ -118,8 +125,9 @@ def main() -> dict:
             plan = binding.planner.materialize(
                 query_range_to_logical_plan(query, start, step, end),
                 QueryContext())
+            cold = plan_builds(port)
             ask(pi, ns)                  # the programs compile here
-            before = launches(port)
+            before, built = launches(port), plan_builds(port)
             got, stats = ask(pi, ns)
             if first_stages is None:
                 first_stages = sorted(json.loads(get(
@@ -130,6 +138,7 @@ def main() -> dict:
                 "gap": g, "root": type(plan).__name__,
                 "shards": sorted(plan.shards),
                 "launched": launched(before, launches(port)),
+                "built": [built - cold, plan_builds(port) - built],
                 "rung": rung_of(port, stats)}
 
         # the boundary: 129 members, the namespace and one instance of its

@@ -152,10 +152,11 @@ def test_every_program_of_a_family_is_named_for_it(family):
 
 @pytest.mark.parametrize("span", [
     "mesh.collect", "mesh.stage", "mesh.assemble", "mesh.dispatch",
-    "mesh.device_wait", "mesh.readback", "mesh.present"])
+    "mesh.device_wait", "mesh.readback", "mesh.present",
+    "mesh.plan_build", "mesh.prepare"])
 def test_fabric_stage_span_is_emitted(span, emitted):
-    """The mesh fabric's stage spans (PR 34; doc/observability.md "Stage
-    spans"), read by the ``mesh_*`` metrics of ``dev4.mesh-wide``."""
+    """The mesh fabric's stage spans (PR 34, PR 35; doc/observability.md
+    "Stage spans"), read by the ``mesh_*`` metrics of ``dev4.mesh-wide``."""
     assert span in emitted
 
 

@@ -31,7 +31,7 @@ TRAFFIC = json.loads((ROOT / "benchmark" / "traffic"
                       / "hicard-wide.json").read_text())
 PANELS = [p["name"] for p in TRAFFIC["panels"]]
 CHILD_TIMEOUT_S = 240        # ~20 s here; the suite's own limit is far off
-SPANS = MESH_STAGES + ("mesh.stage", "mesh.assemble")
+SPANS = MESH_STAGES + ("mesh.plan_build", "mesh.stage", "mesh.assemble")
 
 
 @pytest.fixture(scope="module")
@@ -83,6 +83,16 @@ def test_the_root_is_the_fabric_and_a_request_is_one_launch(report, name):
     assert got["launched"] == {program: 1.0}
     assert got["rung"] == ("partial" if "quantile" in name else "fused")
     assert report["fallbacks"] == 0
+
+
+@pytest.mark.parametrize("name", PANELS)
+def test_a_panel_asked_again_builds_no_shard_plan(report, name):
+    """Through the server: the first request of a panel builds a shard
+    plan a shard it selects on (the wide sums share the staging panel's
+    lookup, not its plans), the same panel asked again builds none."""
+    first, again = report["panels"][name]["built"]
+    assert first == (4 if name.startswith("wide") else 2)
+    assert again == 0
 
 
 # ------------------------------------------------------ (c) the cost model
