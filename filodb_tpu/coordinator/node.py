@@ -13,6 +13,7 @@ event hub instead of an Akka event stream.
 from __future__ import annotations
 
 import threading
+import time
 import traceback
 from typing import Callable, Optional, Sequence
 
@@ -23,6 +24,7 @@ from filodb_tpu.core.schemas import Schemas
 from filodb_tpu.core.storeconfig import StoreConfig
 from filodb_tpu.ingest.stream import IngestionStreamFactory
 from filodb_tpu.memstore.memstore import TimeSeriesMemStore
+from filodb_tpu.utils.observability import TRACER
 
 
 class IngestionCoordinator:
@@ -227,7 +229,15 @@ class IngestionCoordinator:
             # sentinel is always consumed (no stale sentinel for the next
             # consumer of a shared stream).
             for offset, container in stream.get():
-                sh.ingest_container(container, offset)
+                t_in = getattr(stream, "last_arrived", None)
+                samples = sh.ingest_container(container, offset)
+                if t_in is not None:
+                    # from the container's arrival at the edge to the
+                    # epoch bump that made its rows readable: the wall
+                    # is the visibility lag (a wait and the work above)
+                    TRACER.record("ingest.visible", time.time() - t_in,
+                                  start_s=t_in, stage=True,
+                                  samples=samples)
                 if flush_sched is not None:
                     flush_sched.note_ingested()
                 if recovering:

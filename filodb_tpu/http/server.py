@@ -94,6 +94,18 @@ def _parse_downsample(v) -> int:
     return px
 
 
+class _Listener(ThreadingHTTPServer):
+    """The stdlib server with a listen queue a node can live with.  The
+    stdlib asks for 5: with more clients than that connecting while the
+    accept thread is away (a collection stops it half a second; a
+    dashboard's panels refresh together) the kernel drops the SYNs over
+    the queue and each such client waits out a retransmit, 1 s and then
+    3, before its request is even read — nine simultaneous connections
+    (eight sessions and a writer) lose three that way."""
+
+    request_queue_size = 128
+
+
 @dataclass
 class DatasetBinding:
     """Everything the HTTP layer needs to serve one dataset."""
@@ -207,7 +219,7 @@ class FiloHttpServer:
             def do_POST(self):
                 server._handle(self, "POST")
 
-        self._httpd = ThreadingHTTPServer((self.host, self.port), Handler)
+        self._httpd = _Listener((self.host, self.port), Handler)
         self.port = self._httpd.server_address[1]
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         name="filo-http", daemon=True)

@@ -10,8 +10,10 @@ factory is resolved by name from the ingestion config's ``sourcefactory``
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
+import time
 from typing import Callable, Iterable, Iterator, Optional
 
 # A stream element is (offset, container_bytes) — offsets are the
@@ -79,6 +81,12 @@ class QueueStream(IngestionStream):
         self._next_offset = start_offset
         self._lock = threading.Lock()
         self._close_pending = False
+        # when the element ``get`` last yielded was pushed
+        # (``time.time()``): the edge's 200 follows the push, so this is
+        # where a container's visibility lag starts.  ``_arrivals`` holds
+        # (offset, time) of what is still queued, oldest first
+        self.last_arrived: Optional[float] = None
+        self._arrivals: collections.deque = collections.deque()
 
     def push(self, container: bytes) -> int:
         # assign AND enqueue under the lock: out-of-order offsets would turn
@@ -86,6 +94,7 @@ class QueueStream(IngestionStream):
         with self._lock:
             off = self._next_offset
             self._next_offset += 1
+            self._arrivals.append((off, time.time()))
             self._q.put((off, container))
         return off
 
@@ -119,6 +128,11 @@ class QueueStream(IngestionStream):
                 with self._lock:
                     self._close_pending = False
                 return
+            arrivals, self.last_arrived = self._arrivals, None
+            while arrivals and arrivals[0][0] <= item[0]:
+                at, when = arrivals.popleft()
+                if at == item[0]:
+                    self.last_arrived = when
             yield item
 
     def teardown(self) -> None:

@@ -25,8 +25,10 @@ directly instead of re-uploading numpy per query:
   like the reference's time-ordered block lists.
 - Chunk freezes invalidate overlapping blocks (the shard wires
   ``partition.on_freeze`` to :meth:`note_freeze`); the mutable write-buffer
-  tail is served through a version-tagged tail block rebuilt only when new
-  data arrived.
+  rows are served from an OPEN block: dense planes that live on the device
+  and are appended to as containers arrive (``partition.on_append`` ->
+  :meth:`note_append` -> ``devicestore.tail_append``), never rebuilt for an
+  ingest epoch.
 
 The grid layout contract matches :mod:`filodb_tpu.ops.grid`: row ``c``
 holds the (single) sample with ``ts in (epoch0+(c-1)*gstep, epoch0+c*gstep]``.
@@ -38,6 +40,7 @@ absent.
 
 from __future__ import annotations
 
+import collections
 import threading
 from typing import NamedTuple, Optional, Sequence
 
@@ -56,6 +59,7 @@ from filodb_tpu.utils.observability import TRACER
 
 BLOCK_BUCKETS = 128
 _I32_SPAN = 2**31 - 2
+_NO_ROW_TS = -2**62     # an open block's lane that holds no row yet
 
 # range functions the aligned grid can serve, mapped to the fused
 # kernel op (ops/grid.py GridQuery.op); None = the bare instant
@@ -640,6 +644,79 @@ def _mesh_stage(ts_parts, val_parts: tuple, row0: int, nrows: int):
     return _MESH_STAGE_FN(ts_parts, val_parts, row0, nrows=nrows)
 
 
+# cells one launch of the append program writes: ONE shape whatever a
+# container held, so that no append compiles (a container a second of a
+# 102 400-series shard scraped every 15 s is 6 827 cells; more go in turns)
+APPEND_CELLS = 8192
+_TAIL_APPEND_FN = None
+
+
+def _tail_append(ts_plane, val_plane, idx, vals):
+    """An open block's planes with ``APPEND_CELLS`` cells written:
+    ``idx`` ``[3, APPEND_CELLS]`` int32 holds each cell's row, column
+    and epoch-relative timestamp, ``vals`` its value; a row index of
+    ``BLOCK_BUCKETS`` pads (dropped).  The planes are NOT donated: a plan
+    that was handed them dispatches outside the grid lock and may still
+    be reading them, so the write lands in a copy made on the device
+    (HBM to HBM) and only the cells cross the host link.  A helper: it
+    answers no request (like ``devicestore.mesh_stage``)."""
+    global _TAIL_APPEND_FN
+    if _TAIL_APPEND_FN is None:
+        import functools
+
+        @functools.partial(devicewatch.jit,
+                           program="devicestore.tail_append")
+        def append(ts_plane, val_plane, idx, vals):
+            rows, cols = idx[0], idx[1]
+            return (ts_plane.at[rows, cols].set(idx[2], mode="drop"),
+                    val_plane.at[rows, cols].set(vals, mode="drop"))
+        _TAIL_APPEND_FN = append
+    return _TAIL_APPEND_FN(ts_plane, val_plane, idx, vals)
+
+
+def _rehearse_call(call: tuple, stack) -> None:
+    """Launch one remembered program call over an open block's planes so
+    that it is compiled (``DeviceGridCache._rehearse``): the solo program
+    where ``stack`` is None, else its stacked form at that stack size.
+    The arguments mirror the serving calls' to the letter (a ``None``
+    given by position and one left to its default are two programs), the
+    ``grid.dispatch`` stage is left out (a compile is no dispatch), and
+    the result is dropped."""
+    (kind, ts_parts, val_parts, row0, steps0, kw, num_groups, op,
+     width) = call
+    try:
+        import jax
+        extra, kw = (), dict(kw)
+        if kind == "grouped":
+            extra = (np.full(width, num_groups, dtype=np.int32),)
+            kw.update(num_groups=num_groups, op=op)
+        if stack is None:
+            prog = _fused_progs()[kind].__wrapped__
+        else:
+            prog = _fused_progs()[kind + "_batch"].__wrapped__
+            row0, steps0 = np.full(stack, row0), np.full(stack, steps0)
+        jax.block_until_ready(prog(ts_parts, val_parts, row0, steps0,
+                                   *extra, None, **kw))
+    except Exception:  # noqa: BLE001 — the request compiles instead
+        import logging
+        logging.getLogger(__name__).exception(
+            "rehearsing %s for an open block failed", kind)
+
+
+_REHEARSERS = None
+
+
+def _rehearsers():
+    """The threads that compile an open block's programs before a request
+    needs them.  A ``concurrent.futures`` pool: the interpreter joins its
+    threads at exit, so none is inside the compiler when it goes."""
+    global _REHEARSERS
+    if _REHEARSERS is None:
+        from concurrent.futures import ThreadPoolExecutor
+        _REHEARSERS = ThreadPoolExecutor(4, thread_name_prefix="grid-rehearse")
+    return _REHEARSERS
+
+
 def _ids_fingerprint(part_ids) -> int:
     """Content hash guarding the id()-keyed prep cache against address
     reuse and keying the big-K deny set.  Position-dependent mix over
@@ -670,11 +747,19 @@ class _Block:
     filled cells: a lane with ``pmin == pmax`` in every covered block is
     UNIFORM-PHASE and rate/increase/delta queries reconstruct its
     timestamps from one phase scalar — the ts plane is never streamed
-    (ops/grid.py PHASE_OPS)."""
+    (ops/grid.py PHASE_OPS).
+
+    An OPEN block (``hi_ts`` set: it holds write-buffer rows) keeps dense
+    planes that ``DeviceGridCache._append_cells`` replaces as rows arrive,
+    the fill and phase ranges moving with them; ``hi_ts`` (host int64, per
+    lane) is the newest timestamp staged a lane, so a row that a build
+    read from a write buffer and the ingest hook also queued lands once;
+    ``gen`` counts the appends (what is staged FROM the planes keys on
+    it); ``later`` the rehearsals that wait for a row (``_rehearse``)."""
 
     __slots__ = ("ts", "vals", "lanes", "nbytes", "last_used",
                  "fmin", "fmax", "fcnt", "pmin", "pmax", "staged_hi",
-                 "ts_desc", "width", "pack_inv")
+                 "ts_desc", "width", "pack_inv", "hi_ts", "gen", "later")
 
     def __init__(self, ts, vals, lanes: int, seq: int, fill_stats,
                  phase_stats, staged_hi: int, ts_desc=None,
@@ -698,6 +783,9 @@ class _Block:
         # fused packed kernels run in packed lane order while callers
         # compose their lane indirections host-side.
         self.pack_inv = pack_inv
+        self.hi_ts = None
+        self.gen = 0
+        self.later = ()         # rehearsals due at a later row (_rehearse)
         # lanes < staged_hi were populated at build time; a lane at or
         # beyond it belongs to a partition that joined later and is NOT
         # represented in this block (it must rebuild, never serve NaN)
@@ -746,7 +834,25 @@ class DeviceGridCache:
         self.lane_of: dict[int, int] = {}
         self._next_lane = 0
         self.blocks: dict[int, _Block] = {}
-        self._tails: dict[int, tuple[int, _Block]] = {}  # bi -> (ver, blk)
+        # bi -> the OPEN block: the one that holds write-buffer rows,
+        # resident once and appended to (two while the live edge straddles
+        # a block boundary).  What retired one is kept until it is built
+        # again (``grid.tail_build``'s ``why``)
+        self._open: dict[int, _Block] = {}
+        self._open_retired: dict[int, str] = {}
+        # rows the ingest hook queued (note_append; any thread, no lock)
+        # for the ingest thread's flush_appends: (lane, ts, vals, was the
+        # buffer empty, the shard's newest timestamp before the batch)
+        self._pend: collections.deque = collections.deque()
+        # newest timestamp this cache has staged or been told of: a block
+        # that begins at or after it holds nothing yet (_apply_pending)
+        self._seen_hi = -1
+        # what the served plans' program calls are made of, by shape
+        # (insertion-ordered, the oldest goes): compiled over an open
+        # block when one is created (_rehearse), by futures a request
+        # that gets there first waits for (_await_rehearsals)
+        self._recipes: dict = {}
+        self._rehearsals: list = []
         self.version = 0               # bumped on invalidating freezes
         # quarantine epoch the resident blocks were staged under: a
         # chunk quarantined AFTER staging must stop being served, so a
@@ -781,8 +887,10 @@ class DeviceGridCache:
         # beside a workspace-wide plan of 25 000 and never push it out
         self._plan_memo = CostMemo(8)
         # the last frozen-frontier walk: (state key, earliest buffered
-        # row's timestamp or None) — see _frozen_high
+        # row's timestamp or None) — see _frozen_high; ``_freezes``
+        # counts what can RAISE it (a freeze empties buffers)
         self._frontier: tuple = (None, None)
+        self._freezes = 0
         self._seq = 0
         self._lock = threading.Lock()
         # stats
@@ -791,14 +899,27 @@ class DeviceGridCache:
         self.dense_hits = 0
         self.evictions = 0
         self.frontier_walks = 0
+        self.appends = 0               # launches of the append program
+        self.opened = 0                # open blocks created empty
 
     # ------------------------------------------------------------ bookkeeping
 
     @property
     def bytes_resident(self) -> int:
         n = sum(b.nbytes for b in self.blocks.values())
-        n += sum(blk.nbytes for _v, blk in self._tails.values())
+        n += sum(blk.nbytes for blk in self._open.values())
         return n
+
+    def _drop_open(self, why: str) -> None:  # holds-lock: _lock
+        """Let every open block go (a width that grew, a re-pin, a
+        quarantine, the cache disabled): the next plan that needs one builds it over
+        every lane again.  The plans made of them go too, so that no
+        plane of a block that was let go stays referenced."""
+        for bi in self._open:
+            self._open_retired[bi] = why
+        if self._open:
+            self._open.clear()
+            self._plan_memo.clear()
 
     def note_repin(self) -> None:
         """The shard was pinned to a different mesh device: resident
@@ -806,12 +927,12 @@ class DeviceGridCache:
         old device — drop them so they rebuild in place on the new one
         (shard.pin_grid_device)."""
         with self._lock:
-            n = len(self.blocks) + len(self._tails)
+            n = len(self.blocks) + len(self._open)
             if n:
                 LEDGER.note_eviction(self.owner, "epoch_purge", n=n,
                                      nbytes=self.bytes_resident)
             self.blocks.clear()
-            self._tails.clear()
+            self._drop_open("recovery")
             self._phase_memo.clear()
             self._mesh_stage_memo.clear()
             self._plan_memo.clear()
@@ -819,11 +940,20 @@ class DeviceGridCache:
 
     def note_freeze(self, cs) -> None:
         """A chunk froze: blocks overlapping it are stale (a lagging series
-        back-filled an old bucket), and the tail moved.  (The shard bumps
-        its ``ingest_epoch`` — our tail version — separately.)"""
+        back-filled an old bucket) and buffers emptied (the frozen frontier
+        may have risen: the next plan walks for it).  The open blocks
+        STAY: their cells are the same samples whether a chunk or a write
+        buffer holds them now, and the rows that follow are appended as
+        before; a flush group's freeze costs no every-lane build.  An
+        open block gives way to a frozen, packed one once the frontier
+        has passed its range: no buffer holds a row of it any more
+        (``_block_for``).  (The shard bumps its ``ingest_epoch``
+        separately.)"""
         with self._lock:
-            self._tails.clear()
-            self._plan_memo.clear()       # tail plans reference old epoch
+            self._freezes += 1
+            if self.hist:
+                self._drop_open("recovery")   # bucket planes take no append
+            self._plan_memo.clear()       # plans keyed by the old epoch
             if self.gstep is None or self.epoch0 is None:
                 return
             lo_block = (cs.info.start_time - self.epoch0) // (
@@ -864,12 +994,12 @@ class DeviceGridCache:
         self._disable_count += 1
         backoff = 2 ** min(self._disable_count, 16)
         self.disabled_until_version = self._shard.ingest_epoch + backoff
-        n = len(self.blocks) + len(self._tails)
+        n = len(self.blocks) + len(self._open)
         if n:
             LEDGER.note_eviction(self.owner, "epoch_purge", n=n,
                                  nbytes=self.bytes_resident)
         self.blocks.clear()
-        self._tails.clear()
+        self._drop_open("recovery")
         self._plan_memo.clear()            # plans pin the dropped blocks
         # re-probe the bucket scheme on the next attempt: a widened
         # histogram (16 -> 20 buckets) must not disable the fast path
@@ -904,12 +1034,14 @@ class DeviceGridCache:
             if plan is None:
                 return None
             _note_hbm(plan)
+            self._note_recipe(plan, "series", 0, "")
             tops = np.asarray(self.bucket_tops) if self.hist else None
         # dispatch + readback run OUTSIDE the grid lock (the
         # scan_rate_grouped structure): the plan tuple holds live refs
         # to its device arrays, so a concurrent eviction cannot free
         # them mid-dispatch — and concurrent shape-compatible queries
         # can now rendezvous in the fleet batching tier
+        self._await_rehearsals(plan)
         vals = self._dispatch_series(plan)
         return vals, tops
 
@@ -944,9 +1076,11 @@ class DeviceGridCache:
             stride = self.hb if self.hist else 1
             tops = np.asarray(self.bucket_tops) if self.hist else None
             _note_hbm(plan)
+            self._note_recipe(plan, "grouped", num_groups * stride, op)
         # the full-width group map is built OUTSIDE the grid lock: the
         # plan tuple and the caller's arguments are all it reads
         # (``stride`` and ``tops`` were snapshotted under it)
+        self._await_rehearsals(plan)
         garr = np.full(plan.ncols, num_groups * stride, dtype=np.int32)
         gid_arr = np.asarray(group_ids, dtype=np.int32)
         if stride == 1:
@@ -1084,7 +1218,7 @@ class DeviceGridCache:
             # over one range share ONE staged value plane
             no_ts = plan.phase is not None or op in TS_FREE_OPS
             key = (plan.row0, plan.nrows, no_ts)
-            parts_id = tuple(id(b) for b in plan.segs)
+            parts_id = tuple((id(b), b.gen) for b in plan.segs)
             memo = self._mesh_stage_memo.get(key)
             if memo is not None and memo[0] == parts_id:
                 _, ts_st, val_st, segs_ref = memo
@@ -1152,7 +1286,8 @@ class DeviceGridCache:
         own inside it, the frontier walk once per shard state
         (``grid.frontier``; tag ``frontier``: ``walk`` where this plan
         made it, else ``memo``) and, on a cold range, the builds
-        (``grid.build``).  The wait for the lock is the caller's
+        (``grid.build``; ``grid.tail_build`` for an open block that no
+        append could make).  The wait for the lock is the caller's
         ``grid.lock_wait`` stage: what a worker loses to the other
         workers' plans."""
         with TRACER.stage("grid.plan", cpu=True,
@@ -1342,12 +1477,12 @@ class DeviceGridCache:
             # quarantined chunk's rows — serving them would defeat the
             # exclusion the partition read path applies.  Quarantine is
             # rare; a full re-stage is the correct price.
-            if self._quarantine_epoch >= 0 and (self.blocks or self._tails):
+            if self._quarantine_epoch >= 0 and (self.blocks or self._open):
                 LEDGER.note_eviction(self.owner, "integrity_quarantine",
-                                     n=len(self.blocks) + len(self._tails),
+                                     n=len(self.blocks) + len(self._open),
                                      nbytes=self.bytes_resident)
                 self.blocks.clear()
-                self._tails.clear()
+                self._drop_open("recovery")
                 self._plan_memo.clear()
                 self._phase_memo.clear()
                 self._mesh_stage_memo.clear()
@@ -1438,9 +1573,10 @@ class DeviceGridCache:
             # ... or the widest sibling shard's, where that is close: one
             # width a dataset, so that its shards share their programs
             lanes = shapes.lanes_for(lanes)
-        if any(b.lanes != lanes for b in self.blocks.values()):
+        if any(b.lanes != lanes for b in self.blocks.values()) \
+                or any(b.lanes != lanes for b in self._open.values()):
             self.blocks.clear()                # widths must match to concat
-            self._tails.clear()
+            self._drop_open("width")
             self._plan_memo.clear()            # plans pin old-width blocks
         frozen_hi = self._frozen_high()
         bi_lo = c0 // BLOCK_BUCKETS
@@ -1476,12 +1612,16 @@ class DeviceGridCache:
         op = _GRID_OPS[func]
         # phase proof piggybacks on the dense walk: every requested lane
         # must be uniform-phase within each covered block AND carry the
-        # SAME phase across blocks.  Tail blocks are excluded (their
-        # contents change per ingest epoch; the memoized device phase
-        # vector below would churn) — queries touching the tail keep the
-        # ts-streaming kernels.  Final eligibility is grid.phase_eligible
-        # on the built query (adds dense + K>=2); this is the cheap
-        # pre-filter for the proof walk.
+        # SAME phase across blocks.  Open blocks are excluded: theirs
+        # are the rows still arriving, a lane's first row there (or a
+        # jittered scrape) would change the proof under a memoized device
+        # phase vector keyed by block range and cache version, neither of
+        # which an append moves — so a span that touches an open block
+        # streams the ts planes (the open block's own, kept current by
+        # every append).  Those program shapes are compiled when the
+        # block opens (_rehearse), not by the first request.  Final
+        # eligibility is grid.phase_eligible on the built query (adds
+        # dense + K>=2); this is the cheap pre-filter for the proof walk.
         want_phase = op in PHASE_OPS and K >= 2 and \
             bi_hi * BLOCK_BUCKETS + BLOCK_BUCKETS - 1 <= frozen_hi
         ph_req = np.full(len(req), -1, np.int64)
@@ -1649,25 +1789,29 @@ class DeviceGridCache:
         """Highest bucket (exclusive) fully covered by frozen chunks: the
         earliest write-buffer row across THIS cache's lanes bounds it —
         an unrelated metric's laggy buffer must not demote this cache's
-        recent blocks to per-epoch-rebuilt tail blocks.
+        recent blocks to open blocks.
 
-        The walk over every lane runs once per STATE, not once per plan
-        (``shard.mutable_floor`` is the same idiom): its result stands
-        while nothing that can change a lane's write buffer, or the set
-        of lanes walked, has happened.  ``ingest_epoch`` moves with every
-        ingest batch that added rows and every chunk freeze,
-        ``removal_epoch`` with eviction, purge and page-cache eviction,
-        and the roster with a lane assigned (``_prep_for``) or pruned
-        (``_build_block``); a page-in moves none and need not (a paged
-        partition holds chunks only).  The key is read BEFORE the walk,
-        so a row ingested mid-walk leaves the memo stale, not fresh.  A
-        buffer detached for a pipelined flush (``freeze_raw``) moves no
-        epoch until its chunk freezes: the memo then reads too LOW, which
-        only sends a block down the exact tail path.  Like the tails and
-        the plan memo, it first sees a batch's rows when the batch's
-        epoch bump lands, which is before the batch is acknowledged."""
+        The bound is MAINTAINED, not walked: an append to a buffer that
+        already holds a row cannot move a lane's earliest buffered row, so
+        ingest moves the bound only where a buffer went from empty to
+        non-empty (``note_append`` says so with each row; O(1) a series,
+        folded in by ``_apply_pending``).  The walk over every lane
+        (``grid.frontier``) runs only where the bound may have RISEN or
+        the lanes walked have changed: after a chunk freeze
+        (``_freezes``), a removal (``removal_epoch``: eviction, purge,
+        page-cache eviction) and a lane assigned (``_prep_for``) or
+        pruned (``_build_block``); a page-in moves none and need not (a
+        paged partition holds chunks only).  The key is read BEFORE the
+        walk, so a freeze mid-walk leaves the memo stale, not fresh.  A
+        buffer detached for a pipelined flush (``freeze_raw``) moves
+        nothing until its chunk freezes: the bound then reads too LOW,
+        which only sends a block down the exact open-block path.  The
+        ingest thread folds a batch's rows in itself (``flush_appends``:
+        the bound, and the open blocks' cells) before the epoch bump
+        that makes them readable, also where the batch raised midway: a
+        plan appends nothing, and sees whole batches only."""
         shard = self._shard
-        key = (shard.ingest_epoch, shard.removal_epoch, self._next_lane,
+        key = (self._freezes, shard.removal_epoch, self._next_lane,
                len(self.lane_of))
         memo_key, lo = self._frontier
         if memo_key != key:
@@ -1707,30 +1851,41 @@ class DeviceGridCache:
             # rows land inside it (live ingest after the block was
             # staged), the staged copy is missing them and the dense
             # proof would read the hole as "no samples" — serving a
-            # silently-partial window.  Such ranges take the per-epoch
-            # tail path below; note_freeze drops the stale copy when
-            # the buffer flushes.
+            # silently-partial window.  Such ranges take the open-block
+            # path below; note_freeze drops the stale copy when the
+            # buffer flushes.
             return blk
         if b_hi > frozen_hi:
-            # tail block: includes mutable write-buffer rows; cache under
-            # the shard's ingest epoch so repeat queries skip the rebuild
-            epoch = self._shard.ingest_epoch
-            got = self._tails.get(bi)
-            if got is not None and got[0] == epoch \
-                    and got[1].lanes == lanes \
-                    and got[1].staged_hi >= need_hi:
-                return got[1]
-            # tail blocks rebuild every ingest epoch: the host-side
-            # pack would be pure added latency on the live-ingest path
-            blk = self._build(bi, lanes, compress=False)
+            # open block: includes mutable write-buffer rows.  Resident
+            # once: the rows that arrive are appended to it (by the ingest
+            # thread, before the epoch bump), it is never rebuilt for an
+            # ingest epoch or a freeze, and it is never packed (the pack
+            # would be pure added latency on the live-ingest path).  The
+            # build over every lane is left for what needs it: no open
+            # block yet and rows in the range that the ingest hook did not
+            # bring (the first query over an unflushed shard, a restart),
+            # one let go by a re-pin or a lane width that grew
+            blk = self._open.get(bi)
+            if blk is not None and blk.lanes == lanes:
+                if blk.staged_hi < need_hi \
+                        and not self._stage_new_lanes(bi, blk):
+                    return None
+                return blk
+            blk = self._build(bi, lanes, compress=False,
+                              why=self._open_retired.pop(bi, "first"))
             if blk is not None:
-                self._tails[bi] = (epoch, blk)
-                while len(self._tails) > 8:      # bound lagging-replay spans
-                    self._tails.pop(next(iter(self._tails)))
+                self._open[bi] = blk
+                self._rehearse(bi)
             return blk
         blk = self._build(bi, lanes)
         if blk is not None:
             self.blocks[bi] = blk
+            self._open_retired.pop(bi, None)   # frozen for good
+            if self._open.pop(bi, None) is not None:
+                # the open block it replaces (every buffer with a row in
+                # its range has frozen: the last group's flush, or a node
+                # gone quiet): no plan keeps its planes
+                self._plan_memo.clear()
             self.version += 1
         return blk
 
@@ -1744,11 +1899,19 @@ class DeviceGridCache:
             return np.float32
         return np.float64 if jax.config.jax_enable_x64 else np.float32
 
-    def _build(self, bi: int, lanes: int, compress: bool = True):
-        """Host staging + one upload for block ``bi``, as the
-        ``grid.build`` stage (most of a cold node's set-up)."""
+    def _build(self, bi: int, lanes: int, compress: bool = True,
+               why: str = "first"):
+        """Host staging + one upload for block ``bi``: a frozen block as
+        the ``grid.build`` stage (most of a cold node's set-up), an open
+        one (``compress=False``) as ``grid.tail_build``, whose ``why``
+        says what made an every-lane build of it necessary (``first``,
+        ``width``, ``recovery``)."""
+        if not compress:
+            with TRACER.stage("grid.tail_build", cpu=True, lanes=lanes,
+                              why=why):
+                return self._build_block(bi, lanes, False, None)
         with TRACER.stage("grid.build", lanes=lanes, compressed=compress):
-            shapes = self._shard.grid_shapes if compress else None
+            shapes = self._shard.grid_shapes
             if shapes is None:
                 return self._build_block(bi, lanes, compress, None)
             # the dataset's other shards build this block now too (the
@@ -1881,11 +2044,335 @@ class DeviceGridCache:
             vals_dev = LEDGER.device_put(val_stage, dev, owner=self.owner,
                                          fmt="dense")
             nbytes += val_stage.nbytes
-        return _Block(ts_dev, vals_dev,
-                      lanes, self._seq, (fmin, fmax, fcnt), (pmin, pmax),
-                      staged_hi=self._next_lane, ts_desc=ts_desc,
-                      nbytes=nbytes, width=val_stage.shape[1],
-                      pack_inv=pack_inv)
+        blk = _Block(ts_dev, vals_dev,
+                     lanes, self._seq, (fmin, fmax, fcnt), (pmin, pmax),
+                     staged_hi=self._next_lane, ts_desc=ts_desc,
+                     nbytes=nbytes, width=val_stage.shape[1],
+                     pack_inv=pack_inv)
+        newest = np.where(fin, ts_stage, -1).max(axis=0).astype(np.int64)
+        self._seen_hi = max(self._seen_hi, self.epoch0 + int(newest.max()))
+        if not compress and not self.hist:
+            # an open block that takes appends: the newest row staged a
+            # lane, and fill and phase ranges that ``ufunc.at`` can move
+            # (an empty lane reads "nothing yet" on both sides)
+            blk.hi_ts = np.where(fcnt > 0, self.epoch0 + newest, _NO_ROW_TS)
+            fmin[fcnt == 0] = BLOCK_BUCKETS
+            pmin[fcnt == 0] = np.iinfo(np.int32).max
+        return blk
+
+    # ------------------------------------------------------------ open blocks
+
+    def note_append(self, pid: int, ts, vals, was_empty: bool) -> None:
+        """The ingest hook (``partition.on_append`` through the shard):
+        rows ``ts`` / ``vals`` (arrays, or one row's scalars) were
+        appended to partition ``pid``'s write buffer, which held no row
+        before them where ``was_empty``.  Any thread, no lock, O(1) a
+        call: the rows wait in ``_pend`` for ``flush_appends`` at the end
+        of the batch.  A partition with no lane here is not this cache's
+        yet: it is staged when a query first selects it."""
+        lane = self.lane_of.get(pid)
+        if lane is not None:
+            self._pend.append((lane, ts, vals, was_empty,
+                               self._shard.latest_ingest_ts))
+
+    def flush_appends(self) -> None:
+        """What the hook queued, into the open blocks: the ingest thread
+        calls it once a batch, BEFORE the epoch bump that makes the
+        batch's rows readable (one thread owns the append), so a request
+        waits for no more than one batch's append."""
+        if self._pend:
+            with self._lock:
+                self._apply_pending()  # filolint: disable=blocking-under-lock — the append's dispatch under the grid lock is the design: a plan never sees a plane half replaced
+
+    def _apply_pending(self) -> None:  # holds-lock: _lock
+        """Fold the queued rows in: the frozen frontier (a buffer that
+        went from empty to non-empty may lower it), and the open blocks,
+        with host work proportional to the rows queued, never to the
+        lanes resident.  Rows of a block that is not open are dropped
+        here (the every-lane build reads the write buffers themselves)
+        unless the block is provably NEW: it begins at or after the
+        newest timestamp the shard had ingested, and this cache had seen,
+        before its first row arrived, so it holds nothing but what the
+        hook brings and is opened empty, on this thread, with no walk."""
+        pend = self._pend
+        if not pend:
+            return
+        items = []
+        while True:
+            try:
+                items.append(pend.popleft())
+            except IndexError:
+                break
+        if self.gstep is None or self.epoch0 is None:
+            return
+        firsts = [int(np.min(it[1])) for it in items if it[3]]
+        memo_key, lo = self._frontier
+        if firsts and memo_key is not None:
+            first = min(firsts)
+            self._frontier = (memo_key,
+                              first if lo is None else min(lo, first))
+        if self.hist:
+            # bucket planes take no append: the next plan rebuilds
+            self._drop_open("recovery")
+            return
+        sizes = [np.size(it[1]) for it in items]
+        lanes = np.repeat(np.fromiter((it[0] for it in items), np.int64,
+                                      len(items)), sizes)
+        ts = np.concatenate([np.atleast_1d(it[1]) for it in items]) \
+            .astype(np.int64)
+        vals = np.concatenate([np.atleast_1d(it[2]) for it in items])
+        g = self.gstep
+        bis = ((ts - self.epoch0 + g - 1) // g) // BLOCK_BUCKETS
+        seen_before = self._seen_hi
+        self._seen_hi = max(seen_before, int(ts.max()))
+        for bi in np.unique(bis).tolist():
+            sel = bis == bi
+            blk = self._open.get(bi)
+            if blk is None:
+                first = int(np.argmax(sel))
+                shard_hi = items[int(np.searchsorted(
+                    np.cumsum(sizes), first, side="right"))][4]
+                known = max(seen_before, shard_hi,
+                            int(ts[:first].max()) if first else -1)
+                if bi < 0 or known > self.epoch0 \
+                        + (bi * BLOCK_BUCKETS - 1) * g:
+                    continue
+                blk = self._open_empty(bi)
+                if blk is None:
+                    continue
+            if not self._append_cells(bi, blk, lanes[sel], ts[sel],
+                                      vals[sel]):
+                return
+
+    def _open_empty(self, bi: int):  # holds-lock: _lock
+        """An open block with nothing in it, made on the device (no
+        upload), at the width the resident blocks have."""
+        import jax
+        import jax.numpy as jnp
+        widths = {b.lanes for b in self.blocks.values()} \
+            | {b.lanes for b in self._open.values()}
+        if len(widths) != 1:
+            return None          # no width to agree with: the build's
+        lanes = widths.pop()
+        dev = self._shard.grid_device
+        with jax.default_device(dev):
+            ts_dev = jnp.zeros((BLOCK_BUCKETS, lanes), jnp.int32)
+            vals_dev = jnp.full((BLOCK_BUCKETS, lanes), jnp.nan,
+                                self._val_dtype())
+        for arr in (ts_dev, vals_dev):
+            LEDGER.track(arr, owner=self.owner, fmt="dense")
+        fill = (np.full(lanes, BLOCK_BUCKETS, np.int32),
+                np.full(lanes, -1, np.int32), np.zeros(lanes, np.int32))
+        phase = (np.full(lanes, np.iinfo(np.int32).max, np.int32),
+                 np.full(lanes, -1, np.int32))
+        blk = _Block(ts_dev, vals_dev, lanes, self._seq, fill, phase,
+                     staged_hi=self._next_lane, width=lanes)
+        blk.hi_ts = np.full(lanes, _NO_ROW_TS, np.int64)
+        self._open[bi] = blk
+        self._open_retired.pop(bi, None)
+        self.opened += 1
+        self._rehearse(bi)
+        return blk
+
+    def _append_cells(self, bi: int, blk: "_Block", lanes: np.ndarray,  # holds-lock: _lock
+                      ts: np.ndarray, vals: np.ndarray) -> bool:
+        """Write rows (lane, absolute timestamp, value) into open block
+        ``bi``, as the ``grid.tail_append`` stage: the fill and phase
+        ranges on the host, the cells on the device by
+        ``devicestore.tail_append``, ``APPEND_CELLS`` a launch.  A row a
+        build already staged (``hi_ts``), or of a lane the block was not
+        built with, is skipped; a second row in a lane's bucket breaks the
+        layout and disables the cache (False), as in a build."""
+        keep = (lanes < blk.staged_hi) & (lanes < blk.width)
+        keep[keep] = ts[keep] > blk.hi_ts[lanes[keep]]
+        if not keep.all():
+            lanes, ts, vals = lanes[keep], ts[keep], vals[keep]
+        n = len(lanes)
+        if n == 0:
+            return True
+        with TRACER.stage("grid.tail_append", cpu=True, cells=n) as sp:
+            g = self.gstep
+            rel = ts - self.epoch0
+            bucket = (rel + g - 1) // g
+            rows = bucket - bi * BLOCK_BUCKETS
+            cell = lanes * BLOCK_BUCKETS + rows
+            if (rows <= blk.fmax[lanes]).any() \
+                    or len(np.unique(cell)) != n:
+                self._disable()                 # >1 sample per bucket
+                return False
+            np.add.at(blk.fcnt, lanes, 1)
+            np.minimum.at(blk.fmin, lanes, rows)
+            np.maximum.at(blk.fmax, lanes, rows)
+            phase = rel - (bucket - 1) * g
+            np.minimum.at(blk.pmin, lanes, phase)
+            np.maximum.at(blk.pmax, lanes, phase)
+            np.maximum.at(blk.hi_ts, lanes, ts)
+            dev = self._shard.grid_device
+            vals = vals.astype(blk.vals.dtype, copy=False)
+            ts_dev, vals_dev, nbytes = blk.ts, blk.vals, 0
+            for a in range(0, n, APPEND_CELLS):
+                b = min(a + APPEND_CELLS, n)
+                idx = np.full((3, APPEND_CELLS), BLOCK_BUCKETS, np.int32)
+                idx[0, :b - a], idx[1, :b - a] = rows[a:b], lanes[a:b]
+                idx[2, :b - a] = rel[a:b]
+                v = np.zeros(APPEND_CELLS, vals.dtype)
+                v[:b - a] = vals[a:b]
+                nbytes += idx.nbytes + v.nbytes
+                ts_dev, vals_dev = _tail_append(
+                    ts_dev, vals_dev,
+                    LEDGER.device_put(idx, dev, owner=self.owner,
+                                      fmt="scratch"),
+                    LEDGER.device_put(v, dev, owner=self.owner,
+                                      fmt="scratch"))
+                self.appends += 1
+            sp.tag(rows=len(np.unique(rows)), bytes=nbytes)
+        if blk.later:
+            self._rehearse_due(bi, blk, int(rows.max()))
+        for arr in (ts_dev, vals_dev):
+            LEDGER.track(arr, owner=self.owner, fmt="dense")
+        blk.ts, blk.vals = ts_dev, vals_dev
+        blk.gen += 1
+        # a plan made of the planes just replaced pins them: let it go
+        # (its key holds an ingest epoch that is about to pass anyway)
+        self._plan_memo.discard(lambda plan: blk in plan.segs)
+        return True
+
+    def _stage_new_lanes(self, bi: int, blk: "_Block") -> bool:  # holds-lock: _lock
+        """Lanes assigned since open block ``blk`` was built (a series a
+        query first selects after its rows began to arrive): their rows
+        of the block's range, read from their partitions and appended.
+        O(lanes new): ``lane_of`` keeps the order they were assigned in."""
+        g = self.gstep
+        b_lo_ms = self.epoch0 + (bi * BLOCK_BUCKETS - 1) * g
+        lanes, ts_l, val_l = [], [], []
+        for pid, lane in reversed(self.lane_of.items()):
+            if lane < blk.staged_hi:
+                break
+            part = self._shard.grid_partition(pid)
+            if part is None:
+                return False           # evicted mid-plan: fall back
+            ts, vals = part.read_range(b_lo_ms + 1,
+                                       b_lo_ms + BLOCK_BUCKETS * g,
+                                       self.column_id)
+            if not isinstance(vals, np.ndarray):
+                self._disable()
+                return False
+            lanes.append(np.full(len(ts), lane, np.int64))
+            ts_l.append(ts)
+            val_l.append(vals)
+        blk.staged_hi = self._next_lane
+        if not lanes:
+            return True
+        return self._append_cells(bi, blk, np.concatenate(lanes),
+                                  np.concatenate(ts_l).astype(np.int64),
+                                  np.concatenate(val_l))
+
+    def _note_recipe(self, plan: "_GridPlan", kind: str, num_groups: int,  # holds-lock: _lock
+                     op: str) -> None:
+        """Remember what a served plan's program call is made of, by
+        SHAPE alone (a handful a dashboard, whatever blocks its spans
+        began in): ``_rehearse`` compiles the same call over an open
+        block when one is created."""
+        key = (kind, plan.q, plan.lane_mult, plan.nrows, num_groups, op)
+        if key not in self._recipes:
+            self._recipes[key] = None
+            if len(self._recipes) > 64:     # the oldest shape goes
+                del self._recipes[next(iter(self._recipes))]
+
+    # bucket rows ahead of the row where a span's segment count changes
+    # at which the programs of the new count are compiled (16 min at 15 s)
+    REHEARSE_LEAD_ROWS = 64
+
+    def _rehearse(self, bi: int) -> None:  # holds-lock: _lock
+        """Open block ``bi`` was just created: the served spans will soon
+        end in it, with a dense plane beside packed ones and (the phase
+        proof leaves open blocks out) a ts plane — program shapes no
+        request has met.  They do not depend on how many rows the block
+        holds, so they are compiled ahead, on helper threads, by
+        launching each remembered call once over the real planes, solo
+        and, stacked, at every stack size: NOW with the span's last row
+        on the block's first (the imminent shape, the most segments a
+        span of that length covers), and with its last row on the
+        block's last (the fewest: what the edge changes to once the span
+        no longer reaches back into the oldest block) when the block has
+        filled to ``REHEARSE_LEAD_ROWS`` short of that change
+        (``_rehearse_due``, from the append that writes the row) — so a
+        cold node compiles one set while it serves, not two.  A request
+        that reaches an open block before its programs are done waits for
+        them (``_await_rehearsals``) and compiles nothing itself; once
+        the programs exist (the next block's planes have the same shapes)
+        a rehearsal is a launch.  The result is dropped; a failure leaves
+        the compile to the request."""
+        if self.hist or not self._recipes:
+            return
+        blk = self._open[bi]
+        jobs = {}
+        for recipe in self._recipes:
+            nrows = recipe[3]
+            # the row of a block from which a span of ``nrows`` rows no
+            # longer reaches the block before its first
+            change = (nrows - 1) % BLOCK_BUCKETS
+            for last_row, due in (
+                    (0, 0), (BLOCK_BUCKETS - 1,
+                             max(0, change - self.REHEARSE_LEAD_ROWS))):
+                lo = (bi * BLOCK_BUCKETS + last_row - nrows + 1) \
+                    // BLOCK_BUCKETS
+                jobs.setdefault((recipe, lo), (due, recipe, last_row))
+        # (what waits holds no plane: the call is made of the planes the
+        # block has when it falls due)
+        blk.later = sorted(jobs.values(), key=lambda job: job[0])
+        self._rehearse_due(bi, blk, 0)
+
+    def _rehearse_due(self, bi: int, blk: "_Block", row: int) -> None:  # holds-lock: _lock
+        """Hand the helpers every call of open block ``bi`` that falls
+        due at bucket row ``row`` or before it; the rest wait for the
+        append that writes their row."""
+        due = [job for job in blk.later if job[0] <= row]
+        if not due:
+            return
+        blk.later = [job for job in blk.later if job[0] > row]
+        calls = []
+        for _at, (kind, q, lane_mult, nrows, num_groups, op), last_row in due:
+            end = bi * BLOCK_BUCKETS + last_row
+            c0 = end - nrows + 1
+            lo = c0 // BLOCK_BUCKETS
+            segs = [self._open.get(b) or self.blocks.get(b)
+                    for b in range(lo, bi)] + [blk]
+            if c0 < 0 or any(b is None or b.lanes != blk.lanes
+                             for b in segs):
+                continue            # no such span on this node (yet)
+            ts_parts = () if q.op in TS_FREE_OPS \
+                else tuple(b.ts_seg for b in segs)
+            calls.append((kind, ts_parts, tuple(b.vals for b in segs),
+                          c0 - lo * BLOCK_BUCKETS,
+                          (end - (q.nsteps - 1) * q.stride) * q.gstep_ms,
+                          dict(q=q, lanes=lane_mult, nrows=nrows),
+                          num_groups, op, blk.width))
+        # the ts-streaming shapes first: they take longest to compile
+        calls.sort(key=lambda call: not call[1])
+        batcher = getattr(self._shard, "query_batcher", None)
+        sizes = batcher.stack_sizes() \
+            if batcher is not None and batcher.enabled else []
+        pool = _rehearsers()
+        self._rehearsals = [f for f in self._rehearsals if not f.done()] \
+            + [pool.submit(_rehearse_call, call, stack)
+               for stack in [None] + sizes for call in calls]
+
+    def _await_rehearsals(self, plan: "_GridPlan") -> None:
+        """A plan that reads an open block whose programs are still being
+        compiled waits for the helpers here, outside every lock, and
+        compiles nothing itself (stage ``grid.rehearse_wait``: only where
+        a request arrived within seconds of a block's creation on a node
+        that had never served an open block)."""
+        pending = self._rehearsals
+        if not pending or not any(b.hi_ts is not None for b in plan.segs):
+            return
+        pending = [f for f in pending if not f.done()]
+        if pending:
+            from concurrent.futures import wait
+            with TRACER.stage("grid.rehearse_wait", leaf=False,
+                              programs=len(pending)):
+                wait(pending)
 
     def _reclaim(self, target_bytes: int,  # holds-lock: _lock
                  keep: set) -> int:

@@ -45,7 +45,8 @@ class TimeSeriesPartition:
                  "chunks", "_decoded", "_buf_ts", "_buf_cols", "_buf_n",
                  "_capacity", "_hist_buckets", "_seq", "_unflushed",
                  "_pending", "_lock", "_encode_lock",
-                 "out_of_order_dropped", "on_freeze", "on_corrupt")
+                 "out_of_order_dropped", "on_freeze", "on_corrupt",
+                 "on_append")
 
     def __init__(self, part_id: int, schema: Schema, partkey: bytes,
                  tags: dict[str, str], group: int, capacity: int = 400):
@@ -82,6 +83,10 @@ class TimeSeriesPartition:
         # shard hook observing corrupt-chunk detections: (err, newly) ->
         # None, bumps shard stats (set wherever partitions are built)
         self.on_corrupt = None
+        # shard hook observing appends to the write buffer: (part, ts,
+        # column values, did the buffer hold no row before) -> None; the
+        # device grid's open blocks and frozen frontier follow it
+        self.on_append = None
 
     def _new_col_buffer(self, ctype: ColumnType):
         if ctype == ColumnType.DOUBLE:
@@ -142,6 +147,8 @@ class TimeSeriesPartition:
                 else:
                     buf[i] = v
             self._buf_n = i + 1
+        if self.on_append is not None:
+            self.on_append(self, timestamp, decoded, i == 0)
         if froze:
             self.drain_pending()
         return True
@@ -197,6 +204,7 @@ class TimeSeriesPartition:
                 self._hist_buckets = new_buckets
             if self._buf_cols is None:
                 self._alloc_buffers_locked()
+            was_empty = self._buf_n == 0
             i = 0
             while i < kept:
                 if self._buf_n == self._capacity:
@@ -216,6 +224,8 @@ class TimeSeriesPartition:
                         buf[j:j + take] = arr[i:i + take]
                 self._buf_n = j + take
                 i += take
+        if self.on_append is not None:
+            self.on_append(self, ts, cols, was_empty)
         if froze:
             # encode outside _lock (lock order: _encode_lock then _lock)
             self.drain_pending()

@@ -159,6 +159,7 @@ class TimeSeriesShard:
         # flush), and ingest-side adds against each other
         self._dirty_lock = threading.Lock()
         self.latest_offset = -1
+        self._batch_series = 0      # series the last batch ingested held
         # newest sample timestamp seen: drives time-boundary flush
         # scheduling (reference: createFlushTasks time boundaries :804-846)
         self.latest_ingest_ts = -1
@@ -183,7 +184,7 @@ class TimeSeriesShard:
         # shape agreement with the dataset's other local shards
         # (memstore/gridshapes.py; the memstore sets it at setup)
         self.grid_shapes = None
-        # monotone counter observed by the device caches' tail versioning:
+        # monotone counter the device caches key their plan memos by:
         # bumped whenever new rows or chunks could change query results
         self.ingest_epoch = 0
         # counts chunk FREEZES only (a strict subset of ingest_epoch
@@ -251,10 +252,16 @@ class TimeSeriesShard:
     # ------------------------------------------------------------------ ingest
 
     def ingest_container(self, container: bytes, offset: int) -> int:
-        fast = self._ingest_container_fast(container, offset)
-        if fast is not None:
-            return fast
-        return self.ingest(decode_container(container, self.schemas), offset)
+        """One container, whole: decode, the per-series appends, the
+        device grids' open blocks, the epoch bump (stage
+        ``ingest.container``, on the shard's ingest thread)."""
+        with TRACER.stage("ingest.container", cpu=True) as sp:
+            added = self._ingest_container_fast(container, offset)
+            if added is None:
+                added = self.ingest(
+                    decode_container(container, self.schemas), offset)
+            sp.tag(samples=added, series=self._batch_series)
+            return added
 
     def _ingest_container_fast(self, container: bytes, offset: int
                                ) -> Optional[int]:
@@ -291,7 +298,7 @@ class TimeSeriesShard:
                 self.stats.rows_skipped += skipped
                 ts, uniq_idx = ts[keep], uniq_idx[keep]
                 cols = [c[keep] for c in cols]
-        n_uniq = len(dec.partkeys)
+        n_uniq = self._batch_series = len(dec.partkeys)
         order = np.argsort(uniq_idx, kind="stable")
         ts_s = ts[order]
         cols_s = [c[order] for c in cols]
@@ -334,8 +341,11 @@ class TimeSeriesShard:
                         part.part_id)
         except BaseException:
             # a batch that raises midway may have moved write buffers:
-            # what is cached per ingest epoch (the device grid's tails
-            # and frozen frontier, mutable_floor) must not outlive that
+            # what is cached per ingest epoch (the device grid's plans,
+            # mutable_floor) must not outlive that; the rows that did land
+            # reach the grid's open blocks before the bump, as a whole
+            # batch's do (the ingest thread alone appends)
+            self._flush_grid_appends()
             self.ingest_epoch += 1
             raise
         if len(ts):
@@ -343,6 +353,7 @@ class TimeSeriesShard:
                                         int(ts.max()))
         self.latest_offset = max(self.latest_offset, offset)
         if added_total:
+            self._flush_grid_appends()
             self.ingest_epoch += 1
         return added_total
 
@@ -396,6 +407,7 @@ class TimeSeriesShard:
         if self.ingest_sched_check is not None:
             self.ingest_sched_check()
         n = 0
+        touched = set()
         try:
             for rec in records:
                 group = rec.part_hash % self.num_groups
@@ -411,6 +423,7 @@ class TimeSeriesShard:
                 except SplitFiltered:
                     self.stats.rows_split_filtered += 1
                     continue
+                touched.add(part.part_id)
                 if part.ingest(rec.timestamp, rec.values):
                     n += 1
                     self.stats.rows_ingested += 1
@@ -423,10 +436,13 @@ class TimeSeriesShard:
                 if rec.timestamp > self.latest_ingest_ts:
                     self.latest_ingest_ts = rec.timestamp
         except BaseException:
+            self._flush_grid_appends()
             self.ingest_epoch += 1      # rows may have landed: see above
             raise
         self.latest_offset = max(self.latest_offset, offset)
+        self._batch_series = len(touched)
         if n:
+            self._flush_grid_appends()
             self.ingest_epoch += 1
         return n
 
@@ -455,6 +471,7 @@ class TimeSeriesShard:
                 capacity=self.config.max_chunks_size)
             part.on_freeze = self._on_chunk_freeze
             part.on_corrupt = self.note_corrupt_chunk
+            part.on_append = self._on_rows_appended
             self.partitions[pid] = part
             self.index.mark_active(pid)
             return part
@@ -482,6 +499,7 @@ class TimeSeriesShard:
             capacity=self.config.max_chunks_size)
         part.on_freeze = self._on_chunk_freeze
         part.on_corrupt = self.note_corrupt_chunk
+        part.on_append = self._on_rows_appended
         self.partitions[pid] = part
         self.part_set[pk] = pid
         self.part_schema_hash[pid] = schema.schema_hash
@@ -704,7 +722,9 @@ class TimeSeriesShard:
     def purge_expired(self, retention_ms: int, now_ms: int) -> int:
         """Drop partitions whose data aged out entirely (reference :776-795)."""
         cutoff = now_ms - retention_ms
-        doomed = [pid for pid, p in self.partitions.items()
+        # a snapshot: the ingest thread adds partitions while a purge on
+        # another thread waits for one's lock
+        doomed = [pid for pid, p in list(self.partitions.items())
                   if p.latest_timestamp < cutoff]
         for pid in doomed:
             part = self.partitions.pop(pid)
@@ -922,6 +942,28 @@ class TimeSeriesShard:
         return self.partitions.get(part_id)
 
     # --------------------------------------------------- device-resident scan
+
+    def _on_rows_appended(self, part, ts, cols, was_empty: bool) -> None:
+        """``partition.on_append``: rows reached ``part``'s write buffer
+        (one row's scalars or a block's arrays; ``cols`` the data columns
+        after the timestamp).  The device grids of its schema are told,
+        O(1) a call; with no grid yet (set-up's load) it costs the one
+        test."""
+        caches = self.device_caches
+        if not caches:
+            return
+        shash = part.schema.schema_hash
+        # a snapshot: a query's thread adds a cache (``device_cache``, the
+        # first query of a column) while a container ingests
+        for (cache_hash, cid), cache in tuple(caches.items()):
+            if cache_hash == shash:
+                cache.note_append(part.part_id, ts, cols[cid - 1], was_empty)
+
+    def _flush_grid_appends(self) -> None:
+        """The rows this batch brought, into the device grids' open
+        blocks, before the epoch bump that makes them readable."""
+        for cache in list(self.device_caches.values()):
+            cache.flush_appends()
 
     def _on_chunk_freeze(self, cs) -> None:
         self.ingest_epoch += 1

@@ -1130,8 +1130,10 @@ def boot(config: dict) -> FiloServer:
     through here, so the smoke proves the program users start."""
     enable_server_x64()
     place_compile_cache()
-    from filodb_tpu.utils.observability import install_gc_watch
+    from filodb_tpu.utils.observability import (install_gc_watch,
+                                                install_stall_watch)
     install_gc_watch()
+    install_stall_watch()
     server = FiloServer(config)
     server.start()
     return server

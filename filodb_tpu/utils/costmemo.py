@@ -65,6 +65,14 @@ class CostMemo:
         with self._lock:
             self._entries.clear()
 
+    def discard(self, unwanted) -> None:
+        """Let go of every entry whose value ``unwanted(value)`` is true
+        of (a plan made of device planes that were just replaced)."""
+        with self._lock:
+            for key in [k for k, entry in self._entries.items()
+                        if unwanted(entry[0])]:
+                del self._entries[key]
+
     def values(self) -> list:
         """The kept values, least recently used first."""
         with self._lock:

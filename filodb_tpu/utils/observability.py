@@ -18,6 +18,8 @@ import bisect
 import collections
 import dataclasses
 import itertools
+import json
+import os
 import random
 import sys
 import threading
@@ -1473,6 +1475,109 @@ def install_gc_watch() -> GcPauseWatch:
     if _GC_WATCH not in gc.callbacks:
         gc.callbacks.append(_GC_WATCH)
     return _GC_WATCH
+
+
+class StallWatch:
+    """What stops the process for seconds: a daemon thread that sleeps
+    ``TICK_S`` at a time and, where it wakes more than ``LIMIT_S`` late,
+    reports a ``host.stall`` stage span (its wall is the lateness) and
+    one line on stderr that says WHAT KIND of stop it was, from three
+    clocks read before and after: the process's CPU over all threads (a
+    thread that kept the interpreter lock and computed reads about the
+    wall; one that kept it inside a blocking call, or a machine that did
+    not run, reads near zero), the machine's busy, idle and stolen
+    seconds summed over its CPUs (``/proc/stat``: where they add up to
+    less than the wall times the CPUs the machine itself was not run),
+    and the collector's seconds (a full collection is no news) — then
+    the top frames of every thread as they stand when the watch got to
+    run again: the thread that held everything up is usually still at
+    the call it was in.  A collection of half a second stays under the
+    limit; the watch costs a wake-up a tick."""
+
+    TICK_S = 0.1
+    LIMIT_S = 1.0
+
+    def __init__(self, tracer: "Tracer" = TRACER, out=None):
+        self._tracer, self._out = tracer, out
+        self.stalls: list[dict] = []       # the last few, newest last
+        self._stop = threading.Event()
+        self._thread: Optional[threading.Thread] = None
+
+    @staticmethod
+    def _machine() -> Optional[tuple]:
+        """(busy, idle, stolen) seconds over all CPUs since boot."""
+        try:
+            with open("/proc/stat") as f:
+                v = [int(x) for x in f.readline().split()[1:9]]
+            hz = float(os.sysconf("SC_CLK_TCK"))
+            return ((v[0] + v[1] + v[2] + v[5] + v[6]) / hz,
+                    (v[3] + v[4]) / hz, v[7] / hz)
+        except (OSError, ValueError, IndexError):
+            return None
+
+    def _collected(self) -> float:
+        return sum(_GC_WATCH.seconds) if _GC_WATCH is not None else 0.0
+
+    def start(self) -> "StallWatch":
+        if self._thread is None:
+            self._thread = threading.Thread(target=self._run, daemon=True,
+                                            name="stall-watch")
+            self._thread.start()
+        return self
+
+    def stop(self) -> None:
+        self._stop.set()
+
+    def _run(self) -> None:
+        while not self._stop.is_set():
+            wall0, t0 = time.time(), time.perf_counter()
+            cpu0, mach0, gc0 = (time.process_time(), self._machine(),
+                                self._collected())
+            self._stop.wait(self.TICK_S)
+            late = time.perf_counter() - t0 - self.TICK_S
+            if late > self.LIMIT_S and not self._stop.is_set():
+                self._report(wall0, late, time.process_time() - cpu0,
+                             mach0, self._machine(),
+                             self._collected() - gc0)
+
+    def _report(self, wall0, late, cpu, mach0, mach1, collected) -> None:
+        note = {"late_s": round(late, 3), "process_cpu_s": round(cpu, 3),
+                "collector_s": round(collected, 3)}
+        if mach0 is not None and mach1 is not None:
+            note.update(zip(("machine_busy_s", "machine_idle_s",
+                             "machine_stolen_s"),
+                            (round(b - a, 3) for a, b in zip(mach0, mach1))))
+            note["cpus"] = os.cpu_count()
+        names = {t.ident: t.name for t in threading.enumerate()}
+        frames = []
+        for ident, frame in sys._current_frames().items():
+            top = []
+            while frame is not None and len(top) < 6:
+                code = frame.f_code
+                top.append(f"{os.path.basename(code.co_filename)}:"
+                           f"{frame.f_lineno}:{code.co_name}")
+                frame = frame.f_back
+            frames.append(f"{names.get(ident, ident)}[{' < '.join(top)}]")
+        self.stalls = self.stalls[-7:] + [dict(note, at=wall0)]
+        try:
+            self._tracer.record("host.stall", late, start_s=wall0,
+                                stage=True, **note)
+            print(f"host stall: {json.dumps(note)} threads: "
+                  + "; ".join(sorted(frames)),
+                  file=self._out or sys.stderr, flush=True)
+        except Exception:  # noqa: BLE001 — the watch never fails the node
+            pass
+
+
+_STALL_WATCH: Optional[StallWatch] = None
+
+
+def install_stall_watch() -> StallWatch:
+    """Start the process's one :class:`StallWatch` (idempotent)."""
+    global _STALL_WATCH
+    if _STALL_WATCH is None:
+        _STALL_WATCH = StallWatch().start()
+    return _STALL_WATCH
 
 
 # ---------------------------------------------------------------------------

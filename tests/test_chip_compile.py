@@ -333,6 +333,89 @@ def test_fused_program_batched(one_chip, as_tpu, prog):
                  **kw)
 
 
+# ---------------------------------------------------------------------
+# the live edge (PR 37): a span that ends in the OPEN block, and the
+# program that appends to it
+# ---------------------------------------------------------------------
+
+def _live_parts(mode: str, ncols: int, segs: int):
+    """(ts_parts, val_parts) of a span whose last block is the open one,
+    the way _plan_locked hands them over.  Over three blocks: the
+    cache's first block dense (its empty bucket 0 keeps it unpacked), a
+    packed one, then the open block's dense planes; over two (what the
+    edge changes to as the open block fills): a packed one and the open
+    block.  A ts plane of its own for the open block alone (the frozen
+    ones are rebuilt from a phase row), and none at all for an op that
+    reads no timestamps."""
+    dense = _sds((BLOCK_BUCKETS, ncols), jnp.float32)
+    vals = ((dense, _packed_block(ncols), dense) if segs == 3
+            else (_packed_block(ncols), dense))
+    if mode != "ts":
+        return (), vals
+    ts = tuple({"base": (bi * BLOCK_BUCKETS - 1) * GSTEP, "g": GSTEP,
+                "phase": _sds((ncols,), jnp.int32)}
+               for bi in range(3 - segs, 2))
+    return ts + (_sds((BLOCK_BUCKETS, ncols), jnp.int32),), vals
+
+
+@pytest.mark.parametrize("segs", [3, 2])
+@pytest.mark.parametrize("mode", ["ts", "free"])
+@pytest.mark.parametrize("prog", ["series", "grouped", "series_batch",
+                                  "grouped_batch"])
+def test_fused_program_live_edge(one_chip, as_tpu, prog, mode, segs):
+    """A 1 h panel whose end has moved into the open block: three
+    segments (two once the open block has filled past the span's reach),
+    a dense plane beside packed ones, no phase row (the phase proof
+    leaves open blocks out, so ``rate`` streams the ts planes), solo and
+    stacked: the shapes ``DeviceGridCache._rehearse`` compiles ahead."""
+    ncols, nrows, k = 2048, 240, 20
+    q = _query(mode, 23, k, dense=True)._replace(stride=10)
+    assert (q.nsteps - 1) * q.stride + k == nrows
+    ts_parts, val_parts = _live_parts(mode, ncols, segs)
+    kw = dict(q=q, lanes=lane_tile(ncols, nrows), nrows=nrows)
+    at = (_sds((), jnp.int64),) * 2 if "batch" not in prog \
+        else (_sds((4,), jnp.int64),) * 2
+    fn = devicestore._fused_progs()[prog]
+    if prog.startswith("series"):
+        _compile(fn, one_chip, ts_parts, val_parts, *at, None, **kw)
+    else:
+        _compile(fn, one_chip, ts_parts, val_parts, *at,
+                 _sds((ncols,), jnp.int32), None, num_groups=8, op="sum",
+                 **kw)
+
+
+def test_tail_append_program(one_chip):
+    """``devicestore.tail_append`` at the deployment's width: 102 400
+    lanes, ``APPEND_CELLS`` cells a launch, the planes not donated (the
+    result is a copy on the device).  Pure XLA: no kernel to look for."""
+    ncols, cells = 102_400, devicestore.APPEND_CELLS
+    jitted = _tail_append_jitted()
+    compiled = jitted.lower(*_abstract((
+        _sds((BLOCK_BUCKETS, ncols), jnp.int32),
+        _sds((BLOCK_BUCKETS, ncols), jnp.float32),
+        _sds((3, cells), jnp.int32), _sds((cells,), jnp.float32)),
+        one_chip)).compile()
+    text = compiled.as_text()
+    assert "scatter" in text
+    # two planes in, two out, nothing aliased onto its input
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 2 * BLOCK_BUCKETS * ncols * 4
+    assert mem.alias_size_in_bytes == 0
+
+
+def _tail_append_jitted():
+    """The jitted append program, without launching it: ``_tail_append``
+    builds it on its first call, so call it on tiny CPU planes once."""
+    if devicestore._TAIL_APPEND_FN is None:
+        devicestore._tail_append(
+            jnp.zeros((BLOCK_BUCKETS, 8), jnp.int32),
+            jnp.zeros((BLOCK_BUCKETS, 8), jnp.float32),
+            jnp.full((3, devicestore.APPEND_CELLS), BLOCK_BUCKETS,
+                     jnp.int32),
+            jnp.zeros(devicestore.APPEND_CELLS, jnp.float32))
+    return devicestore._TAIL_APPEND_FN._jitted
+
+
 def test_fused_mesh_program_four_chips(topo, as_tpu):
     """The mesh fabric's fused program (scan -> window -> group reduce
     -> cross-shard psum -> present) on a Mesh of the four described

@@ -8,6 +8,8 @@ repeat query (zero host->device transfer), must invalidate on new data,
 and must fall back — never be wrong — on irregular layouts.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -1474,17 +1476,25 @@ def _fr_partition_removed(_tmp_path):
 
 
 def _fr_ingest_during_walk(_tmp_path):
-    """A row lands in a lane the walk has already passed: the memo is
-    stamped with the epoch read BEFORE the walk, so it is stale."""
+    """A row lands in a lane the walk has already passed: the walk
+    misses it, the ingest hook has queued it, and it is folded into the
+    bound once the ingest thread gets the grid lock (or by the next
+    plan): the memo is never left fresh over a missed row."""
+    import threading
     shard, cache, res = _fr_shard()
     shard.bump_removal_epoch()          # any event: the next plan walks
     pids = list(cache.lane_of)
     inner = shard.grid_partition
+    ingest = threading.Thread(
+        target=_fr_ingest, args=(shard, "m_a", [0], FR_ROWS, FR_ROWS + 1,
+                                 400))
 
     def racing(pid):
         if pid == pids[-1] and shard.grid_partition is racing:
             shard.grid_partition = inner        # once
-            _fr_ingest(shard, "m_a", [0], FR_ROWS, FR_ROWS + 1, 400)
+            ingest.start()      # another thread's: it waits for the lock
+            while not cache._pend and ingest.is_alive():
+                time.sleep(0.001)
         return inner(pid)
 
     shard.grid_partition = racing
@@ -1492,7 +1502,8 @@ def _fr_ingest_during_walk(_tmp_path):
         assert _fr_used(cache) == 2**62     # the walk missed the row
     finally:
         shard.grid_partition = inner
-    assert cache._frontier[0][0] != shard.ingest_epoch
+    ingest.join(30)
+    assert not ingest.is_alive()
     yield shard, cache
     assert _fr_used(cache) < 2**62
     assert _fr_agrees(shard, res.part_ids, FR_ROWS + 1) is not None

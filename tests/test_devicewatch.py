@@ -68,7 +68,7 @@ def _expected_grid_bytes(cache) -> dict:
     MUST show for this owner, by format."""
     by_fmt: collections.Counter = collections.Counter()
     blocks = list(cache.blocks.values()) \
-        + [blk for _v, blk in cache._tails.values()]
+        + list(cache._open.values())
     for blk in blocks:
         if blk.ts is not None:
             by_fmt["dense"] += int(blk.ts.nbytes)

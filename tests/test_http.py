@@ -246,3 +246,49 @@ def test_param_parsing():
     assert parse_duration_ms("250ms") == 250
     assert parse_duration_ms("2h") == 7_200_000
     assert parse_duration_ms("30") == 30_000
+
+
+def test_more_clients_than_five_connect_at_once_and_none_waits_a_second():
+    """The stdlib's listen queue of 5 drops the SYNs of every client over
+    it while the accept thread is away (a collection; panels that refresh
+    together), and each such client waits out the kernel's retransmit, a
+    whole second, before its request is read.  Twelve connect while
+    nobody accepts for 0.2 s: every one is answered well inside a
+    second."""
+    import http.client
+    import threading
+    import time
+
+    srv = FiloHttpServer(shard_manager=ShardManager())
+    port = srv.start()
+    took = []
+    try:
+        assert srv._httpd.request_queue_size >= 64
+        # the accept thread, away: it does not accept until the gate opens
+        accept_fn = srv._httpd.get_request
+        gate = threading.Event()
+
+        def away():
+            gate.wait(5)
+            return accept_fn()
+
+        srv._httpd.get_request = away
+
+        def one():
+            t0 = time.time()
+            c = http.client.HTTPConnection("127.0.0.1", port, timeout=10)
+            c.request("GET", "/__health")
+            c.getresponse().read()
+            c.close()
+            took.append(time.time() - t0)
+
+        clients = [threading.Thread(target=one) for _ in range(12)]
+        for t in clients:
+            t.start()
+        time.sleep(0.2)
+        gate.set()
+        for t in clients:
+            t.join(15)
+    finally:
+        srv.shutdown()
+    assert len(took) == 12 and max(took) < 0.9, sorted(took)

@@ -230,7 +230,7 @@ def _repin(fab):
 
 def _widths(cache) -> set:
     return {b.lanes for b in cache.blocks.values()} \
-        | {blk.lanes for _epoch, blk in cache._tails.values()}
+        | {blk.lanes for blk in cache._open.values()}
 
 
 def _widen(fab):

@@ -581,6 +581,59 @@ class TestStageClock:
         assert span_from_dict({"name": "old-node"}).cpu_s == 0.0
 
 
+class TestStallWatch:
+    """A stop of the interpreter for longer than the limit becomes a
+    ``host.stall`` span and one line that says what kind of stop."""
+
+    def _stalled(self, tracer, hold):
+        import io
+        from filodb_tpu.utils.observability import StallWatch
+        out = io.StringIO()
+        watch = StallWatch(tracer, out=out)
+        watch.TICK_S, watch.LIMIT_S = 0.02, 0.15
+        watch.start()
+        try:
+            time.sleep(0.1)            # ticks that are on time: no news
+            assert watch.stalls == []
+            hold()
+            deadline = time.time() + 5
+            while not watch.stalls and time.time() < deadline:
+                time.sleep(0.01)
+        finally:
+            watch.stop()
+        return watch, out.getvalue()
+
+    def test_a_held_interpreter_is_a_stall_with_its_cpu(self, tracer):
+        import re
+
+        def hold():                    # one C call that keeps the lock
+            re.match(r"(a*)*b", "a" * 24)
+
+        t0 = time.perf_counter()
+        hold()
+        took = time.perf_counter() - t0
+        if took < 0.3:
+            pytest.skip(f"the hold took only {took:.2f} s here")
+        watch, line = self._stalled(tracer, hold)
+        (stall,) = watch.stalls[:1]
+        assert stall["late_s"] > 0.15
+        # a thread computed: the process's CPU is of the wall's order
+        assert stall["process_cpu_s"] > 0.5 * stall["late_s"]
+        row = tracer.stages.snapshot()["host.stall"]
+        assert row["count"] >= 1 and row["wall_s"] >= stall["late_s"] * 0.99
+        assert line.startswith("host stall: {") and "MainThread[" in line
+
+    def test_ticks_on_time_report_nothing(self, tracer):
+        watch, line = self._stalled(tracer, lambda: time.sleep(0.3))
+        assert watch.stalls == [] and line == ""
+        assert "host.stall" not in tracer.stages.snapshot()
+
+    def test_boot_installs_one(self):
+        from filodb_tpu.utils import observability as obs
+        a = obs.install_stall_watch()
+        assert obs.install_stall_watch() is a and a._thread.is_alive()
+
+
 class TestGcPauseWatch:
     def _watch(self, tracer):
         from filodb_tpu.utils.observability import GcPauseWatch

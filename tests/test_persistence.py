@@ -521,7 +521,7 @@ class TestOnDemandPaging:
         # … a re-appearing partition gets a FRESH lane, so the stale NaN
         # lane can never serve it, and end-to-end results stay correct
         cache.blocks.clear()
-        cache._tails.clear()
+        cache._open.clear()
         res2 = shard.lookup_partitions(flt, 0, 2**62)
         shard.scan_batch(res2.part_ids, 0, 2**62)      # re-page victim
         got2 = shard.scan_grid(res2.part_ids, F.RATE, t0 + 120_000, 20,
