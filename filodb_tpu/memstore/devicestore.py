@@ -26,9 +26,10 @@ directly instead of re-uploading numpy per query:
 - Chunk freezes invalidate overlapping blocks (the shard wires
   ``partition.on_freeze`` to :meth:`note_freeze`); the mutable write-buffer
   rows are served from an OPEN block: dense planes that live on the device
-  and are appended to as containers arrive (``partition.on_append`` ->
-  :meth:`note_append` -> ``devicestore.tail_append``), never rebuilt for an
-  ingest epoch.
+  and are appended to as containers arrive (a container's common series
+  through :meth:`note_append_rows`, once; the rest through
+  ``partition.on_append`` -> :meth:`note_append`; then
+  ``devicestore.tail_append``), never rebuilt for an ingest epoch.
 
 The grid layout contract matches :mod:`filodb_tpu.ops.grid`: row ``c``
 holds the (single) sample with ``ts in (epoch0+(c-1)*gstep, epoch0+c*gstep]``.
@@ -41,6 +42,7 @@ absent.
 from __future__ import annotations
 
 import collections
+import itertools
 import threading
 from typing import NamedTuple, Optional, Sequence
 
@@ -840,9 +842,11 @@ class DeviceGridCache:
         # again (``grid.tail_build``'s ``why``)
         self._open: dict[int, _Block] = {}
         self._open_retired: dict[int, str] = {}
-        # rows the ingest hook queued (note_append; any thread, no lock)
-        # for the ingest thread's flush_appends: (lane, ts, vals, was the
-        # buffer empty, the shard's newest timestamp before the batch)
+        # rows the ingest hook queued (note_append, a series' rows under
+        # one lane; note_append_rows, a container's with a lane a row; any
+        # thread, no lock) for the ingest thread's flush_appends: (lanes,
+        # ts, vals, the rows whose earliest lowers the frontier or None,
+        # the shard's newest timestamp before the batch)
         self._pend: collections.deque = collections.deque()
         # newest timestamp this cache has staged or been told of: a block
         # that begins at or after it holds nothing yet (_apply_pending)
@@ -1794,8 +1798,8 @@ class DeviceGridCache:
         The bound is MAINTAINED, not walked: an append to a buffer that
         already holds a row cannot move a lane's earliest buffered row, so
         ingest moves the bound only where a buffer went from empty to
-        non-empty (``note_append`` says so with each row; O(1) a series,
-        folded in by ``_apply_pending``).  The walk over every lane
+        non-empty (``note_append`` and ``note_append_rows`` say so with
+        the rows; folded in by ``_apply_pending``).  The walk over every lane
         (``grid.frontier``) runs only where the bound may have RISEN or
         the lanes walked have changed: after a chunk freeze
         (``_freezes``), a removal (``removal_epoch``: eviction, purge,
@@ -2072,7 +2076,25 @@ class DeviceGridCache:
         yet: it is staged when a query first selects it."""
         lane = self.lane_of.get(pid)
         if lane is not None:
-            self._pend.append((lane, ts, vals, was_empty,
+            self._pend.append((lane, ts, vals, ts if was_empty else None,
+                               self._shard.latest_ingest_ts))
+
+    def note_append_rows(self, pids: list, ts: np.ndarray,
+                         vals: np.ndarray, opened: list) -> None:
+        """The bulk form of :meth:`note_append`, once a container: row
+        ``i`` went to partition ``pids[i]``; the rows at ``opened`` are
+        the first of a buffer that held none.  The rows of partitions with
+        a lane here wait in ``_pend`` as ONE item."""
+        lanes = list(map(self.lane_of.get, pids, itertools.repeat(-1)))
+        firsts = [int(ts[i]) for i in opened if lanes[i] >= 0]
+        missing = -1 in lanes
+        lanes = np.array(lanes, np.int64)
+        if missing:
+            keep = lanes >= 0
+            lanes, ts, vals = lanes[keep], ts[keep], vals[keep]
+        if len(lanes):
+            self._pend.append((lanes, ts, vals,
+                               min(firsts) if firsts else None,
                                self._shard.latest_ingest_ts))
 
     def flush_appends(self) -> None:
@@ -2105,7 +2127,8 @@ class DeviceGridCache:
                 break
         if self.gstep is None or self.epoch0 is None:
             return
-        firsts = [int(np.min(it[1])) for it in items if it[3]]
+        # (an item's earliest row of a buffer that held none, if any)
+        firsts = [int(np.min(it[3])) for it in items if it[3] is not None]
         memo_key, lo = self._frontier
         if firsts and memo_key is not None:
             first = min(firsts)
@@ -2116,20 +2139,33 @@ class DeviceGridCache:
             self._drop_open("recovery")
             return
         sizes = [np.size(it[1]) for it in items]
-        lanes = np.repeat(np.fromiter((it[0] for it in items), np.int64,
-                                      len(items)), sizes)
-        ts = np.concatenate([np.atleast_1d(it[1]) for it in items]) \
-            .astype(np.int64)
-        vals = np.concatenate([np.atleast_1d(it[2]) for it in items])
+        if len(items) == 1 and isinstance(items[0][0], np.ndarray):
+            lanes, ts, vals = items[0][:3]      # a container's bulk rows
+        else:
+            # (one series' rows under its lane, or a container's bulk
+            # rows with a lane each)
+            lanes = np.concatenate([
+                it[0] if isinstance(it[0], np.ndarray)
+                else np.full(n, it[0], np.int64)
+                for it, n in zip(items, sizes)])
+            ts = np.concatenate([np.atleast_1d(it[1]) for it in items])
+            vals = np.concatenate([np.atleast_1d(it[2]) for it in items])
+        ts = ts.astype(np.int64, copy=False)
         g = self.gstep
-        bis = ((ts - self.epoch0 + g - 1) // g) // BLOCK_BUCKETS
+        lo, hi = int(ts.min()), int(ts.max())
         seen_before = self._seen_hi
-        self._seen_hi = max(seen_before, int(ts.max()))
-        for bi in np.unique(bis).tolist():
-            sel = bis == bi
+        self._seen_hi = max(seen_before, hi)
+        bi_lo, bi_hi = (((t - self.epoch0 + g - 1) // g) // BLOCK_BUCKETS
+                        for t in (lo, hi))
+        if bi_lo == bi_hi:
+            blocks = [(bi_lo, None)]            # every row in one block
+        else:
+            bis = ((ts - self.epoch0 + g - 1) // g) // BLOCK_BUCKETS
+            blocks = [(bi, bis == bi) for bi in np.unique(bis).tolist()]
+        for bi, sel in blocks:
             blk = self._open.get(bi)
             if blk is None:
-                first = int(np.argmax(sel))
+                first = 0 if sel is None else int(np.argmax(sel))
                 shard_hi = items[int(np.searchsorted(
                     np.cumsum(sizes), first, side="right"))][4]
                 known = max(seen_before, shard_hi,
@@ -2140,8 +2176,9 @@ class DeviceGridCache:
                 blk = self._open_empty(bi)
                 if blk is None:
                     continue
-            if not self._append_cells(bi, blk, lanes[sel], ts[sel],
-                                      vals[sel]):
+            cells = (lanes, ts, vals) if sel is None \
+                else (lanes[sel], ts[sel], vals[sel])
+            if not self._append_cells(bi, blk, *cells):
                 return
 
     def _open_empty(self, bi: int):  # holds-lock: _lock
@@ -2183,39 +2220,48 @@ class DeviceGridCache:
         build already staged (``hi_ts``), or of a lane the block was not
         built with, is skipped; a second row in a lane's bucket breaks the
         layout and disables the cache (False), as in a build."""
-        keep = (lanes < blk.staged_hi) & (lanes < blk.width)
-        keep[keep] = ts[keep] > blk.hi_ts[lanes[keep]]
-        if not keep.all():
+        # (few NumPy calls over the rows: each lets the interpreter go,
+        # and the ingest thread waits to have it back)
+        hi = min(blk.staged_hi, blk.width)
+        if len(lanes) and int(lanes.max()) >= hi:
+            keep = lanes < hi
             lanes, ts, vals = lanes[keep], ts[keep], vals[keep]
+        if len(lanes):
+            fresh = ts > blk.hi_ts[lanes]
+            if not fresh.all():
+                lanes, ts, vals = lanes[fresh], ts[fresh], vals[fresh]
         n = len(lanes)
         if n == 0:
             return True
         with TRACER.stage("grid.tail_append", cpu=True, cells=n) as sp:
             g = self.gstep
             rel = ts - self.epoch0
-            bucket = (rel + g - 1) // g
+            # the bucket a row lands in, and its phase in the bucket:
+            # ``rel - (bucket - 1) * g``
+            bucket, phase = np.divmod(rel + (g - 1), g)
+            phase += 1
             rows = bucket - bi * BLOCK_BUCKETS
-            cell = lanes * BLOCK_BUCKETS + rows
-            if (rows <= blk.fmax[lanes]).any() \
-                    or len(np.unique(cell)) != n:
-                self._disable()                 # >1 sample per bucket
+            # >1 sample per bucket: a row at or before a lane's newest, or
+            # two of one lane's rows in a cell (a lane once: none can be)
+            if (rows <= blk.fmax[lanes]).any() or (
+                    np.bincount(lanes).max() > 1 and len(np.unique(
+                        lanes * BLOCK_BUCKETS + rows)) != n):
+                self._disable()
                 return False
             np.add.at(blk.fcnt, lanes, 1)
             np.minimum.at(blk.fmin, lanes, rows)
             np.maximum.at(blk.fmax, lanes, rows)
-            phase = rel - (bucket - 1) * g
             np.minimum.at(blk.pmin, lanes, phase)
             np.maximum.at(blk.pmax, lanes, phase)
             np.maximum.at(blk.hi_ts, lanes, ts)
             dev = self._shard.grid_device
-            vals = vals.astype(blk.vals.dtype, copy=False)
             ts_dev, vals_dev, nbytes = blk.ts, blk.vals, 0
             for a in range(0, n, APPEND_CELLS):
                 b = min(a + APPEND_CELLS, n)
                 idx = np.full((3, APPEND_CELLS), BLOCK_BUCKETS, np.int32)
                 idx[0, :b - a], idx[1, :b - a] = rows[a:b], lanes[a:b]
                 idx[2, :b - a] = rel[a:b]
-                v = np.zeros(APPEND_CELLS, vals.dtype)
+                v = np.zeros(APPEND_CELLS, blk.vals.dtype)
                 v[:b - a] = vals[a:b]
                 nbytes += idx.nbytes + v.nbytes
                 ts_dev, vals_dev = _tail_append(
@@ -2225,9 +2271,10 @@ class DeviceGridCache:
                     LEDGER.device_put(v, dev, owner=self.owner,
                                       fmt="scratch"))
                 self.appends += 1
-            sp.tag(rows=len(np.unique(rows)), bytes=nbytes)
+            row_lo, row_hi = int(rows.min()), int(rows.max())
+            sp.tag(rows=row_hi - row_lo + 1, bytes=nbytes)
         if blk.later:
-            self._rehearse_due(bi, blk, int(rows.max()))
+            self._rehearse_due(bi, blk, row_hi)
         for arr in (ts_dev, vals_dev):
             LEDGER.track(arr, owner=self.owner, fmt="dense")
         blk.ts, blk.vals = ts_dev, vals_dev

@@ -588,6 +588,16 @@ class PartKeyIndex:
             raise KeyError(part_id)
         return int(self._start_arr[part_id])
 
+    def closed(self, part_ids: Sequence[int]) -> list:
+        """Those of ``part_ids`` whose end time is set (a stopped
+        series): what ingest marks active again."""
+        ids = np.asarray(part_ids, np.int64)
+        with self._lock:
+            ends = self._end_arr[ids]
+        if not len(ids) or ends.min() == _NO_END:
+            return []
+        return ids[ends != _NO_END].tolist()
+
     def end_time(self, part_id: int) -> int:
         if part_id not in self._tags:
             raise KeyError(part_id)

@@ -172,15 +172,7 @@ class TimeSeriesPartition:
                 new_buckets = c[0]
         froze = False
         with self._lock:
-            # high-water mark inline (the property would re-take _lock)
-            if self._buf_n:
-                lt = int(self._buf_ts[self._buf_n - 1])
-            elif self._pending:
-                lt = int(self._pending[-1].ts[-1])
-            elif self.chunks:
-                lt = self.chunks[-1].info.end_time
-            else:
-                lt = -1
+            lt = self._high_water_locked()
             running = np.maximum.accumulate(np.concatenate(([lt], ts)))[:-1]
             keep = ts > running
             kept = int(keep.sum())
@@ -234,13 +226,18 @@ class TimeSeriesPartition:
     @property
     def latest_timestamp(self) -> int:
         with self._lock:
-            if self._buf_n:
-                return int(self._buf_ts[self._buf_n - 1])
-            if self._pending:
-                return int(self._pending[-1].ts[-1])
-            if self.chunks:
-                return self.chunks[-1].info.end_time
-            return -1
+            return self._high_water_locked()
+
+    def _high_water_locked(self) -> int:
+        """The newest timestamp held (write buffer, pending, chunks): a
+        row at or before it is out of order."""
+        if self._buf_n:
+            return int(self._buf_ts[self._buf_n - 1])
+        if self._pending:
+            return int(self._pending[-1].ts[-1])
+        if self.chunks:
+            return self.chunks[-1].info.end_time
+        return -1
 
     @property
     def earliest_timestamp(self) -> int:
@@ -548,3 +545,50 @@ class TracingTimeSeriesPartition(TimeSeriesPartition):
         out = super().drain_pending()
         self._log_freeze(out)
         return out
+
+
+def append_newer(parts: Sequence[TimeSeriesPartition], at: Sequence[int],
+                 n: Sequence[int], ts: np.ndarray,
+                 cols: Sequence[np.ndarray]) -> list:
+    """The bulk form of ``ingest_block``, for many plain partitions of one
+    container: ``parts[i]`` takes rows ``at[i]:at[i] + n[i]`` of ``ts`` /
+    ``cols`` (scalar columns; a part's rows in order among themselves) if
+    the first of them is newer than its high-water mark and its write
+    buffer has room for all of them: nothing is dropped and nothing
+    freezes.  A row is written from scalars (one ``tolist`` a column), a
+    longer run by slices; each part under its own ``_lock``, so a
+    concurrent freeze never sees a torn row.  No hook is called: the
+    caller tells the device grids once for every part.  Returns a part
+    whether its buffer held no row before, or None where it declined (it
+    takes ``ingest_block``)."""
+    ts_l = ts.tolist()
+    cols_l = [c.tolist() for c in cols]
+    # (a schema of one data column, the common case, writes it unzipped)
+    col0 = cols_l[0] if len(cols_l) == 1 else None
+    out = []
+    append = out.append
+    for p, a, k in zip(parts, at, n):
+        with p._lock:
+            m = p._buf_n
+            t = ts_l[a]
+            if t <= (p._buf_ts.item(m - 1) if m
+                     else p._high_water_locked()) \
+                    or m + k > p._capacity:
+                append(None)
+                continue
+            if p._buf_cols is None:
+                p._alloc_buffers_locked()
+            if k != 1:
+                p._buf_ts[m:m + k] = ts[a:a + k]
+                for buf, c in zip(p._buf_cols, cols):
+                    buf[m:m + k] = c[a:a + k]
+            elif col0 is not None:
+                p._buf_ts[m] = t
+                p._buf_cols[0][m] = col0[a]
+            else:
+                p._buf_ts[m] = t
+                for buf, c in zip(p._buf_cols, cols_l):
+                    buf[m] = c[a]
+            p._buf_n = m + k
+        append(m == 0)
+    return out

@@ -21,6 +21,7 @@ padded device-ready ChunkBatch instead of per-row iterators.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Iterable, Optional, Sequence
@@ -34,7 +35,8 @@ from filodb_tpu.core.record import (IngestRecord, decode_container,
 from filodb_tpu.core.schemas import ColumnType, Schemas
 from filodb_tpu.core.storeconfig import StoreConfig
 from filodb_tpu.memstore.index import PartKeyIndex
-from filodb_tpu.memstore.partition import TimeSeriesPartition
+from filodb_tpu.memstore.partition import TimeSeriesPartition, append_newer
+from filodb_tpu.native.ingestfast import HistColumn
 from filodb_tpu.store.columnstore import ColumnStore, NullColumnStore, PartKeyRecord
 from filodb_tpu.store.metastore import InMemoryMetaStore, MetaStore
 from filodb_tpu.utils.bloom import BloomFilter
@@ -54,6 +56,11 @@ class SplitFiltered(Exception):
 
 
 _FLUSH_METRICS = None
+_SERIES_TOTAL = None
+
+# data columns whose write buffers take a row as a scalar (the bulk path)
+_BULK_COLUMNS = (ColumnType.DOUBLE, ColumnType.LONG, ColumnType.TIMESTAMP,
+                 ColumnType.INT)
 
 
 def _flush_m() -> dict:
@@ -63,6 +70,15 @@ def _flush_m() -> dict:
         from filodb_tpu.utils.observability import flush_metrics
         _FLUSH_METRICS = flush_metrics()
     return _FLUSH_METRICS
+
+
+def _series_total():
+    """``filodb_ingest_series_total``, resolved once per process."""
+    global _SERIES_TOTAL
+    if _SERIES_TOTAL is None:
+        from filodb_tpu.utils.observability import ingest_metrics
+        _SERIES_TOTAL = ingest_metrics()["series"]
+    return _SERIES_TOTAL
 
 
 @dataclasses.dataclass
@@ -159,7 +175,9 @@ class TimeSeriesShard:
         # flush), and ingest-side adds against each other
         self._dirty_lock = threading.Lock()
         self.latest_offset = -1
-        self._batch_series = 0      # series the last batch ingested held
+        # series the last batch ingested by the per-series path, and by
+        # the bulk path (``_ingest_bulk``)
+        self._batch_series = self._batch_bulk = 0
         # newest sample timestamp seen: drives time-boundary flush
         # scheduling (reference: createFlushTasks time boundaries :804-846)
         self.latest_ingest_ts = -1
@@ -252,21 +270,32 @@ class TimeSeriesShard:
     # ------------------------------------------------------------------ ingest
 
     def ingest_container(self, container: bytes, offset: int) -> int:
-        """One container, whole: decode, the per-series appends, the
-        device grids' open blocks, the epoch bump (stage
-        ``ingest.container``, on the shard's ingest thread)."""
+        """One container, whole: decode, the appends (its common series
+        in bulk, the rest a series at a time), the device grids' open
+        blocks, the epoch bump (stage ``ingest.container``, on the shard's
+        ingest thread; ``filodb_ingest_series_total`` counts the series by
+        the path they took)."""
+        self._batch_bulk = self._batch_series = 0
         with TRACER.stage("ingest.container", cpu=True) as sp:
             added = self._ingest_container_fast(container, offset)
             if added is None:
                 added = self.ingest(
                     decode_container(container, self.schemas), offset)
-            sp.tag(samples=added, series=self._batch_series)
-            return added
+            sp.tag(samples=added, bulk=self._batch_bulk,
+                   series=self._batch_series)
+        series = _series_total()
+        for path, n in (("bulk", self._batch_bulk),
+                        ("series", self._batch_series)):
+            if n:
+                series.inc(n, dataset=self.dataset, shard=self.shard_num,
+                           path=path)
+        return added
 
     def _ingest_container_fast(self, container: bytes, offset: int
                                ) -> Optional[int]:
-        """Columnar ingest: C++ container decode + per-series batch append
-        (native/ingestfast.py).  Histogram columns arrive blob-expanded
+        """Columnar ingest: C++ container decode (native/ingestfast.py),
+        the common series appended in bulk (:meth:`_ingest_bulk`), the
+        rest a series at a time.  Histogram columns arrive blob-expanded
         (HistColumn) and batch-append when a series' rows share one
         bucket scheme and width — the rare mixed-scheme run falls back
         to per-record ingest for just that series.  Returns None when
@@ -298,19 +327,25 @@ class TimeSeriesShard:
                 self.stats.rows_skipped += skipped
                 ts, uniq_idx = ts[keep], uniq_idx[keep]
                 cols = [c[keep] for c in cols]
-        n_uniq = self._batch_series = len(dec.partkeys)
-        order = np.argsort(uniq_idx, kind="stable")
-        ts_s = ts[order]
-        cols_s = [c[order] for c in cols]
-        counts = np.bincount(uniq_idx, minlength=n_uniq)
-        starts = np.concatenate(([0], np.cumsum(counts)))
-        added_total = 0
+        n_uniq = len(dec.partkeys)
+        if n_uniq == len(ts) == dec.num_records:
+            # a record a series and none skipped (a scrape's shape): the
+            # records are the series in order, a row each
+            ts_s, cols_s = ts, cols
+            n_l, at_l = [1] * n_uniq, list(range(n_uniq + 1))
+        else:
+            order = np.argsort(uniq_idx, kind="stable")
+            ts_s = ts[order]
+            cols_s = [c[order] for c in cols]
+            counts = np.bincount(uniq_idx, minlength=n_uniq)
+            n_l, at_l = counts.tolist(), [0] + np.cumsum(counts).tolist()
         maxint = np.iinfo(np.int64).max
         try:
-            for u in range(n_uniq):
-                s0, s1 = int(starts[u]), int(starts[u + 1])
-                if s0 == s1:
-                    continue  # all its records were watermark-skipped
+            rest, added_total = self._ingest_bulk(
+                dec, schema, ts_s, cols_s, n_l, at_l, groups_r)
+            self._batch_series = len(rest)
+            for u in rest:
+                s0, s1 = at_l[u], at_l[u + 1]
                 first = int(dec.uniq_first[u])
                 try:
                     part = self._get_or_add_partition_pk(
@@ -357,6 +392,96 @@ class TimeSeriesShard:
             self.ingest_epoch += 1
         return added_total
 
+    def _ingest_bulk(self, dec, schema, ts_s: np.ndarray, cols_s: list,
+                     n_l: list, at_l: list, groups_r: np.ndarray
+                     ) -> tuple[list, int]:
+        """The container's common series, a container at a time: those
+        whose partition is resident and plain (a traced one logs its
+        rows), whose rows are in order, newer than its high-water mark
+        and fit its write buffer (``partition.append_newer``).  What the
+        per-series path does a series is done here once: the stats, the
+        dirty sets (one ``update`` a flush group), the index's active
+        marks (only ids whose end time is closed), and ONE call a device
+        grid with the rows as arrays.  Series ``u`` has ``n_l[u]`` rows
+        from ``at_l[u]`` of ``ts_s`` / ``cols_s``.  Returns (the series
+        left to the per-series path, the rows added): every series with
+        rows that it did not take (new, re-paged, traced, out of order, a
+        histogram, a buffer that must freeze) is ingested there as before.
+        Python lists, not arrays, a series: a NumPy call over a
+        container's rows lets the interpreter go, and the ingest thread
+        waits to have it back behind every request thread."""
+        # the series with rows (the watermark may have skipped all of one's)
+        rest = [u for u, k in enumerate(n_l) if k]
+        if any(c.ctype not in _BULK_COLUMNS
+               for c in schema.data.columns[1:]):
+            return rest, 0
+        parts = list(map(self.partitions.get,
+                         map(self.part_set.get, dec.partkeys)))
+        cand = [u for u in rest if type(parts[u]) is TimeSeriesPartition
+                and parts[u].schema is schema]
+        if len(ts_s) > len(rest) and cand:
+            # a series whose rows are out of order among themselves drops
+            # some: ingest_block's
+            starts = np.asarray(at_l)
+            rows = np.flatnonzero(np.diff(ts_s) <= 0) + 1
+            rows = rows[~np.isin(rows, starts)]
+            if len(rows):
+                bad = set((np.searchsorted(starts, rows, "right") - 1)
+                          .tolist())
+                cand = [u for u in cand if u not in bad]
+        if not cand:
+            return rest, 0
+        empty = append_newer([parts[u] for u in cand],
+                             [at_l[u] for u in cand],
+                             [n_l[u] for u in cand], ts_s, cols_s)
+        # what was taken: the series, their ids by flush group, and the
+        # bulk rows that are the first of a buffer that held none
+        got, pids, opened, added = [], [], [], 0
+        groups, first = groups_r.tolist(), dec.uniq_first.tolist()
+        by_group: dict = {}
+        for u, e in zip(cand, empty):
+            if e is None:
+                continue
+            pid = parts[u].part_id
+            got.append(u)
+            pids.append(pid)
+            by_group.setdefault(groups[first[u]], []).append(pid)
+            if e:
+                opened.append(added)
+            added += n_l[u]
+        if not got:
+            return rest, 0
+        if len(got) < len(rest):
+            taken = set(got)
+            rest = [u for u in rest if u not in taken]
+        else:
+            rest = []
+        self._batch_bulk = len(got)
+        self.stats.rows_ingested += added
+        for pid in self.index.closed(pids):
+            self.index.mark_active(pid)
+        with self._dirty_lock:
+            for g, ids in by_group.items():
+                self._dirty_partkeys[g].update(ids)
+        if self.device_caches:
+            if added == len(ts_s):
+                # every row of the container, in order
+                ts_b, cols_b = ts_s, cols_s
+            else:
+                rows = np.fromiter(itertools.chain.from_iterable(
+                    range(at_l[u], at_l[u + 1]) for u in got), np.int64,
+                    added)
+                ts_b, cols_b = ts_s[rows], [c[rows] for c in cols_s]
+            pid_rows = pids if added == len(got) else [
+                pid for u, pid in zip(got, pids) for _ in range(n_l[u])]
+            # each grid of the schema told once (a snapshot: a query's
+            # thread may add a cache meanwhile)
+            for (shash, cid), cache in tuple(self.device_caches.items()):
+                if shash == schema.schema_hash:
+                    cache.note_append_rows(pid_rows, ts_b, cols_b[cid - 1],
+                                           opened)
+        return rest, added
+
     @staticmethod
     def _ingest_series_block(part, ts: np.ndarray, cols: list
                              ) -> tuple[int, int]:
@@ -365,7 +490,6 @@ class TimeSeriesShard:
         (one scheme, one width — the overwhelmingly common case);
         otherwise the run ingests per record so bucket-scheme-switch
         semantics (buffer freeze) match the slow path exactly."""
-        from filodb_tpu.native.ingestfast import HistColumn
         block_cols: list = []
         uniform = True
         for c in cols:

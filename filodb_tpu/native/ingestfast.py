@@ -249,14 +249,16 @@ def decode(container: bytes, schemas: Schemas) -> Optional[DecodedContainer]:
                     return None     # malformed / oversized: Python path
                 cols.append(hc)
                 continue
-            raw = vals[:n, c].copy()
+            raw = vals[:n, c]
             cols.append(raw.view(np.float64)
                         if col.ctype == ColumnType.DOUBLE else raw)
-    partkeys = [buf[int(pk_off[i]):int(pk_off[i]) + int(pk_len[i])]
-                for i in range(nu)]
+    partkeys = [buf[o:o + k] for o, k in zip(pk_off[:nu].tolist(),
+                                              pk_len[:nu].tolist())]
+    # views of this call's buffers, not copies (a copy lets the
+    # interpreter go): the shard's ingest lets them go with the container
     return DecodedContainer(
         schema_hash=int(schema_hash.value) if n else 0,
-        ts=ts[:n].copy(), cols=cols,
-        shard_hashes=shard_h[:n].copy(), part_hashes=part_h[:n].copy(),
-        uniq_idx=uniq[:n].copy(), partkeys=partkeys,
-        uniq_first=uniq_first[:nu].copy())
+        ts=ts[:n], cols=cols,
+        shard_hashes=shard_h[:n], part_hashes=part_h[:n],
+        uniq_idx=uniq[:n], partkeys=partkeys,
+        uniq_first=uniq_first[:nu])

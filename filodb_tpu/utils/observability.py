@@ -304,6 +304,11 @@ def ingest_metrics() -> dict:
             "filodb_ingest_replica_publish_failures_total",
             "per-replica container deliveries that failed (the replica "
             "lags and must catch up from its checkpoint/broker)"),
+        "series": REGISTRY.counter(
+            "filodb_ingest_series_total",
+            "series of the containers a shard ingested, by the path they "
+            "took (path=bulk: a container at a time; path=series: one "
+            "at a time)"),
     }
 
 

@@ -17,6 +17,7 @@ COUNTS: the open block is appended to and never rebuilt for an ingest epoch
 A scenario runs ONCE (a server each); the tests read what it recorded.
 """
 
+import collections
 import functools
 import json
 import threading
@@ -57,14 +58,15 @@ STAGING = "sum(rate(m[5m]))"      # every series into the device store
 
 
 class Data:
-    """``NS x PER`` counter series, a scrape every 15 s at a phase of the
+    """``ns x PER`` counter series, a scrape every 15 s at a phase of the
     series' own, ``rows`` loaded and ``live`` streamed; whole numbers."""
 
     def __init__(self, rows: int, live: int, seed: int, extra: int = 0,
-                 extra_from: int = 0):
+                 extra_from: int = 0, ns: int = NS):
         rng = np.random.default_rng(seed)
-        self.rows, self.live = rows, live
-        self.n = n = NS * PER + extra
+        self.rows, self.live, self.ns = rows, live, ns
+        self.loaded = ns * PER               # the series set-up loads
+        self.n = n = self.loaded + extra
         self.phase = rng.integers(1, STEP, n)
         self.ts = (BASE + np.arange(rows + live, dtype=np.int64)[None, :]
                    * STEP + self.phase[:, None])
@@ -74,12 +76,12 @@ class Data:
         # the ``extra`` series: first seen in a live container, at row
         # ``extra_from``
         self.first_row = np.zeros(n, np.int64)
-        self.first_row[NS * PER:] = extra_from
+        self.first_row[self.loaded:] = extra_from
         # rows that never arrive (dropped by ingest): not the oracle's
         self.gone = np.zeros((n, rows + live), bool)
 
     def tags(self, s: int) -> dict:
-        ns = s // PER if s < NS * PER else (s - NS * PER) % NS
+        ns = s // PER if s < self.loaded else (s - self.loaded) % self.ns
         return {"_metric_": "m", "_ws_": "demo", "_ns_": f"App-{ns:04d}",
                 "instance": f"i{s:07d}"}
 
@@ -122,10 +124,10 @@ class Node:
         self.port = self.server.http.port
         self.shard = self.server.memstore.shards("prom")[0]
         self.visible = np.zeros(data.n, np.int64)   # rows a series has
-        loaded = {s: np.arange(data.rows) for s in range(NS * PER)}
+        loaded = {s: np.arange(data.rows) for s in range(data.loaded)}
         self.post(loaded)
         self.server.flush_all()
-        self.visible[:NS * PER] = data.rows
+        self.visible[:data.loaded] = data.rows
         self.mismatches: dict = {}
         self.asked = 0
 
@@ -561,6 +563,91 @@ def test_concurrent_ingest_appends_and_never_rebuilds():
     # many of the 90 containers or fewer)
     assert 6 <= out["appends"] <= 48
     assert out["builds"] <= 2          # block 0 frozen, then open, once
+
+
+# ------------------------------------------- at the live cell's size
+
+# 6 000 series: the live cell's containers hold ~6 800, one row each
+LIVE_NS = 750
+
+
+@functools.lru_cache(maxsize=None)
+def at_scale() -> dict:
+    """Containers of one row for every one of 6 000 known series (the
+    live cell's shape), the four panels after each.  COUNTS a container:
+    the grid's bulk hook and its one-series hook, ``ingest_block`` (the
+    per-series path's write), the append program's launches, and the
+    series the bulk path took by ``filodb_ingest_series_total``."""
+    from filodb_tpu.memstore.partition import TimeSeriesPartition
+    from filodb_tpu.utils.observability import REGISTRY
+    d = Data(60, 4, seed=39, ns=LIVE_NS)
+    node = Node(d)
+    calls = collections.Counter()
+    real_block = TimeSeriesPartition.ingest_block
+
+    def block(part, *args):
+        calls["ingest_block"] += 1
+        return real_block(part, *args)
+
+    try:
+        warm(node, 3)
+        node.query("every_series", 0, d.edge(d.rows - 1))   # stage all
+        cache = node.cache
+        for name in ("note_append_rows", "note_append"):
+            real = getattr(cache, name)
+            setattr(cache, name, lambda *a, _n=name, _f=real: (
+                calls.update([_n]), _f(*a))[1])
+        TimeSeriesPartition.ingest_block = block
+        series = REGISTRY.counter("filodb_ingest_series_total")
+        per = []
+        for row in range(d.rows, d.rows + d.live):
+            was = (calls.copy(), cache.appends,
+                   series.value(dataset="prom", shard=0, path="bulk"))
+            node.post({s: [row] for s in range(d.n)})
+            # (the counter moves just after the epoch bump post waits for)
+            deadline = time.time() + 10
+            while time.time() < deadline and series.value(
+                    dataset="prom", shard=0, path="bulk") - was[2] < d.n:
+                time.sleep(0.001)
+            per.append(dict(
+                calls=dict(calls - was[0]), appends=cache.appends - was[1],
+                bulk=series.value(dataset="prom", shard=0, path="bulk")
+                - was[2]))
+            node.check(f"row {row}", 3, d.edge(row))
+        return dict(per=per, mismatches=node.mismatches, asked=node.asked,
+                    n=d.n)
+    finally:
+        TimeSeriesPartition.ingest_block = real_block
+        node.close()
+
+
+def test_at_the_live_cells_size_every_answer_is_the_oracles():
+    out = at_scale()
+    assert out["n"] >= 6000 and out["asked"] >= 4 * 6
+    assert not out["mismatches"], {k: v[:3]
+                                   for k, v in out["mismatches"].items()}
+
+
+@pytest.mark.parametrize("what", ["hook", "append", "ingest_block",
+                                  "counter"])
+def test_a_live_container_is_written_a_container_at_a_time(what):
+    """Counts, not times, a container: the bulk hook ONCE and the
+    one-series hook never; one launch of the append program (the first
+    container's rows reach no open block: its range is the loaded rows'
+    frozen block until the panels after it build it open); no
+    ``ingest_block`` for a known one-row series; the counter's ``bulk``
+    moves by the container's series."""
+    out = at_scale()
+    for k, got in enumerate(out["per"]):
+        if what == "hook":
+            assert got["calls"].get("note_append_rows") == 1, got
+            assert "note_append" not in got["calls"], got
+        if what == "append":
+            assert got["appends"] == (0 if k == 0 else 1), got
+        if what == "ingest_block":
+            assert "ingest_block" not in got["calls"], got
+        if what == "counter":
+            assert got["bulk"] == out["n"], got
 
 
 # -------------------------------------------------- the pieces, directly
