@@ -105,6 +105,29 @@ class _Listener(ThreadingHTTPServer):
 
     request_queue_size = 128
 
+    def get_request(self):
+        """``accept``, with the moment it returned beside the address:
+        the handler's ``http.accept`` span begins there."""
+        request, address = super().get_request()
+        return request, (address, time.perf_counter())
+
+    def process_request(self, request, client_address):
+        """The stdlib's, as the ``http.spawn`` stage: making and starting
+        the handler thread.  ``Thread.start`` waits until the new thread
+        has run, which needs the interpreter, and while it waits the
+        accept loop accepts nothing: wall x request rate is the
+        listener's busy share.  This thread is every request's serial
+        path, so it only reads two clocks and leaves the span to the
+        next flush of another thread (``defer``)."""
+        wall, t0 = time.time(), time.perf_counter()
+        super().process_request(request, client_address)
+        TRACER.defer("http.spawn", time.perf_counter() - t0,
+                     start_s=wall, stage=True)
+
+    def finish_request(self, request, client_address):
+        address, accepted = client_address
+        self.RequestHandlerClass(request, address, self, accepted)
+
 
 @dataclass
 class DatasetBinding:
@@ -210,8 +233,37 @@ class FiloHttpServer:
         server = self
 
         class Handler(BaseHTTPRequestHandler):
+            def __init__(self, request, client_address, listener,
+                         accepted):
+                self.accepted = accepted    # perf_counter after accept
+                self.read = None
+                super().__init__(request, client_address, listener)
+
             def log_message(self, fmt, *args):  # silence stdlib logging
                 pass
+
+            def handle(self):
+                # thread start, its first wait for the interpreter and
+                # setup() lie behind this line; the request line, the
+                # headers and the dispatch to do_* ahead of it
+                self.handled = time.perf_counter()
+                self.read = TRACER.stage("http.read").opened(self.handled)
+                try:
+                    super().handle()
+                finally:
+                    if self.read is not None:   # no route ran
+                        self.read.closed()
+
+            def front_spans(self) -> tuple:
+                """``http.accept`` and ``http.read``, closed now: the
+                connection's first request alone has them."""
+                read, self.read = self.read, None
+                if read is None:
+                    return ()
+                read.closed()
+                accept = TRACER.stage("http.accept", leaf=False) \
+                    .opened(self.accepted).closed(self.handled)
+                return (accept, read)
 
             def do_GET(self):
                 server._handle(self, "GET")
@@ -235,11 +287,17 @@ class FiloHttpServer:
 
     def _handle(self, req: BaseHTTPRequestHandler, method: str) -> None:
         """One request as the ``http.request`` stage, from entry to
-        after the write.  It encloses the others, so it is no leaf."""
+        after the write.  It encloses the others, so it is no leaf.  The
+        spans before it (``http.accept``, ``http.read``) end here and
+        join the trace of the query it ran, as ``http.encode`` does."""
+        front = req.front_spans()
         self._answered.tok = None
         with TRACER.stage("http.request", leaf=False, cpu=True,
                           method=method):
-            self._handle_request(req, method)
+            try:
+                self._handle_request(req, method)
+            finally:
+                TRACER.adopt(self._answered.tok, front)
 
     def _handle_request(self, req: BaseHTTPRequestHandler,
                         method: str) -> None:

@@ -1105,4 +1105,7 @@ def device_summary() -> dict:
         # the stage clock's process-wide totals since start
         # ({count, wall_s, cpu_s} per stage span name)
         "stages": TRACER.stages.snapshot(),
+        # False once leaf stages can no longer be annotated: a profile
+        # then names no idle gap after the program's stages
+        "annotations": TRACER.annotating,
     }

@@ -1182,8 +1182,9 @@ class Tracer:
         """:meth:`record` for a caller that may take no lock and call no
         reporter: a ``gc.callbacks`` hook runs on whichever thread set
         the collection off, at whatever point it had reached, and that
-        can be inside the stage table's lock or a reporter's.  The span
-        is reported by the next flush on any thread, or the next read of
+        can be inside the stage table's lock or a reporter's; the HTTP
+        accept thread is every request's serial path.  The span is
+        reported by the next flush on any thread, or the next read of
         the stage table."""
         self._deferred.append((name, duration_s, kw))
 
@@ -1202,10 +1203,19 @@ class Tracer:
             ann = self._annotate = _jax_annotation()
         return ann
 
+    @property
+    def annotating(self) -> bool:
+        """False once leaves can no longer be annotated (jax missing, or
+        the annotation failed): a profile then names no idle gap after a
+        program stage, however busy the program is."""
+        return self._annotate is not None
+
     def _leaf_annotation(self, name: str):
         """An entered profiler annotation for ``name``, or None: jax
         missing or the annotation failing degrades to no annotation,
-        never to an error on the request."""
+        never to an error on the request.  The first failure is said
+        once on stderr: from then on no trace of this process carries
+        a program name on its host plane."""
         try:
             ann = self._annotation_class()
             if ann is None:
@@ -1213,9 +1223,29 @@ class Tracer:
             cm = ann(name)
             cm.__enter__()
             return cm
-        except Exception:  # noqa: BLE001 — tracing never fails the work
-            self._annotate = None
+        except Exception as e:  # noqa: BLE001 — tracing never fails the work
+            if self._annotate is not None:
+                self._annotate = None
+                try:
+                    print(f"tracer: leaf annotations off for this process: "
+                          f"{name}: {e!r}", file=sys.stderr, flush=True)
+                except Exception:  # noqa: BLE001
+                    pass
             return None
+
+    def adopt(self, token, spans: Sequence["_Span"]) -> None:
+        """Hand ``spans``, opened and closed off the span stack
+        (:meth:`_Span.opened`), to this thread's next flush, in the
+        trace of ``token`` (``capture``'s ``(trace id, span id)``, or
+        None): they fold into the stage table with what the thread
+        finishes next, under no lock of their own."""
+        st = self._state()
+        trace_id, parent_id = token or (None, None)
+        for sp in spans:
+            sp.trace_id, sp.parent_hint = trace_id, parent_id
+        st.done[:0] = spans
+        if not st.depth:
+            self._flush(st)
 
     def flush(self) -> None:
         """Hand over what this thread has finished now: a reader of the
@@ -1359,6 +1389,33 @@ class _Span:
     def end(self) -> None:
         self.__exit__(None, None, None)
 
+    def opened(self, t0: Optional[float] = None) -> "_Span":
+        """Open the interval OFF the thread's span stack, at ``t0`` (a
+        ``perf_counter`` reading, taken on any thread) or now: for an
+        interval that begins before the code that owns it runs, or on
+        another thread (a request's accept and read, before its
+        handler's first span).  :meth:`closed` ends it and
+        ``Tracer.adopt`` hands it to a thread's flush.  No CPU clock."""
+        if self.leaf:
+            self._ann = self.tracer._leaf_annotation(self.name)
+        self._t0 = time.perf_counter() if t0 is None else t0
+        return self
+
+    def closed(self, t1: Optional[float] = None) -> "_Span":
+        """End an :meth:`opened` interval at ``t1`` or now."""
+        self.duration_s = (time.perf_counter() if t1 is None else t1) \
+            - self._t0
+        if self._ann is not None:
+            self._exit_annotation()
+        return self
+
+    def _exit_annotation(self) -> None:
+        try:  # spans must NEVER raise into the instrumented path
+            self._ann.__exit__(None, None, None)
+        except Exception:  # noqa: BLE001
+            pass
+        self._ann = None
+
     def __exit__(self, exc_type, exc, tb):
         self.duration_s = time.perf_counter() - self._t0
         if self.cpu:
@@ -1367,11 +1424,7 @@ class _Span:
             # and only the sums over many spans mean anything
             self.cpu_s = max(time.thread_time() - self._c0, 0.0)
         if self._ann is not None:
-            try:  # spans must NEVER raise into the instrumented path
-                self._ann.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass
-            self._ann = None
+            self._exit_annotation()
         if exc is not None:
             self.error = repr(exc)
         st = self._st
@@ -1424,12 +1477,17 @@ class GcPauseWatch:
         self._tracer = tracer
         tracer._annotation_class()      # no import from inside the hook
         self.seconds = [0.0, 0.0, 0.0]  # by generation; the hook alone adds
+        # each full collection's start and end: odd while one runs.  A
+        # collection runs its finalizers' Python code before its end, and
+        # another thread may take the interpreter then
+        self.full_edges = 0
         self._t0 = self._wall0 = self._cpu0 = self._ann = None
 
     def __call__(self, phase: str, info: dict) -> None:
         gen = info["generation"]
         if phase == "start":
             if gen == 2:
+                self.full_edges += 1
                 self._ann = self._tracer._leaf_annotation("gc.pause")
                 self._cpu0 = time.thread_time()
             self._wall0 = time.time()
@@ -1440,6 +1498,8 @@ class GcPauseWatch:
         dur = time.perf_counter() - self._t0
         self._t0 = None
         self.seconds[gen] += dur
+        if gen == 2:
+            self.full_edges += 1
         if gen < 2 and dur <= self.SLOW_S:
             return
         cpu = 0.0               # the CPU clock is read for full ones only
@@ -1497,7 +1557,23 @@ class StallWatch:
     the top frames of every thread as they stand when the watch got to
     run again: the thread that held everything up is usually still at
     the call it was in.  A collection of half a second stays under the
-    limit; the watch costs a wake-up a tick."""
+    limit; the watch costs a wake-up a tick.
+
+    A tick's lateness is also a sample of what it costs a thread to get
+    the interpreter back under the load of that moment: the stage
+    ``interp.wait`` (``count`` the ticks, ``wall_s`` the lateness).  The
+    reference is read right before the wait, so one sample is one
+    reacquisition, from the wait's due end to the first bytecode after
+    it: the switch interval a waiter sits out before it may ask the
+    holder to drop, and any C call that holds the lock without
+    checking, are in it; the watch's own clock and ``/proc/stat`` reads
+    are not.  A tick that a full collection overlapped (one ran, began
+    or ended between the two reads), or that ran over the limit, is no
+    sample: the collector's seconds are ``gc.pause``'s and a stop's are
+    ``host.stall``'s, and one such tick would outweigh hundreds of
+    others in the mean.  The sample also holds the timer's own lateness
+    in waking the thread, which an idle process reads alone.  It
+    carries no trace id, so no trace keeps it."""
 
     TICK_S = 0.1
     LIMIT_S = 1.0
@@ -1523,6 +1599,9 @@ class StallWatch:
     def _collected(self) -> float:
         return sum(_GC_WATCH.seconds) if _GC_WATCH is not None else 0.0
 
+    def _full_edges(self) -> int:
+        return _GC_WATCH.full_edges if _GC_WATCH is not None else 0
+
     def start(self) -> "StallWatch":
         if self._thread is None:
             self._thread = threading.Thread(target=self._run, daemon=True,
@@ -1535,15 +1614,22 @@ class StallWatch:
 
     def _run(self) -> None:
         while not self._stop.is_set():
-            wall0, t0 = time.time(), time.perf_counter()
-            cpu0, mach0, gc0 = (time.process_time(), self._machine(),
-                                self._collected())
+            wall0 = time.time()
+            cpu0, mach0, gc0, full0 = (time.process_time(),
+                                       self._machine(), self._collected(),
+                                       self._full_edges())
+            t0 = time.perf_counter()
             self._stop.wait(self.TICK_S)
             late = time.perf_counter() - t0 - self.TICK_S
-            if late > self.LIMIT_S and not self._stop.is_set():
+            if self._stop.is_set():
+                return
+            if late > self.LIMIT_S:
                 self._report(wall0, late, time.process_time() - cpu0,
                              mach0, self._machine(),
                              self._collected() - gc0)
+            elif self._full_edges() == full0 and not full0 % 2:
+                self._tracer.record("interp.wait", max(late, 0.0),
+                                    stage=True)
 
     def _report(self, wall0, late, cpu, mach0, mach1, collected) -> None:
         note = {"late_s": round(late, 3), "process_cpu_s": round(cpu, 3),

@@ -22,8 +22,8 @@ METRICS = sorted((ROOT / "benchmark" / "metrics").glob("*.json"))
 # a stage span (and the ``timings`` bucket it fills), a deferred span, or
 # a bucket written by hand
 _EMIT = re.compile(
-    r"""(?:\.stage|\.defer|TRACER\.record|\.add_timing|timings\.setdefault"""
-    r"""|_leaf_annotation)\(\s*["']([\w.]+)["']""")
+    r"""(?:\.stage|\.defer|(?:TRACER|_tracer)\.record|\.add_timing"""
+    r"""|timings\.setdefault|_leaf_annotation)\(\s*["']([\w.]+)["']""")
 
 
 @pytest.fixture(scope="module")
@@ -55,6 +55,32 @@ def test_metric_reads_names_the_program_emits(path, emitted):
     assert not missing, \
         f"{path.stem}: no stage, span or add_timing named {missing} under " \
         f"filodb_tpu/: the metric would read null"
+
+
+@pytest.mark.parametrize("field", ["count", "wall_s", "cpu_s"])
+def test_stage_rows_carry_the_field_the_readers_read(field):
+    """``stage_delta`` reads a row's ``field`` out of ``stages`` of
+    ``/admin/device``, and reads a missing one as 0: a column renamed
+    would read 0 in every cell, not ``null``."""
+    from filodb_tpu.utils.devicewatch import device_summary
+    from filodb_tpu.utils.observability import TRACER
+    with TRACER.stage("test.contract_probe", leaf=False, cpu=True):
+        pass
+    row = device_summary()["stages"]["test.contract_probe"]
+    assert field in row and row["count"] >= 1
+    read = {json.loads(p.read_text())["args"].get("field") for p in METRICS
+            if json.loads(p.read_text())["reader"] == "stage_delta"}
+    assert read <= {"count", "wall_s", "cpu_s"}
+
+
+@pytest.mark.parametrize("span", [
+    "http.spawn", "http.accept", "http.read", "interp.wait"])
+def test_front_end_and_interpreter_stage_is_emitted(span, emitted):
+    """The front end's spans from ``accept`` to the route and the
+    interpreter's wait (doc/observability.md "Stage spans"), read
+    by ``http_spawn_ms``, ``http_accept_ms``, ``http_read_ms`` and
+    ``interp_wait_ms`` in every cell."""
+    assert span in emitted
 
 
 def _run_py_constant(name: str):

@@ -5,6 +5,7 @@ invariants, the Gauge set_fn-under-lock deadlock regression, scheduler
 saturation metrics, and the TraceStore/slow-log/profiler units."""
 
 import re
+import sys
 import threading
 import time
 
@@ -580,6 +581,87 @@ class TestStageClock:
         assert span_from_dict(span_to_dict(rec)).cpu_s == 0.125
         assert span_from_dict({"name": "old-node"}).cpu_s == 0.0
 
+    def test_the_cpu_clock_is_the_threads_to_the_digit(self, tracer,
+                                                       monkeypatch):
+        """``cpu_s`` is ``thread_time``'s difference over the span, and
+        the stage row sums it unchanged (``host_cpu_ms_per_query``)."""
+        ticks = iter([1.0, 1.25])
+        monkeypatch.setattr(time, "thread_time", lambda: next(ticks))
+        with tracer.stage("http.request", leaf=False, cpu=True) as sp:
+            pass
+        assert sp.cpu_s == 0.25
+        assert tracer.stages.snapshot()["http.request"] == {
+            "count": 1, "wall_s": sp.duration_s, "cpu_s": 0.25}
+
+    # -- spans that begin before their thread's first span
+
+    def test_a_span_opened_off_the_stack_joins_the_next_flush(self, tracer):
+        """``opened`` / ``closed`` / ``adopt``: an interval that began on
+        another thread or before the code that owns it; it folds in with
+        what its thread finishes next, in the trace it is given."""
+        batches = []
+        tracer.add_reporter(lambda recs: batches.append(
+            [r.name for r in recs]))
+        t0 = time.perf_counter()
+        time.sleep(0.002)
+        t1 = time.perf_counter()
+        accept = tracer.stage("http.accept", leaf=False).opened(t0) \
+            .closed(t1)
+        read = tracer.stage("http.read").opened(t1)
+        assert _FakeAnnotation.log == [("enter", "http.read")]
+        read.closed()
+        assert _FakeAnnotation.log[-1] == ("exit", "http.read")
+        assert accept.duration_s == pytest.approx(t1 - t0)
+        assert tracer.current_span() is None       # never on the stack
+        token = (tracer.new_trace_id(), "00000000000000aa")
+        with tracer.stage("http.request", leaf=False):
+            tracer.adopt(token, (accept, read))
+            assert batches == []                   # waits for the flush
+        assert batches == [["http.accept", "http.read", "http.request"]]
+        recs = {r.name: r for r in tracer.recs}
+        for name in ("http.accept", "http.read"):
+            assert recs[name].trace_id == token[0]
+            assert recs[name].parent_id == token[1]
+        assert recs["http.read"].start_s == pytest.approx(
+            recs["http.accept"].start_s + accept.duration_s, abs=1e-6)
+        assert tracer.stages.snapshot()["http.accept"]["count"] == 1
+        # outside any span, adopting flushes at once
+        tracer.adopt(None, (tracer.stage("http.read").opened().closed(),))
+        assert tracer.stages.snapshot()["http.read"]["count"] == 2
+        assert tracer.recs[-1].trace_id is None
+
+    # -- a failing annotation is said, once, and shown
+
+    def test_a_failing_annotation_is_said_once(self, tracer, capsys):
+        raised = []
+
+        def flaky(name):
+            if not raised:
+                raised.append(name)
+                raise RuntimeError("profiler gone")
+            return _FakeAnnotation(name)
+
+        tracer._annotate = flaky
+        assert tracer.annotating
+        for _ in range(3):
+            with tracer.stage("grid.dispatch"):
+                pass
+        err = capsys.readouterr().err
+        assert err.count("leaf annotations off") == 1
+        assert "profiler gone" in err and "grid.dispatch" in err
+        assert not tracer.annotating
+        assert tracer.stages.snapshot()["grid.dispatch"]["count"] == 3
+
+    def test_admin_device_shows_whether_leaves_are_annotated(self,
+                                                             monkeypatch):
+        from filodb_tpu.utils import devicewatch
+        from filodb_tpu.utils.observability import TRACER
+        monkeypatch.setattr(TRACER, "_annotate", _FakeAnnotation)
+        assert devicewatch.device_summary()["annotations"] is True
+        monkeypatch.setattr(TRACER, "_annotate", None)
+        summary = devicewatch.device_summary()
+        assert summary["annotations"] is False and "stages" in summary
+
 
 class TestStallWatch:
     """A stop of the interpreter for longer than the limit becomes a
@@ -633,6 +715,139 @@ class TestStallWatch:
         a = obs.install_stall_watch()
         assert obs.install_stall_watch() is a and a._thread.is_alive()
 
+    # -- interp.wait: every tick's lateness, one reacquisition a sample
+
+    def _sampling(self, tracer, tick=0.01):
+        from filodb_tpu.utils.observability import StallWatch
+        watch = StallWatch(tracer, out=None)
+        watch.TICK_S = tick
+        return watch
+
+    @staticmethod
+    def _row(tracer):
+        return tracer.stages.snapshot().get(
+            "interp.wait", {"count": 0, "wall_s": 0.0})
+
+    def test_each_tick_is_one_interp_wait(self, tracer, monkeypatch):
+        watch = self._sampling(tracer)
+        ticks = []
+        monkeypatch.setattr(watch, "_collected", lambda: ticks.append(1)
+                            or 0.0)
+        watch.start()
+        time.sleep(0.2)
+        watch.stop()
+        watch._thread.join(5)
+        assert not watch._thread.is_alive()
+        row = self._row(tracer)
+        # the last tick's wait is cut short by the stop and samples nothing
+        assert len(ticks) - 1 <= row["count"] <= len(ticks)
+        assert row["count"] >= 5
+        assert row["wall_s"] >= 0.0
+        assert "host.stall" not in tracer.stages.snapshot()
+        # no trace id: no trace keeps ten records a second
+        assert all(r.trace_id is None for r in tracer.recs)
+
+    def test_a_held_interpreter_reads_about_the_switch_interval(
+            self, tracer):
+        """A thread that runs pure Python keeps the interpreter until a
+        waiter has sat out the switch interval and asked for it: each
+        sample reads at least most of that interval."""
+        interval = 0.02
+        old = sys.getswitchinterval()
+        watch = self._sampling(tracer)
+        watch.start()
+        try:
+            time.sleep(0.05)
+            sys.setswitchinterval(interval)
+            before = self._row(tracer)
+            end = time.perf_counter() + 0.6
+            n = 0
+            while time.perf_counter() < end:    # bytecode, no release
+                n += 1
+            after = self._row(tracer)
+        finally:
+            sys.setswitchinterval(old)
+            watch.stop()
+            watch._thread.join(5)
+        assert not watch._thread.is_alive()
+        count = after["count"] - before["count"]
+        assert count >= 3
+        mean = (after["wall_s"] - before["wall_s"]) / count
+        assert mean >= 0.6 * interval, (mean, count)
+
+    def test_the_sample_leaves_out_the_watchs_own_reads(self, tracer,
+                                                        monkeypatch):
+        """``/proc/stat``, the process clock and the collector's seconds
+        are read before the reference: a slow read is no lateness."""
+        watch = self._sampling(tracer)
+        slow = 0.1
+        monkeypatch.setattr(watch, "_machine",
+                            lambda: time.sleep(slow) or None)
+        watch.start()
+        time.sleep(0.5)
+        watch.stop()
+        watch._thread.join(5)
+        assert not watch._thread.is_alive()
+        row = self._row(tracer)
+        assert row["count"] >= 2
+        assert row["wall_s"] / row["count"] < slow / 2
+
+    @pytest.mark.parametrize("collector,sampled", [
+        ("a full one began and ended", False),
+        ("a full one runs its finalizers", False),
+        ("young ones only", True)])
+    def test_a_tick_a_full_collection_overlapped_is_no_sample(
+            self, tracer, monkeypatch, collector, sampled):
+        """A full collection's seconds are ``gc.pause``'s: one such tick
+        would outweigh hundreds of others in the mean.  A collection runs
+        its finalizers' Python code before its end, so the watch may read
+        in the middle of one.  A young one is part of the holder's turn
+        and stays in."""
+        from filodb_tpu.utils import observability as obs
+
+        class Collector:
+            seconds = [0.0, 0.0, 0.0]
+
+            def __init__(self):
+                self.reads, self.edges = 0, 0
+
+            @property
+            def full_edges(self):
+                self.reads += 1
+                if collector == "a full one began and ended":
+                    self.edges += 2
+                elif collector == "a full one runs its finalizers":
+                    self.edges = 1
+                return self.edges
+
+        gc_watch = Collector()
+        monkeypatch.setattr(obs, "_GC_WATCH", gc_watch)
+        watch = self._sampling(tracer)
+        watch.start()
+        time.sleep(0.2)
+        watch.stop()
+        watch._thread.join(5)
+        assert not watch._thread.is_alive()
+        assert gc_watch.reads >= 10             # five ticks at least
+        count = self._row(tracer)["count"]
+        assert (count >= 5) if sampled else (count == 0), count
+
+    def test_a_tick_over_the_limit_is_a_stall_and_no_sample(self, tracer):
+        import io
+        from filodb_tpu.utils.observability import StallWatch
+        out = io.StringIO()
+        watch = StallWatch(tracer, out=out)
+        watch.TICK_S, watch.LIMIT_S = 0.01, -1.0    # every tick is over
+        watch.start()
+        time.sleep(0.2)
+        watch.stop()
+        watch._thread.join(5)
+        table = tracer.stages.snapshot()
+        assert table["host.stall"]["count"] >= 3
+        assert "interp.wait" not in table
+        assert out.getvalue().count("host stall: {") == len(
+            [r for r in tracer.recs if r.name == "host.stall"])
+
 
 class TestGcPauseWatch:
     def _watch(self, tracer):
@@ -649,6 +864,7 @@ class TestGcPauseWatch:
             gc.callbacks.remove(watch)
         # the hook reports nothing itself: the span waits for a flush
         assert tracer.recs == [] and len(tracer._deferred) == 1
+        assert watch.full_edges == 2            # began and ended: even
         row = tracer.stages.snapshot()["gc.pause"]     # a read drains it
         recs = [r for r in tracer.recs if r.name == "gc.pause"]
         assert len(recs) == 1

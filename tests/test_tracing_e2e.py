@@ -643,6 +643,82 @@ class TestStageClockEndpoints:
         assert not any(k.startswith("http.")
                        for k in body["data"]["stats"]["timings"])
 
+    FRONT = ("http.spawn", "http.accept", "http.read")
+
+    def _counts(self):
+        """The four rows once no handler has anything left to hand over
+        (a handler flushes after its client holds the answer)."""
+        from filodb_tpu.utils.observability import TRACER
+        none = {"count": 0, "wall_s": 0.0, "cpu_s": 0.0}
+        last = None
+        for _ in range(100):
+            table = TRACER.stages.snapshot()
+            rows = {name: table.get(name, none)
+                    for name in self.FRONT + ("http.request",)}
+            if rows == last:
+                return rows
+            last = rows
+            time.sleep(0.05)
+        return last
+
+    def test_a_request_adds_one_of_each_front_stage(self, grid):
+        """From ``accept`` to the route: the listener's thread start, the
+        handler's way to ``handle()`` and the request line and headers,
+        one span each a request; ``http.request`` as before."""
+        n = 5
+        before = self._counts()
+        for _ in range(n):
+            code, _b, _h = _grid_query(grid, self.QUERY)
+            assert code == 200
+        after = self._counts()
+        for name in self.FRONT + ("http.request",):
+            assert after[name]["count"] - before[name]["count"] == n, name
+            assert after[name]["wall_s"] > before[name]["wall_s"], name
+        # only http.request of the four reads the CPU clock
+        assert after["http.request"]["cpu_s"] > before["http.request"]["cpu_s"]
+        for name in self.FRONT:
+            assert after[name]["cpu_s"] == 0.0, name
+
+    def test_accept_and_read_join_the_querys_trace(self, grid):
+        code, body, _h = _grid_query(grid, self.QUERY)
+        tid = body["data"]["stats"]["traceId"]
+        for _ in range(200):
+            _c, tbody, _h = _get(grid, f"/admin/traces/{tid}")
+            roots = tbody["data"]["spans"]
+            kids = {n["name"]: n for r in roots for n in r["children"]}
+            if "http.read" in kids:
+                break
+            time.sleep(0.01)
+        assert [r["name"] for r in roots] == ["query"]
+        assert {"http.accept", "http.read", "http.encode"} <= set(kids)
+        accept, read = kids["http.accept"], kids["http.read"]
+        # the accept ends where the read begins, and both before the query
+        assert accept["start_s"] <= read["start_s"] <= roots[0]["start_s"]
+        assert read["start_s"] == pytest.approx(
+            accept["start_s"] + accept["duration_s"], abs=1e-6)
+        assert "http.spawn" not in kids       # the listener's: no trace
+
+    def test_the_read_is_annotated_and_the_waits_are_not(self, grid,
+                                                         monkeypatch):
+        from filodb_tpu.utils.observability import TRACER
+        seen = []
+
+        class Ann:
+            def __init__(self, name):
+                seen.append(name)
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(TRACER, "_annotate", Ann)
+        code, _b, _h = _grid_query(grid, self.QUERY)
+        assert code == 200
+        assert "http.read" in seen
+        assert not {"http.accept", "http.spawn"} & set(seen)
+
     def test_plan_bucket_is_the_plan_spans_duration(self, grid):
         _c, body, _h = _grid_query(grid, self.QUERY)
         tid = body["data"]["stats"]["traceId"]
