@@ -15,6 +15,7 @@ import contextlib
 import functools
 import json
 import math
+import queue
 import threading
 import time
 import urllib.parse
@@ -35,7 +36,8 @@ from filodb_tpu.promql.parser import (ParseError,
 from filodb_tpu.query.exec import ExecContext
 from filodb_tpu.query.model import (QueryContext, QueryError,
                                     ShardUnavailable)
-from filodb_tpu.utils.observability import (TRACER, insights_metrics,
+from filodb_tpu.utils.observability import (REGISTRY, TRACER,
+                                            insights_metrics,
                                             query_metrics,
                                             workload_metrics)
 from filodb_tpu.workload import deadline as wdl
@@ -48,6 +50,12 @@ _MAX_REMOTE_UNCOMPRESSED = 128 * 1024 * 1024
 _METRICS = query_metrics()
 _WORKLOAD_M = workload_metrics()
 _INSIGHTS_M = insights_metrics()
+# connections a handler thread took: ``standing`` from the accept
+# thread's queue, ``started`` the first of a thread started for it
+_HANDOFFS = REGISTRY.counter(
+    "filodb_http_handoffs_total",
+    "connections handed to a standing handler thread or to one started "
+    "for them, by thread")
 
 
 def _timed(endpoint: str):
@@ -95,15 +103,37 @@ def _parse_downsample(v) -> int:
 
 
 class _Listener(ThreadingHTTPServer):
-    """The stdlib server with a listen queue a node can live with.  The
-    stdlib asks for 5: with more clients than that connecting while the
-    accept thread is away (a collection stops it half a second; a
-    dashboard's panels refresh together) the kernel drops the SYNs over
-    the queue and each such client waits out a retransmit, 1 s and then
-    3, before its request is even read — nine simultaneous connections
-    (eight sessions and a writer) lose three that way."""
+    """The stdlib server with a listen queue a node can live with, and
+    handler threads that stand.
+
+    The stdlib asks for a queue of 5: with more clients than that
+    connecting while the accept thread is away (a collection stops it
+    half a second; a dashboard's panels refresh together) the kernel
+    drops the SYNs over the queue and each such client waits out a
+    retransmit, 1 s and then 3, before its request is even read — nine
+    simultaneous connections (eight sessions and a writer) lose three
+    that way.
+
+    The stdlib starts a thread a connection, and ``Thread.start`` waits
+    until the new thread has run, which needs the interpreter, on the
+    one thread every request passes.  Here a handler thread, once
+    started, stays: it serves its connection, says it is idle and waits
+    on ``_handoff`` for the next.  The accept thread hands a connection
+    to an idle one; where none is idle it starts one, as the stdlib
+    does, so a handler that calls this same server is never left
+    waiting for a thread.  The threads are as many as the most
+    connections ever in flight at once."""
 
     request_queue_size = 128
+
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
+        # accepted connections for the standing threads, and the idle
+        # ones, each once for every wait on ``_handoff`` it is about to
+        # make: both C queues, so neither side waits for the other
+        self._handoff: queue.SimpleQueue = queue.SimpleQueue()
+        self._idle: queue.SimpleQueue = queue.SimpleQueue()
+        self._started = 0         # threads started: all stand till close
 
     def get_request(self):
         """``accept``, with the moment it returned beside the address:
@@ -112,17 +142,58 @@ class _Listener(ThreadingHTTPServer):
         return request, (address, time.perf_counter())
 
     def process_request(self, request, client_address):
-        """The stdlib's, as the ``http.spawn`` stage: making and starting
-        the handler thread.  ``Thread.start`` waits until the new thread
-        has run, which needs the interpreter, and while it waits the
-        accept loop accepts nothing: wall x request rate is the
-        listener's busy share.  This thread is every request's serial
-        path, so it only reads two clocks and leaves the span to the
-        next flush of another thread (``defer``)."""
+        """The ``http.spawn`` stage: the connection put on the queue of
+        an idle standing thread (tag ``thread`` = ``standing``), or, with
+        none idle, a thread started for it (``started``), which waits in
+        ``Thread.start`` until the new thread has run.  While it lasts
+        the accept loop accepts nothing: wall x request rate is the
+        listener's busy share in the hand-off.  This thread is every
+        request's serial path, so it only reads two clocks and leaves
+        the span to the next flush of another thread (``defer``)."""
         wall, t0 = time.time(), time.perf_counter()
-        super().process_request(request, client_address)
+        try:
+            self._idle.get_nowait()
+        except queue.Empty:
+            how = "started"
+            t = threading.Thread(target=self._stand,
+                                 args=((request, client_address),),
+                                 name=f"filo-http-{self._started}",
+                                 daemon=True)
+            self._started += 1
+            t.start()
+        else:
+            how = "standing"
+            self._handoff.put((request, client_address))
         TRACER.defer("http.spawn", time.perf_counter() - t0,
-                     start_s=wall, stage=True)
+                     start_s=wall, stage=True, thread=how)
+
+    def _stand(self, job) -> None:
+        """A handler thread's life: the connection it was started for,
+        then each one handed to it, until ``server_close``'s ``None``.
+        The stdlib's ``process_request_thread`` serves each: the handler,
+        ``handle_error``, the socket closed."""
+        me, how = threading.current_thread(), "started"
+        while job is not None:
+            _HANDOFFS.inc(thread=how)
+            self.process_request_thread(*job)
+            self._idle.put(me)
+            job, how = self._handoff.get(), "standing"
+
+    def server_close(self) -> None:
+        """Closes the socket, tells every standing thread to stop and
+        joins those idle now.  One still serving a request stops after
+        it: a client that never sends its request line would otherwise
+        hold the close for ever."""
+        super().server_close()
+        idle = []
+        with contextlib.suppress(queue.Empty):
+            while True:
+                idle.append(self._idle.get_nowait())
+        for _ in range(self._started):
+            self._handoff.put(None)
+        self._started = 0
+        for t in idle:
+            t.join()
 
     def finish_request(self, request, client_address):
         address, accepted = client_address
@@ -243,9 +314,10 @@ class FiloHttpServer:
                 pass
 
             def handle(self):
-                # thread start, its first wait for the interpreter and
-                # setup() lie behind this line; the request line, the
-                # headers and the dispatch to do_* ahead of it
+                # the hand-off (or the thread start), this thread's wait
+                # for the interpreter and setup() lie behind this line;
+                # the request line, the headers and the dispatch to do_*
+                # ahead of it
                 self.handled = time.perf_counter()
                 self.read = TRACER.stage("http.read").opened(self.handled)
                 try:

@@ -292,3 +292,216 @@ def test_more_clients_than_five_connect_at_once_and_none_waits_a_second():
     finally:
         srv.shutdown()
     assert len(took) == 12 and max(took) < 0.9, sorted(took)
+
+
+# ----------------------------------------------- standing handler threads
+
+
+def _health(port, query=""):
+    url = f"http://127.0.0.1:{port}/__health" + (f"?{query}" if query else "")
+    with urllib.request.urlopen(url, timeout=10) as resp:
+        resp.read()
+        return resp.status
+
+
+def _wait_idle(srv, n=1):
+    """Until ``n`` standing threads wait for a connection: the one that
+    answered last has closed its socket and gone back to the queue."""
+    import time
+    deadline = time.monotonic() + 10
+    while srv._httpd._idle.qsize() < n:
+        assert time.monotonic() < deadline, "no handler thread went idle"
+        time.sleep(0.002)
+
+
+def _spy_route(srv, before):
+    """Runs ``before(req)`` on the handler thread ahead of each route."""
+    real = srv._handle_request
+
+    def spy(req, method):
+        before(req)
+        real(req, method)
+
+    srv._handle_request = spy
+
+
+def test_sequential_requests_are_served_by_standing_threads():
+    """The accept thread hands each connection to a thread that is
+    already running: twenty requests one after another take at most two
+    threads (the first, and one started while the first was still
+    closing its socket), and all but those two starts are hand-offs."""
+    import threading
+
+    from filodb_tpu.http.server import _HANDOFFS
+    srv = FiloHttpServer(shard_manager=ShardManager())
+    idents = set()
+    _spy_route(srv, lambda req: idents.add(threading.get_ident()))
+    port = srv.start()
+    standing = _HANDOFFS.value(thread="standing")
+    try:
+        for _ in range(20):
+            assert _health(port) == 200
+    finally:
+        srv.shutdown()
+    assert 1 <= len(idents) <= 2, idents
+    assert _HANDOFFS.value(thread="standing") - standing >= 18
+
+
+def test_a_burst_over_the_idle_threads_starts_more_and_is_all_served():
+    """Thirty-two connections that are all inside a handler at once
+    (each waits for the others at a barrier): with one or two threads
+    idle the listener starts the rest, as many as are in flight; there
+    is no cap a handler could wait behind."""
+    import concurrent.futures
+    import threading
+
+    from filodb_tpu.http.server import _HANDOFFS
+    srv = FiloHttpServer(shard_manager=ShardManager())
+    barrier = threading.Barrier(32, timeout=10)
+    _spy_route(srv, lambda req: "burst" in req.path and barrier.wait())
+    port = srv.start()
+    try:
+        assert _health(port) == 200
+        _wait_idle(srv)
+        started = _HANDOFFS.value(thread="started")
+        with concurrent.futures.ThreadPoolExecutor(32) as pool:
+            codes = list(pool.map(lambda _: _health(port, "burst=1"),
+                                  range(32)))
+        assert codes == [200] * 32
+        assert _HANDOFFS.value(thread="started") - started >= 30
+        assert srv._httpd._started >= 32
+    finally:
+        srv.shutdown()
+
+
+def test_a_handler_that_calls_its_own_server_is_answered():
+    """A route that asks this same server (a node dispatching to itself,
+    the fleet aggregator) holds its thread while it waits: with one
+    thread idle, four nested calls each need one more, and get it."""
+    import time
+
+    srv = FiloHttpServer(shard_manager=ShardManager())
+
+    def nest(req):
+        if "hops=" in req.path:
+            hops = int(req.path.rsplit("=", 1)[1])
+            if hops:
+                assert _health(port, f"hops={hops - 1}") == 200
+
+    _spy_route(srv, nest)
+    port = srv.start()
+    try:
+        assert _health(port) == 200
+        _wait_idle(srv)
+        t0 = time.monotonic()
+        assert _health(port, "hops=4") == 200
+        assert time.monotonic() - t0 < 10
+        assert srv._httpd._started >= 5
+    finally:
+        srv.shutdown()
+
+
+def test_shutdown_leaves_no_thread_of_the_listener():
+    """``shutdown()`` stops every standing thread: the idle ones at once
+    (woken and joined), one still closing its last socket right after."""
+    import concurrent.futures
+    import threading
+    import time
+
+    before = set(threading.enumerate())
+    srv = FiloHttpServer(shard_manager=ShardManager())
+    port = srv.start()
+    with concurrent.futures.ThreadPoolExecutor(8) as pool:
+        assert set(pool.map(lambda _: _health(port), range(24))) == {200}
+    assert srv._httpd._started >= 1
+    srv.shutdown()
+    deadline = time.monotonic() + 10
+    while set(threading.enumerate()) - before:
+        assert time.monotonic() < deadline, \
+            sorted(t.name for t in set(threading.enumerate()) - before)
+        time.sleep(0.01)
+
+
+def test_a_request_after_one_that_raised_sees_only_its_own_state(
+        monkeypatch):
+    """A standing thread serves request after request: one whose handler
+    raised with a span open, a trace attached, a query's context active
+    and ``_answered`` set leaves none of it to the next request on the
+    same thread, and the next one's flush carries only its own spans."""
+    import threading
+
+    from filodb_tpu.query import exec as qexec
+    from filodb_tpu.utils.observability import TRACER
+    srv = FiloHttpServer(shard_manager=ShardManager())
+    flushed = []            # (thread, [(span name, trace id)]) a flush
+    monkeypatch.setattr(TRACER, "_reporters", TRACER._reporters + (
+        lambda recs: flushed.append((threading.get_ident(), [
+            (r.name, r.trace_id) for r in recs])),))
+    seen = {}
+
+    def route(req):
+        if "boom" in req.path:
+            seen["boom"] = threading.get_ident()
+            srv._answered.tok = ("t-boom", "s-boom")
+            with TRACER.attach(("t-boom", "s-boom")), \
+                    TRACER.stage("boom.stage"), \
+                    qexec.leaf_scan(qexec.ExecContext(TimeSeriesMemStore())):
+                raise RuntimeError("boom")
+        if "ok" in req.path:
+            seen["ok"] = threading.get_ident()
+            seen["state"] = (TRACER.current_trace_id(),
+                             TRACER.current_span(), qexec.active_exec_ctx(),
+                             srv._answered.tok)
+            with TRACER.attach(("t-ok", "s-ok")), TRACER.stage("ok.stage"):
+                pass
+
+    _spy_route(srv, route)
+    port = srv.start()
+    try:
+        assert _health(port) == 200
+        _wait_idle(srv)
+        with pytest.raises(Exception):     # the connection closed unanswered
+            _health(port, "boom=1")
+        _wait_idle(srv)
+        assert _health(port, "ok=1") == 200
+        _wait_idle(srv)
+        assert srv._httpd._started == 1
+    finally:
+        srv.shutdown()
+    assert seen["ok"] == seen["boom"]
+    assert seen["state"] == (None, "http.request", None, None)
+    mine = [spans for ident, spans in flushed if ident == seen["ok"]]
+    ok = [spans for spans in mine if ("ok.stage", "t-ok") in spans]
+    assert len(ok) == 1
+    assert not [s for s in ok[0] if s[0] == "boom.stage" or s[1] == "t-boom"]
+    assert any(("boom.stage", "t-boom") in spans for spans in mine)
+
+
+def test_every_connection_is_handed_off_once_under_a_short_switch_interval():
+    """Sixty-four clients against the accept thread and the standing
+    threads with the interpreter switching every microsecond: every
+    request is answered, and the hand-offs counted are the connections
+    made, each once (a token lost or taken twice leaves a connection
+    unserved or a thread serving two)."""
+    import concurrent.futures
+    import sys
+
+    from filodb_tpu.http.server import _HANDOFFS
+    srv = FiloHttpServer(shard_manager=ShardManager())
+    port = srv.start()
+    before = sum(_HANDOFFS.value(thread=k) for k in ("standing", "started"))
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with concurrent.futures.ThreadPoolExecutor(64) as pool:
+            codes = list(pool.map(lambda _: _health(port), range(640),
+                                  timeout=120))
+        threads = srv._httpd._started
+    finally:
+        sys.setswitchinterval(old)
+        srv.shutdown()
+    assert codes == [200] * 640
+    after = sum(_HANDOFFS.value(thread=k) for k in ("standing", "started"))
+    assert after - before == 640
+    # no more threads than connections in flight or closing at once
+    assert 1 <= threads <= 128, threads

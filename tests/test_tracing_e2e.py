@@ -679,6 +679,24 @@ class TestStageClockEndpoints:
         for name in self.FRONT:
             assert after[name]["cpu_s"] == 0.0, name
 
+    def test_the_spawn_says_how_the_connection_reached_its_thread(
+            self, grid, monkeypatch):
+        """``http.spawn`` carries ``thread``: on a warm server, whose
+        handler threads have gone back to wait, a request is handed to
+        one of them (``standing``), and no thread is started for it."""
+        from filodb_tpu.utils.observability import TRACER
+        spawns = []
+        monkeypatch.setattr(TRACER, "_reporters", TRACER._reporters + (
+            lambda recs: spawns.extend(r.tags for r in recs
+                                       if r.name == "http.spawn"),))
+        _grid_query(grid, self.QUERY)
+        self._counts()                  # its thread flushed, and went idle
+        del spawns[:]
+        code, _b, _h = _grid_query(grid, self.QUERY)
+        assert code == 200
+        TRACER.stages.snapshot()        # folds the deferred span in
+        assert spawns == [{"thread": "standing"}]
+
     def test_accept_and_read_join_the_querys_trace(self, grid):
         code, body, _h = _grid_query(grid, self.QUERY)
         tid = body["data"]["stats"]["traceId"]
