@@ -36,6 +36,13 @@ def selection(pop, panel: dict, namespace: int) -> np.ndarray:
     return np.arange(first, first + k)
 
 
+def grid_of(panel: dict, pop_spec: dict, end_ms=None) -> np.ndarray:
+    """The instants of a panel's steps, ending at ``end_ms``."""
+    start, _end, step, steps = traffic_mod.panel_range(panel, pop_spec,
+                                                       end_ms)
+    return start + np.arange(steps, dtype=np.int64) * step
+
+
 def reference_answer(pop, panel: dict, namespace: int, vals=None,
                      keep=None, end_ms=None) -> dict:
     """{key: [T]} float64, over ALL the population's rows, loaded and live:
@@ -43,9 +50,27 @@ def reference_answer(pop, panel: dict, namespace: int, vals=None,
     ends at ``end_ms`` (default: the newest loaded row) is due every sample
     up to there, whatever had arrived.  ``vals`` replaces the population's
     values and ``keep`` masks series out: the controls' way in."""
+    return on_grid(pop, panel, namespace, grid_of(panel, pop.spec, end_ms),
+                   vals, keep)
+
+
+def reference_by_end(pop, panel: dict, namespace: int, ends) -> dict:
+    """{end_ms: ``reference_answer`` at that end} for many ends of one panel
+    and namespace, computed once over the union of their grids and sliced
+    by end.  The numbers are the same: a step's cell is reckoned from its
+    own instant alone, and an aggregate across series is taken step by
+    step."""
+    grids = {e: grid_of(panel, pop.spec, e) for e in ends}
+    union = np.unique(np.concatenate(list(grids.values())))
+    whole = on_grid(pop, panel, namespace, union)
+    return {e: {k: v[np.searchsorted(union, g)] for k, v in whole.items()}
+            for e, g in grids.items()}
+
+
+def on_grid(pop, panel: dict, namespace: int, grid: np.ndarray, vals=None,
+            keep=None) -> dict:
+    """The reference at the instants ``grid``."""
     ref = panel["reference"]
-    start, end, step, steps = traffic_mod.panel_range(panel, pop.spec, end_ms)
-    grid = start + np.arange(steps, dtype=np.int64) * step
     sel = selection(pop, panel, namespace)
     if keep is not None:
         sel = sel[keep[sel]]

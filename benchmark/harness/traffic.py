@@ -9,14 +9,36 @@ A traffic file holds ``loop`` (``closed``), ``sessions``, ``think_ms``,
 ``timeout_s`` (what the client sends with every request), ``cycle`` and
 ``panels``.  A panel is a PromQL template with its ``weight`` (how many of the
 ``cycle`` requests are this panel), its range (``steps`` x ``step_ms`` ending
-at the ``newest`` loaded row, or at ``now``: the newest row a writer has made
-visible, floored to the panel's ``edge_ms``), an optional ``select`` draw, the
-``reference`` it is held to and the ``limits`` of that comparison.  Every
-seed gets the same work in another order: a session plays shuffles of one
-cycle that holds each panel ``weight`` times, and a draw over the namespaces
-plays shuffles of one fixed deck of ranks (``uniform``: every namespace once;
-``zipf``: the inverse CDF at evenly spaced quantiles), with the rank ->
-namespace map permuted by the seed.
+at one of three ends, below), an optional ``select`` draw, the ``reference``
+it is held to and the ``limits`` of that comparison.  Every seed gets the
+same work in another order: a session plays shuffles of one cycle that holds
+each panel ``weight`` times, and a draw over the namespaces plays shuffles of
+one fixed deck of ranks (``uniform``: every namespace once; ``zipf``: the
+inverse CDF at evenly spaced quantiles), with the rank -> namespace map
+permuted by the seed.
+
+A panel's ``end`` is one of:
+
+``newest``  the newest loaded bucket edge, the same for every request;
+``now``     the newest row a writer has made visible, floored to the
+            panel's ``edge_ms`` (a mix with a ``writer``, below);
+``slide``   ``newest - k * edge_ms``, ``k`` in ``[0, within_ms // edge_ms]``
+            by ``"slide": {"within_ms": W, "draw": "uniform" | "zipf",
+            "s": <zipf only>}``: a dashboard whose time range was moved
+            back.  ``k`` is dealt as the namespaces are: a session keeps a
+            deck of ``k`` a sliding panel (``deck_of``'s draws over the
+            ``W // edge_ms + 1`` positions), shuffled by its own generator
+            and drawn apart from the namespace deck, so a request is
+            (namespace, end).  Rank 0 is the newest end and the rank -> ``k``
+            map is the same for every seed: recency is what a Zipf draw
+            over ends models.  A panel that does not slide draws nothing
+            for its end.
+
+Set-up warms shapes, not ends: ``warm_requests`` and ``burst_requests`` send
+a sliding panel at ``k = 0``, as they send every panel at one end.
+``setup_s`` is what a restarted node needs before it answers; the ends are
+the traffic that node then meets, and a program that compiles once an end
+pays for it in the window, where ``compiles_in_window`` shows it.
 
 An optional ``writer`` block makes the mix one of queries AND writes:
 ``{"edge": "containers", "batch_ms": B, "visible_after_ms": V}``.  The stream
@@ -59,13 +81,27 @@ def load(path) -> dict:
 
 
 def ends_at(panel: dict) -> str:
-    """``newest`` or ``now``; a panel that ends at ``now`` says at which
-    multiple (``edge_ms``) of time past the loaded rows."""
+    """``newest``, ``now`` or ``slide``; a panel that ends at ``now`` or
+    slides says on which grid (``edge_ms``) its ends fall, one that slides
+    how far back (``slide.within_ms``, a whole multiple of it) and by which
+    draw."""
     end = panel.get("end", "newest")
-    if end not in ("newest", "now") \
-            or (end == "now" and int(panel.get("edge_ms", 0)) < 1):
+    edge = panel.get("edge_ms", 0)
+    if end not in ("newest", "now", "slide") \
+            or (end != "newest" and not (isinstance(edge, int) and edge > 0)):
         raise ValueError(f"panel {panel.get('name')!r}: end is 'newest', or "
-                         f"'now' with an edge_ms")
+                         f"'now' or 'slide' with an edge_ms")
+    if end == "slide":
+        sl = panel.get("slide") or {}
+        within = sl.get("within_ms")
+        if not (isinstance(within, int) and within > 0
+                and within % edge == 0) \
+                or sl.get("draw") not in ("uniform", "zipf") \
+                or (sl["draw"] == "zipf" and not float(sl.get("s", 0)) > 0):
+            raise ValueError(f"panel {panel.get('name')!r}: end is 'slide' "
+                             f"with slide.within_ms a positive whole multiple "
+                             f"of edge_ms and a draw 'uniform' or 'zipf' "
+                             f"(with its s)")
     return end
 
 
@@ -74,29 +110,53 @@ def newest_ms(pop_spec: dict) -> int:
     return pop_spec["base_ms"] + pop_spec["rows"] * pop_spec["scrape_ms"]
 
 
-def end_for(panel: dict, pop_spec: dict, visible_ms=None):
+def end_for(panel: dict, pop_spec: dict, visible_ms=None, back: int = 0):
     """The ``end`` a request of ``panel`` carries: None where it ends at
     the newest loaded row; where it ends at ``now``, the visible watermark
     (``visible_ms``; the loaded edge before a writer has started) floored to
-    the panel's ``edge_ms`` past that edge."""
-    if ends_at(panel) == "newest":
+    the panel's ``edge_ms`` past that edge; where it slides, ``back`` edges
+    before the newest row."""
+    end = ends_at(panel)
+    if end == "newest":
         return None
-    newest = newest_ms(pop_spec)
+    newest, edge = newest_ms(pop_spec), panel["edge_ms"]
+    if end == "slide":
+        return newest - back * edge
     if visible_ms is None:
         return newest
-    edge = int(panel["edge_ms"])
     return newest + max(0, int(visible_ms) - newest) // edge * edge
+
+
+def earliest_ms(panel: dict, pop_spec: dict) -> int:
+    """The oldest instant any answer of ``panel`` reads: the start of its
+    earliest range less its reference's window."""
+    back = panel["slide"]["within_ms"] if ends_at(panel) == "slide" else 0
+    start = panel_range(panel, pop_spec, newest_ms(pop_spec) - back)[0]
+    return start - int(panel["reference"]["window_ms"])
 
 
 def deck_of(sel: dict, n: int) -> list:
     """One deck of ranks in [0, n) for a panel's ``select``."""
     if sel.get("over") != "namespaces":
         raise ValueError(f"unknown select {sel}")
-    if sel["draw"] == "uniform":
+    return ranks(sel, n)
+
+
+def slide_deck(panel: dict) -> list:
+    """One deck of ``k`` for a panel that slides: ``deck_of``'s draws over
+    its ``within_ms // edge_ms + 1`` ends, rank 0 the newest."""
+    sl = panel["slide"]
+    return ranks(sl, sl["within_ms"] // panel["edge_ms"] + 1)
+
+
+def ranks(draw: dict, n: int) -> list:
+    """One deck of ranks in [0, n) by its ``draw``: ``uniform`` (each rank
+    once) or ``zipf`` (``zipf_deck`` with its ``s``)."""
+    if draw["draw"] == "uniform":
         return list(range(n))
-    if sel["draw"] == "zipf":
-        return zipf_deck(n, float(sel["s"]))
-    raise ValueError(f"unknown select {sel}")
+    if draw["draw"] == "zipf":
+        return zipf_deck(n, float(draw["s"]))
+    raise ValueError(f"unknown draw {draw}")
 
 
 def zipf_deck(n: int, s: float, size: int = ZIPF_DECK) -> list:
@@ -143,7 +203,7 @@ def panel_range(panel: dict, pop_spec: dict, end_ms=None) -> tuple:
 
 def request_for(panel: dict, pi: int, namespace: int, pop_spec: dict,
                 dataset: str, timeout_s: int, stats: bool,
-                visible_ms=None) -> Request:
+                visible_ms=None, back: int = 0) -> Request:
     per = pop_spec["per_namespace"]
     sel = panel.get("select") or {}
     first = max(namespace, 0) * per
@@ -152,7 +212,7 @@ def request_for(panel: dict, pi: int, namespace: int, pop_spec: dict,
     query = panel["query"].format(
         metric=pop_spec["metric"], workspace=pop_spec["workspace"],
         namespace=f"App-{max(namespace, 0):04d}", instances=instances)
-    end_ms = end_for(panel, pop_spec, visible_ms)
+    end_ms = end_for(panel, pop_spec, visible_ms, back)
     start, end, step, _n = panel_range(panel, pop_spec, end_ms)
     args = {"query": query, "start": start / 1000, "end": end / 1000,
             "step": f"{step}ms", "timeout": f"{timeout_s}s"}
@@ -175,16 +235,18 @@ def session(traffic: dict, seed: int, index: int, pop_spec: dict,
             dataset: str, timeout_s: int, stats: bool, visible=None):
     """The endless request sequence of session ``index``.  ``visible()``
     (a writer's watermark, read when a request is drawn, which is when it is
-    sent) gives a panel that ends at ``now`` its end."""
+    sent) gives a panel that ends at ``now`` its end; a panel that slides
+    draws its ``k`` from a deck of its own."""
     rng = random.Random(f"{seed}/{traffic['name']}/{index}")
     ns_map = namespace_map(traffic, seed, pop_spec)
     cycle = [pi for pi, p in enumerate(traffic["panels"])
              for _ in range(p["weight"])]
-    decks = {}
+    decks, backs = {}, {}
     while True:
         rng.shuffle(cycle)
         for pi in list(cycle):
-            sel = traffic["panels"][pi].get("select")
+            panel = traffic["panels"][pi]
+            sel = panel.get("select")
             ns = -1
             if sel:
                 deck = decks.get(pi)
@@ -192,17 +254,24 @@ def session(traffic: dict, seed: int, index: int, pop_spec: dict,
                     deck = decks[pi] = deck_of(sel, pop_spec["namespaces"])
                     rng.shuffle(deck)
                 ns = ns_map[deck.pop()]
-            yield request_for(traffic["panels"][pi], pi, ns, pop_spec,
-                              dataset, timeout_s, stats,
-                              visible() if visible else None)
+            back = 0
+            if panel.get("end") == "slide":
+                deck = backs.get(pi)
+                if not deck:
+                    deck = backs[pi] = slide_deck(panel)
+                    rng.shuffle(deck)
+                back = deck.pop()
+            yield request_for(panel, pi, ns, pop_spec, dataset, timeout_s,
+                              stats, visible() if visible else None, back)
 
 
 def warm_requests(traffic: dict, staging: list, seed: int, pop_spec: dict,
                   dataset: str, timeout_s: int, stats: bool) -> list:
-    """One request a distinct panel shape: what set-up issues twice.  The
-    configuration's ``staging`` panels come first (panel index -1 - i): they
-    bring the device store to the state a node is in once it has served for
-    a while, every series of the shard staged."""
+    """One request a distinct panel shape: what set-up issues twice (a
+    sliding panel at ``k = 0``).  The configuration's ``staging`` panels come
+    first (panel index -1 - i): they bring the device store to the state a
+    node is in once it has served for a while, every series of the shard
+    staged."""
     ns_map = namespace_map(traffic, seed, pop_spec)
     before = [(-1 - i, p) for i, p in enumerate(staging)]
     return [(p["name"],
@@ -213,11 +282,12 @@ def warm_requests(traffic: dict, staging: list, seed: int, pop_spec: dict,
 
 def burst_requests(traffic: dict, seed: int, pop_spec: dict, dataset: str,
                    timeout_s: int, stats: bool, sizes) -> list:
-    """For each panel and each size, that many requests of the panel: what
-    set-up sends together.  A panel with a ``select`` is sent over different
-    namespaces, then over one (two sessions do ask one namespace at once now
-    and then); a panel without a draw has ONE request, which sessions playing
-    it send together all the time: that many copies of it."""
+    """For each panel and each size, that many requests of the panel (a
+    sliding one at ``k = 0``): what set-up sends together.  A panel with a
+    ``select`` is sent over different namespaces, then over one (two
+    sessions do ask one namespace at once now and then); a panel without a
+    draw has ONE request, which sessions playing it send together all the
+    time: that many copies of it."""
     ns_map = namespace_map(traffic, seed, pop_spec)
 
     def bursts_of(panel: dict, k: int) -> list:

@@ -16,7 +16,10 @@ written to disk.
 Where the traffic file has a ``writer``, one more thread posts the stream's
 containers (built by the parent, read here as bytes) to ``POST
 /ingest/<dataset>/<shard>`` on their schedule, and the sessions' panels that
-end at ``now`` end at what it has made visible (``Writer``).
+end at ``now`` end at what it has made visible (``Writer``).  A thread of
+this process keeps its own stops (``Stops``): where the load generator was
+not run, the machine stood, and the writer's lateness over those seconds is
+not the server's.
 
 Blob: 8 bytes big-endian length of the JSON head, the head
 (``{"requests": [...], "bodies": [[sha1, length], ...], ...}``; with a writer
@@ -39,6 +42,45 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 from harness import traffic as traffic_mod  # noqa: E402
 
 
+class Stops:
+    """This process's own stops: a thread wakes every ``TICK_S`` and keeps
+    each span in which it woke more than ``STOP_S`` late.  The load
+    generator is a process of its own that shares nothing with the server,
+    so a span in which it was not run is one in which the machine (or the
+    harness) stood, whatever the server did."""
+
+    TICK_S = 0.02
+    STOP_S = 0.1
+
+    def __init__(self):
+        self.spans: list = []          # (from, to) on time.time()
+        self.lock = threading.Lock()
+        self.done = threading.Event()
+        self.thread = threading.Thread(target=self.run, daemon=True)
+
+    def run(self) -> None:
+        prev = time.time()
+        while not self.done.wait(self.TICK_S):
+            now = time.time()
+            if now - prev - self.TICK_S > self.STOP_S:
+                with self.lock:
+                    self.spans.append((prev + self.TICK_S, now))
+            prev = now
+
+    def within(self, t0: float, t1: float) -> float:
+        """Seconds of ``[t0, t1]`` in which this process stood."""
+        with self.lock:
+            return sum(max(0.0, min(b, t1) - max(a, t0))
+                       for a, b in self.spans)
+
+    def head(self) -> dict:
+        with self.lock:
+            longest = sorted(self.spans, key=lambda s: s[0] - s[1])[:10]
+            return {"stopped_s": sum(b - a for a, b in self.spans),
+                    "stops": len(self.spans),
+                    "longest": [[a, b - a] for a, b in longest]}
+
+
 class Writer:
     """The traffic's scheduled writer: batch ``k`` of the stream is due when
     the synthetic clock (the newest loaded edge at ``anchor``, one to one
@@ -48,10 +90,13 @@ class Writer:
     processes and one stream): the anchor, the next batch, and when each
     batch so far had its last 200 (None: not acknowledged).  What fell due
     between the two windows is posted at once when this one opens and is
-    counted (``caught_up``), not held against the schedule."""
+    counted (``caught_up``), not held against the schedule.  A batch's
+    lateness is held against it less the seconds in which this process
+    stood (``stops``): ``behind_s_max`` is the most that is left, and
+    ``late_s_max`` the most before that."""
 
     def __init__(self, block: dict, state: dict, port: int, dataset: str,
-                 newest_ms: int):
+                 newest_ms: int, stops: "Stops | None" = None):
         with open(state["file"], "rb") as f:
             (n,) = struct.unpack(">Q", f.read(8))
             index = json.loads(f.read(n))
@@ -72,6 +117,8 @@ class Writer:
         self.records = []
         self.t_close, self.caught_up = 0.0, 0
         self.behind_s_max, self.exhausted = 0.0, False
+        self.late_s_max = 0.0
+        self.stops = stops if stops is not None else Stops()
 
     def visible_ms(self) -> int:
         """The synthetic time up to which every batch had its last 200 at
@@ -117,7 +164,7 @@ class Writer:
             time.sleep(max(0.0, t_due - time.time()))
             t_first = time.time()
             if t_first >= t_close:        # behind by the rest of the window
-                self.behind_s_max = max(self.behind_s_max, t_first - t_due)
+                self.behind(t_due, t_first)
                 return
             whole = True
             for shard, samples, container in self.by_batch.get(k, ()):
@@ -132,10 +179,16 @@ class Writer:
             if t_due < t_start:
                 self.caught_up += 1
             else:
-                self.behind_s_max = max(self.behind_s_max, t_first - t_due)
+                self.behind(t_due, t_first)
             with self.lock:
                 self.acked.append(time.time() if whole else None)
             k += 1
+
+    def behind(self, t_due: float, t_first: float) -> None:
+        late = t_first - t_due
+        self.late_s_max = max(self.late_s_max, late)
+        self.behind_s_max = max(self.behind_s_max,
+                                late - self.stops.within(t_due, t_first))
 
     def head(self) -> dict:
         # batches whose end the clock reached before the window closed
@@ -144,7 +197,8 @@ class Writer:
                 "batches_due": max(0, int(due) - self.first),
                 "batches_sent": len(self.acked) - self.first,
                 "caught_up": self.caught_up, "exhausted": self.exhausted,
-                "behind_s_max": self.behind_s_max}
+                "behind_s_max": self.behind_s_max,
+                "late_s_max": self.late_s_max}
 
 
 def play(spec: dict) -> tuple:
@@ -154,11 +208,11 @@ def play(spec: dict) -> tuple:
     think = traffic.get("think_ms", 0) / 1000.0
     records, bodies = [], {}
     lock = threading.Lock()
-    writer = None
+    writer, stops = None, Stops()
     if traffic.get("writer"):
         writer = Writer(traffic["writer"], spec["writer"], port,
                         spec["dataset"],
-                        traffic_mod.newest_ms(spec["population"]))
+                        traffic_mod.newest_ms(spec["population"]), stops)
     t_open = time.time() + 0.2            # every session starts together
     t_close = t_open + seconds
 
@@ -210,15 +264,19 @@ def play(spec: dict) -> tuple:
     writing = [threading.Thread(target=writer.run, daemon=True,
                                 args=(t_open, t_close, grace))] \
         if writer else []
+    stops.thread.start()
     for t in threads + writing:
         t.start()
     for t in threads + writing:
         t.join(timeout=max(0.0, t_close + grace + 5 - time.time()))
+    stops.done.set()
+    stops.thread.join()
     hung = sum(t.is_alive() for t in threads)
     with lock:
         head = {"t_open": t_open, "t_close": t_close, "hung_sessions": hung,
                 "requests": sorted(records, key=lambda r: r["t_send"]),
-                "bodies": [[k, len(v)] for k, v in bodies.items()]}
+                "bodies": [[k, len(v)] for k, v in bodies.items()],
+                "stops": stops.head()}
     if writer:
         head["writes"] = list(writer.records)
         head["writer"] = dict(writer.head(), hung=writing[0].is_alive())

@@ -14,6 +14,13 @@
 ``writes_swallowed``      once set-up is done the container edge answers 200
                           and hands nothing on to the shards (a cell whose
                           traffic has a writer)
+``ingest_held``           the first container the edge takes in the
+                          measured window waits 2.5 s before it is handed on: the
+                          server alone stands, the load generator runs on
+                          (a cell whose traffic has a writer)
+``end_ignored``           ``query_range`` is answered as if its ``end`` were
+                          the newest loaded row, on the steps it asked for
+                          (a mix whose panels slide)
 """
 
 import pathlib
@@ -64,6 +71,55 @@ def plant(fault: str) -> None:
             live["server"].http.ingest_sink = lambda dataset, shard, body: 0
             return live
         run.set_up = swallowed
+    elif fault == "ingest_held":
+        import threading
+        import time
+
+        import run
+        whole_set_up, whole_window = run.set_up, run.run_window
+        windows, once = [], threading.Event()
+
+        def set_up(*a, **kw):
+            live = whole_set_up(*a, **kw)
+            sink = live["server"].http.ingest_sink
+
+            def late(dataset, shard, body):
+                if len(windows) > 1 and not once.is_set():   # the window's
+                    once.set()
+                    time.sleep(2.5)
+                return sink(dataset, shard, body)
+            live["server"].http.ingest_sink = late
+            return live
+
+        def run_window(*a, **kw):        # the concurrent warm, the window
+            windows.append(1)
+            return whole_window(*a, **kw)
+        run.set_up, run.run_window = set_up, run_window
+    elif fault == "end_ignored":
+        import run
+        from filodb_tpu.http import server
+        from harness import traffic
+        whole_set_up, whole = run.set_up, server.FiloHttpServer._query_range
+        newest = []
+
+        def set_up(*a, **kw):
+            live = whole_set_up(*a, **kw)
+            newest.append(traffic.newest_ms(live["spec"]))
+            return live
+
+        def ignored(self, b, p):
+            back = newest[0] - int(float(p["end"]) * 1000) if newest else 0
+            if not back:
+                return whole(self, b, p)
+            moved = {k: str((int(float(p[k]) * 1000) + back) / 1000)
+                     for k in ("start", "end")}
+            code, body = whole(self, b, dict(p, **moved))
+            for row in body.get("data", {}).get("result", []):
+                row["values"] = [[t - back / 1000, v] for t, v in
+                                 row["values"]]
+            return code, body
+        run.set_up = set_up
+        server.FiloHttpServer._query_range = ignored
     else:
         raise SystemExit(f"unknown fault {fault!r}")
 

@@ -207,3 +207,72 @@ def test_the_stream_is_the_live_rows_dealt_to_their_batches(batch_ms,
                                                   pop.vals[s, j])
             seen[s] += 1
     assert set(seen.values()) == {6}
+
+
+def test_a_stop_is_what_overlaps_the_span_asked():
+    stops = loadgen.Stops()
+    stops.spans = [(10.0, 11.5), (20.0, 20.25)]
+    assert stops.within(0.0, 100.0) == 1.75
+    assert stops.within(11.0, 20.1) == pytest.approx(0.6)
+    assert stops.within(12.0, 19.0) == 0.0
+    head = stops.head()
+    assert head["stops"] == 2 and head["stopped_s"] == 1.75
+    assert head["longest"][0] == [10.0, 1.5]
+
+
+def test_a_stop_of_the_process_is_seen():
+    """The load generator's process stopped for 0.4 s (SIGSTOP, as a machine
+    that is not run stops it) keeps a span of about that length."""
+    import os
+    import signal
+    import subprocess
+    import sys
+    code = ("import json, sys, time; sys.path.insert(0, sys.argv[1]); "
+            "import loadgen; s = loadgen.Stops(); s.thread.start(); "
+            "print('up', flush=True); time.sleep(1.5); s.done.set(); "
+            "s.thread.join(); print(json.dumps(s.head()))")
+    proc = subprocess.Popen([sys.executable, "-c", code, str(BENCH)],
+                            stdout=subprocess.PIPE, text=True)
+    try:
+        assert proc.stdout.readline().strip() == "up"
+        time.sleep(0.3)
+        os.kill(proc.pid, signal.SIGSTOP)
+        time.sleep(0.4)
+        os.kill(proc.pid, signal.SIGCONT)
+        head = json.loads(proc.stdout.readline())
+    finally:
+        proc.wait(timeout=10)
+    assert head["stops"] == 1 and 0.3 < head["stopped_s"] < 0.6, head
+
+
+@pytest.mark.parametrize("stood", [False, True])
+def test_lateness_over_a_stop_of_the_load_generator_is_not_held(tmp_path,
+                                                                 stood):
+    """Batch 0's POST takes 0.45 s of a 0.1 s schedule: batch 1 starts
+    ~0.35 s late.  Where the load generator itself stood over those
+    seconds the lateness is the machine's; where it ran on, the server's."""
+    head = json.dumps({"batches": 2, "containers": [[0, 0, 1, 0, 1],
+                                                    [1, 0, 1, 1, 1]]})
+    (tmp_path / "w.bin").write_bytes(
+        struct.pack(">Q", len(head)) + head.encode() + b"ab")
+    anchor = time.time() + 0.05
+    stops = loadgen.Stops()
+    if stood:
+        stops.spans = [(anchor + 0.1, anchor + 0.6)]
+    w = loadgen.Writer({"edge": "containers", "batch_ms": 100,
+                        "visible_after_ms": 0},
+                       {"file": str(tmp_path / "w.bin"), "anchor": anchor,
+                        "acked": []}, 0, "prom", 1_000_000, stops)
+
+    def post(shard, container, until):
+        if container == b"a":
+            time.sleep(0.45)
+        return 200
+    w.post = post
+    w.run(anchor - 0.01, anchor + 5.0, 1.0)
+    assert [r["batch"] for r in w.records] == [0, 1]
+    assert w.late_s_max > 0.3
+    if stood:
+        assert w.behind_s_max < 0.05
+    else:
+        assert w.behind_s_max == w.late_s_max

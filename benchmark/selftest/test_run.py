@@ -1,8 +1,8 @@
 """``run.py`` end to end at the rehearsal size (256 series, the CPU backend,
 a child process): every cell; the timed path broken underneath; a cell, a
 configuration, a mix, a metric and a reader added as new files only, one of
-them a cell that ingests while it answers; a checkout without the program; a
-host without the chip."""
+them a cell that ingests while it answers, one a mix whose panels slide; a
+checkout without the program; a host without the chip."""
 
 import json
 import shutil
@@ -44,6 +44,14 @@ def test_rehearsal_of_every_cell(cell, trace):
     # each number compared stands beside its limit, last on stderr
     tail = p.stderr.strip().splitlines()[-len(out["compared"]):]
     assert all(ln.startswith("compared ") for ln in tail), tail
+    # what can fail a run first comes first: the answers' rel_err last
+    names = list(out["compared"])
+    first_rel = min(i for i, k in enumerate(names) if k.startswith("rel_"))
+    assert all(k.startswith("rel_err.") for k in names[first_rel:]), names
+    assert "device_dispatches" in names[:first_rel]
+    if cell == "jmh1.live-edge":
+        assert {"writes_unacknowledged", "writer_behind_s",
+                "writes_not_ingested"} <= set(names[:first_rel]), names
 
 
 def test_a_host_without_the_chip_prints_no_result():
@@ -199,6 +207,72 @@ def add_live_cell(bench, doc: dict, batch_ms: int = 1000) -> str:
     return "live.edge"
 
 
+def add_slide_cell(bench, doc: dict, within_ms: int = 2_400_000) -> str:
+    """What a later PR brings for a mix whose panels slide, as data alone:
+    a traffic file and the cell.  "Last 15 minutes" panels (60 steps of
+    15 s, 5 min windows) whose ends fall on the 15 s grid up to
+    ``within_ms`` before the newest row, one of them drawn by recency, and
+    a workspace-wide sum over the last hour that slides a little."""
+    sel = "{metric}{{_ws_=\"{workspace}\",_ns_=\"{namespace}\"}}"
+    last15 = {"end": "slide", "edge_ms": 15000, "weight": 1, "steps": 60,
+              "step_ms": 15000,
+              "select": {"draw": "uniform", "over": "namespaces"}}
+    (bench / "traffic" / "slide-panels.json").write_text(json.dumps({
+        "name": "slide-panels", "loop": "closed", "sessions": 3,
+        "think_ms": 0, "cycle": 3, "timeout_s": 30, "panels": [
+            dict(last15, name="sum_rate", query=f"sum(rate({sel}[5m]))",
+                 slide={"within_ms": within_ms, "draw": "uniform"},
+                 limits={"rel_err": 1e-9},
+                 reference={"fn": "rate", "window_ms": 300000,
+                            "aggregate": "sum"}),
+            dict(last15, name="raw", query=sel, limits={"rel_err": 0},
+                 slide={"within_ms": within_ms, "draw": "zipf", "s": 1.1},
+                 reference={"fn": "last", "window_ms": 300000,
+                            "aggregate": "none", "key": "instance"}),
+            {"name": "wide_sot", "weight": 1, "steps": 23,
+             "step_ms": 150000, "end": "slide", "edge_ms": 15000,
+             "slide": {"within_ms": 180000, "draw": "uniform"},
+             "query": "sum(sum_over_time({metric}[5m]))",
+             "limits": {"rel_err": 1e-9},
+             "reference": {"fn": "sum_over_time", "window_ms": 300000,
+                           "aggregate": "sum"}}]}))
+    doc["workloads"].append({"name": "jmh1.slide",
+                             "config": "jmh-inmem-1shard",
+                             "traffic": "slide-panels", "chips": 1,
+                             "why": "a test"})
+    return "jmh1.slide"
+
+
+def test_a_program_that_ignores_the_end_is_not_correct(copy):
+    """``query_range`` answered as if its end were the newest row, on the
+    steps asked: every answer at a slid end is another end's numbers."""
+    bench, doc = with_program(copy)
+    cell = add_slide_cell(bench, doc)
+    (copy / "BENCHMARK.json").write_text(json.dumps(doc))
+    p = run_child([bench / "selftest" / "broken_run.py", "end_ignored",
+                   "--workload", cell, "--seed", 37, "--seconds", 4,
+                   "--trace", 0, "--rehearse"], cwd=copy)
+    out = last_json(p)
+    assert out["correct"] is False and p.returncode == 1, out
+    bad = failing(out)
+    assert bad & {"absent_cells", "rel_err.raw", "rel_err.sum_rate",
+                  "rel_err.wide_sot"}, out["compared"]
+    assert out["compared"]["unanswered"]["value"] == 0
+    assert len(ends_in(p)) > 3
+
+
+def test_a_slide_past_the_loaded_rows_prints_no_result(copy):
+    """60 steps of 15 s over 5 min windows read 1 185 s back from their
+    end, and 255 rows are 3 825 s: a slide of 45 min leaves the data."""
+    bench, doc = with_program(copy)
+    cell = add_slide_cell(bench, doc, within_ms=2_700_000)
+    (copy / "BENCHMARK.json").write_text(json.dumps(doc))
+    p = run_child([bench / "run.py", "--workload", cell, "--seed", 1,
+                   "--seconds", 3, "--trace", 0, "--rehearse"], cwd=copy)
+    assert p.returncode == 3 and "before the first loaded row" in p.stderr
+    assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
+
+
 def ends_in(proc) -> dict:
     """Where the window's ``now`` panels ended, and how often."""
     mark = "panel ends in the window: "
@@ -223,6 +297,23 @@ def test_a_writer_that_hands_nothing_on_is_not_correct(copy):
     assert "writes_not_ingested" in bad, out["compared"]
     assert bad & {"absent_cells", "rel_err.raw", "rel_err.sum_rate"}, bad
     assert len(ends_in(p)) >= 2
+
+
+def test_a_server_that_holds_a_container_is_not_correct(copy):
+    """The edge holds one container 2.5 s while the load generator runs on:
+    the writer falls behind by more than a batch, and no stop of its own
+    excuses that."""
+    bench, doc = with_program(copy)
+    cell = add_live_cell(bench, doc)
+    (copy / "BENCHMARK.json").write_text(json.dumps(doc))
+    p = run_child([bench / "selftest" / "broken_run.py", "ingest_held",
+                   "--workload", cell, "--seed", 37, "--seconds", 8,
+                   "--trace", 0, "--rehearse"], cwd=copy)
+    out = last_json(p)
+    assert out["correct"] is False and p.returncode == 1, out
+    assert failing(out) == {"writer_behind_s"}, out["compared"]
+    assert out["compared"]["writer_behind_s"]["value"] > 1.2
+    assert "load generator stood" not in p.stdout
 
 
 def test_a_writer_that_cannot_keep_its_schedule_is_not_correct(copy):
@@ -259,12 +350,14 @@ def test_only_the_benchmark_in_the_directory_prints_no_result(copy):
 
 def test_a_later_pr_adds_files_and_entries_and_edits_nothing(copy):
     """Also a cell on four chips whose panels are all aggregates, two of
-    them with no draw: the fabric serves it (four virtual devices here); and
-    a one-chip cell that ingests while it answers.  Nothing the benchmark
-    has is edited for either."""
+    them with no draw: the fabric serves it (four virtual devices here); a
+    one-chip cell that ingests while it answers; and a one-chip cell whose
+    panels slide, a traffic file and an entry alone.  Nothing the benchmark
+    has is edited for any."""
     bench, doc = with_program(copy)
     had = {f: f.read_bytes() for f in bench.rglob("*") if f.is_file()}
     live = add_live_cell(bench, doc)
+    slide = add_slide_cell(bench, doc)
     # a configuration: two shards of the same population
     conf = json.loads((bench / "configs" / "jmh-inmem-1shard.json")
                       .read_text())
@@ -330,6 +423,14 @@ def test_a_later_pr_adds_files_and_entries_and_edits_nothing(copy):
     assert out["compared"]["writes_unacknowledged"]["value"] == 0
     assert len(ends_in(p)) >= 2
     assert "write_ack_ms" in out["metric_names"]
+    # the cell whose panels slide: many ends, every answer right by its own
+    p = run_child([bench / "run.py", "--workload", slide, "--seed", 5,
+                   "--seconds", 4, "--trace", 1, "--rehearse"], cwd=copy)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-2000:]
+    out = last_json(p)
+    assert out["correct"] is True and not failing(out)
+    assert out["compared"]["unanswered"]["value"] == 0
+    assert len(ends_in(p)) > 3
     # and the old cells report neither new cell's metric, nor the write side
     p = run_child([bench / "run.py", "--workload", CELLS[0], "--seed", 5,
                    "--seconds", 2, "--trace", 1, "--rehearse"], cwd=copy)
