@@ -322,12 +322,12 @@ def _fused_progs():
     # fused compressed-resident programs (ISSUE 3 tentpole): the XOR-
     # class decode runs INSIDE the grid kernel, so HBM serves the
     # packed ~2.5 B/sample planes — no decoded plane is ever written.
-    # row0 is static (the kernel's window slices need compile-time
-    # sublane offsets); outputs are in PACKED lane order.
+    # row0, the span's first row in the block, is a traced int32 like
+    # steps0 (the kernel rotates it to the top): one executable serves
+    # every end of a panel.  Outputs are in PACKED lane order.
     @functools.partial(devicewatch.jit,
                        program="devicestore.series_packed",
-                       static_argnames=("q", "row0", "use_phase",
-                                        "interpret"))
+                       static_argnames=("q", "use_phase", "interpret"))
     def series_prog_packed(packed, steps0, *, q, row0, use_phase,
                            interpret=False):
         return _window(rate_grid_packed, packed, steps0, q, row0=row0,
@@ -335,8 +335,8 @@ def _fused_progs():
 
     @functools.partial(devicewatch.jit,
                        program="devicestore.grouped_packed",
-                       static_argnames=("q", "row0", "use_phase",
-                                        "num_groups", "op", "interpret"))
+                       static_argnames=("q", "use_phase", "num_groups",
+                                        "op", "interpret"))
     def grouped_prog_packed(packed, steps0, garr, *, q, row0, use_phase,
                             num_groups, op, interpret=False):
         stepped = _window(rate_grid_packed, packed, steps0, q, row0=row0,
@@ -396,14 +396,31 @@ def _fused_progs():
                 return prog(*a, **kw)
         return launch
 
+    def staged_packed(prog):
+        """A packed program as the ``grid.dispatch`` stage with the
+        ``grid.packed`` stage inside it over the same call (tags
+        ``program``, ``row0``): its count is the requests the packed
+        kernels served, its wall the compile where one falls on a
+        request.  ``row0`` goes in as an int32, one signature for every
+        offset."""
+        name = getattr(prog, "_program", "")
+
+        @functools.wraps(prog)
+        def launch(*a, row0, **kw):
+            with TRACER.stage("grid.dispatch", leaf=False, program=name), \
+                    TRACER.stage("grid.packed", program=name,
+                                 row0=int(row0)):
+                return prog(*a, row0=np.int32(row0), **kw)
+        return launch
+
     # published whole: a request that arrives while the first one is
     # still building sees no program or all six, never a part of them
     # (entry by entry, it met a non-empty dict without "grouped_batch")
     _FUSED_PROGS.update({
         "series": staged(series_prog),
         "grouped": staged(grouped_prog),
-        "series_packed": staged(series_prog_packed),
-        "grouped_packed": staged(grouped_prog_packed),
+        "series_packed": staged_packed(series_prog_packed),
+        "grouped_packed": staged_packed(grouped_prog_packed),
         "series_batch": staged(series_batch_prog),
         "grouped_batch": staged(grouped_batch_prog)})
     return _FUSED_PROGS
@@ -527,7 +544,7 @@ class _GridPlan(NamedTuple):
     # runs the packed kernels on this single block's class planes —
     # decode happens inside the kernel, output in packed lane order
     packed: object = None          # the block's XOR-class plane dict
-    packed_row0: int = 0           # static row offset within the block
+    packed_row0: int = 0           # the span's first row in the block
     packed_use_phase: bool = False
     packed_inv: object = None      # np [ncols] orig lane -> packed pos
     # logical HBM bytes the serving program reads, by resident format
@@ -1691,7 +1708,9 @@ class DeviceGridCache:
         # each bucket column is an independent packed lane and callers
         # compose their ``lane*hb + bucket`` indirections through the
         # pack's ``inv``.  Multi-block spans, ts-streaming ops, and f64
-        # (no meta) residents keep the XLA decode path.
+        # (no meta) residents keep the XLA decode path.  One segment is
+        # the proof the kernels' traced row offset relies on:
+        # row0 + nrows <= BLOCK_BUCKETS, so the rotate wraps no row in.
         seg0 = segments[0]
         packed = packed_inv = None
         packed_phase = False

@@ -38,6 +38,9 @@ Layout contract (enforced by the caller / device store):
   sublane offsets to be 8-aligned, so the per-query row offset is
   applied host-side (an XLA ``dynamic_slice``), keeping ONE compiled
   kernel per (T, K) signature; ``steps0`` stays a traced SMEM scalar.
+  The packed kernels decode a whole block in VMEM and take their row
+  offset as a traced SMEM scalar too: a sublane rotate by it brings the
+  query's rows to the top (:func:`_decode_rows`).
 - counter correction runs from input row 0, i.e. from the start of the
   scanned range — same scope as the general path, which corrects from
   the first scanned row (filodb_tpu/ops/windows.py counter_correct).
@@ -810,7 +813,8 @@ def _smem():
 
 
 def _s0_tile(steps0):
-    """``steps0`` as the kernels' SMEM operand.  [1, 1], not [1]: under
+    """A traced scalar (``steps0``; the packed kernels' ``row0``) as the
+    kernels' SMEM operand.  [1, 1], not [1]: under
     the fleet tier's vmap the operand grows a leading member axis, and
     Mosaic wants a block's last two dims equal to the array's (or 8/128
     multiples) — [B, 1, 1] blocked (None, 1, 1) is, [B, 1] blocked
@@ -935,19 +939,25 @@ def _decode_packed(p_ref, m_ref):
     return jax.lax.bitcast_convert_type(u ^ first, jnp.float32)
 
 
-def _decode_rows(p_ref, m_ref, q: GridQuery, row0: int):
+def _decode_rows(p_ref, m_ref, r0_ref, q: GridQuery):
     """Decode the full packed block (the prefix-XOR scan must start at
-    block row 0) and take the query's row span as a STATIC sublane
-    slice — ``row0`` is compile-time, which is what lets the slice land
-    at arbitrary (non-8-aligned) offsets under Mosaic."""
+    block row 0), bring the query's first row (``r0_ref``, a traced SMEM
+    scalar) to the top with a sublane rotate by a traced shift, and take
+    the span as a static slice at offset 0: one compiled kernel serves
+    every offset, 8-aligned or not.  The caller proves that the span
+    fits the block (``devicestore._plan_staged``: one block covers it);
+    an offset past that would wrap rows round."""
     vals = _decode_packed(p_ref, m_ref)
+    nb = vals.shape[0]
     need = _rows_needed(q)
-    return jax.lax.slice(vals, (row0, 0), (row0 + need, vals.shape[1]))
+    if need < nb:
+        vals = pltpu.roll(vals, (nb - r0_ref[0, 0]) % nb, axis=0)
+    return jax.lax.slice(vals, (0, 0), (need, vals.shape[1]))
 
 
-def _series_kernel_packed(s0_ref, m_ref, p_ref, out_ref, *, q: GridQuery,
-                          row0: int, use_phase: bool):
-    vals = _decode_rows(p_ref, m_ref, q, row0)
+def _series_kernel_packed(s0_ref, r0_ref, m_ref, p_ref, out_ref, *,
+                          q: GridQuery, use_phase: bool):
+    vals = _decode_rows(p_ref, m_ref, r0_ref, q)
     if use_phase:
         roll = lambda x, s: pltpu.roll(x, s, axis=0)
         out, live_row = _phase_block_raw(m_ref[2:3, :], vals, q, roll,
@@ -981,7 +991,7 @@ def packed_width(packed: dict) -> int:
     return sum(p.shape[1] for p, _m in _packed_planes(packed))
 
 
-def _packed_check(packed: dict, q: GridQuery, row0: int, use_phase: bool):
+def _packed_check(packed: dict, q: GridQuery, use_phase: bool):
     if use_phase:
         if not phase_eligible(q):
             raise ValueError(f"op {q.op} not phase-eligible (dense="
@@ -990,10 +1000,10 @@ def _packed_check(packed: dict, q: GridQuery, row0: int, use_phase: bool):
         raise ValueError(f"packed kernels serve TS_FREE or phase-mode "
                          f"ops only; {q.op} needs a ts plane")
     for p, _m in _packed_planes(packed):
-        if p.shape[0] < row0 + _rows_needed(q):
+        if p.shape[0] < _rows_needed(q):
             raise ValueError(
-                f"packed block has {p.shape[0]} rows; query needs rows "
-                f"[{row0}, {row0 + _rows_needed(q)})")
+                f"packed block has {p.shape[0]} rows; query needs "
+                f"{_rows_needed(q)}")
 
 
 def _plane_lane_tile(n: int) -> int:
@@ -1009,9 +1019,9 @@ def _plane_lane_tile(n: int) -> int:
 
 
 @functools.partial(devicewatch.jit, program="grid.rate_grid_packed",
-                   static_argnames=("q", "row0", "interpret", "use_phase"))
+                   static_argnames=("q", "interpret", "use_phase"))
 @_x32
-def rate_grid_packed(packed: dict, steps0, q: GridQuery, row0: int = 0,
+def rate_grid_packed(packed: dict, steps0, q: GridQuery, row0=0,
                      interpret: bool = False, use_phase: bool = False):
     """Per-series windowed function over XOR-class packed residents:
     packed planes -> [T, packed_width] stepped values in PACKED lane
@@ -1019,37 +1029,37 @@ def rate_grid_packed(packed: dict, steps0, q: GridQuery, row0: int = 0,
 
     One pallas_call per class plane (uniform dtype per call); decode
     runs in VMEM, so HBM sees only the packed bytes.  ``row0`` is the
-    first query row within the block and is STATIC — the decode scan
-    must cover the whole block anyway, and a static offset keeps the
-    window slices on Mosaic's fast path (one compiled kernel per
-    (T, K, row0) signature; dashboards cycle row0 through at most
-    BLOCK_BUCKETS values).  ``use_phase`` activates the uniform-phase
-    kernels reading meta row 2; otherwise only TS_FREE ops are legal.
+    first query row within the block, a traced scalar like ``steps0``:
+    one compiled kernel per (T, K) signature serves every offset
+    (:func:`_decode_rows`).  ``row0 + (T - 1) * stride + K`` must not
+    pass the block's rows: the caller's proof, not checked here.
+    ``use_phase`` activates the uniform-phase kernels reading meta
+    row 2; otherwise only TS_FREE ops are legal.
     """
-    _packed_check(packed, q, row0, use_phase)
+    _packed_check(packed, q, use_phase)
     if q.stride > 1:
         fine = rate_grid_packed(packed, steps0, _fine_query(q), row0,
                                 interpret, use_phase)
         return fine[::q.stride]
-    s0 = _s0_tile(steps0)
+    s0, r0 = _s0_tile(steps0), _s0_tile(row0)
     outs = []
     for p, m in _packed_planes(packed):
         nb, n = p.shape
         lt = _plane_lane_tile(n)
         outs.append(pl.pallas_call(
-            functools.partial(_series_kernel_packed, q=q, row0=row0,
+            functools.partial(_series_kernel_packed, q=q,
                               use_phase=use_phase),
             interpret=interpret, compiler_params=_MOSAIC_PARAMS,
             out_shape=jax.ShapeDtypeStruct((q.nsteps, n), jnp.float32),
             grid=(n // lt,),
-            in_specs=[_smem(),
+            in_specs=[_smem(), _smem(),
                       pl.BlockSpec((8, lt), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
                       pl.BlockSpec((nb, lt), lambda i: (0, i),
                                    memory_space=pltpu.VMEM)],
             out_specs=pl.BlockSpec((q.nsteps, lt), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
-        )(s0, m, p))
+        )(s0, r0, m, p))
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
 
@@ -1084,13 +1094,13 @@ def _hb8(hb: int) -> int:
     return -(-hb // 8) * 8
 
 
-def _hist_grouped_kernel_packed(s0_ref, m_ref, p_ref, sum_ref, cnt_ref, *,
-                                q: GridQuery, row0: int, use_phase: bool,
+def _hist_grouped_kernel_packed(s0_ref, r0_ref, m_ref, p_ref, sum_ref,
+                                cnt_ref, *, q: GridQuery, use_phase: bool,
                                 hb: int):
     """One group per kernel instance: decode the group's packed
     [nb, group_lanes] tile, run the windowed op per bucket column, and
     band-reduce series into [hb8, T] per-bucket (sum, count) planes."""
-    vals = _decode_rows(p_ref, m_ref, q, row0)
+    vals = _decode_rows(p_ref, m_ref, r0_ref, q)
     if use_phase:
         roll = lambda x, s: pltpu.roll(x, s, axis=0)
         out, live_row = _phase_block_raw(m_ref[2:3, :], vals, q, roll,
@@ -1117,11 +1127,11 @@ def _hist_grouped_kernel_packed(s0_ref, m_ref, p_ref, sum_ref, cnt_ref, *,
 
 @functools.partial(devicewatch.jit,
                    program="grid.hist_grid_grouped_packed",
-                   static_argnames=("q", "hb", "group_lanes", "row0",
+                   static_argnames=("q", "hb", "group_lanes",
                                     "interpret", "use_phase"))
 @_x32
 def hist_grid_grouped_packed(packed: dict, steps0, q: GridQuery, hb: int,
-                             group_lanes: int = 1024, row0: int = 0,
+                             group_lanes: int = 1024, row0=0,
                              interpret: bool = False,
                              use_phase: bool = True):
     """Fully fused ``sum by (g)(rate(latency_bucket[w]))`` over packed
@@ -1139,7 +1149,7 @@ def hist_grid_grouped_packed(packed: dict, steps0, q: GridQuery, hb: int,
     if group_lanes % hb != 0:
         raise ValueError(f"group_lanes {group_lanes} not a multiple of "
                          f"the bucket count {hb}")
-    _packed_check(packed, q, row0, use_phase)
+    _packed_check(packed, q, use_phase)
     inv = packed.get("inv")
     if inv is not None and packed_width(packed) != inv.shape[0]:
         raise ValueError(
@@ -1151,7 +1161,7 @@ def hist_grid_grouped_packed(packed: dict, steps0, q: GridQuery, hb: int,
                                         group_lanes, row0, interpret,
                                         use_phase)
         return s[:, ::q.stride], c[:, ::q.stride]
-    s0 = _s0_tile(steps0)
+    s0, r0 = _s0_tile(steps0), _s0_tile(row0)
     hb8 = _hb8(hb)
     sums, cnts = [], []
     for p, m in _packed_planes(packed):
@@ -1163,7 +1173,7 @@ def hist_grid_grouped_packed(packed: dict, steps0, q: GridQuery, hb: int,
                 f"{group_lanes}-column groups — use the hist "
                 f"group-aligned pack layout")
         s, c = pl.pallas_call(
-            functools.partial(_hist_grouped_kernel_packed, q=q, row0=row0,
+            functools.partial(_hist_grouped_kernel_packed, q=q,
                               use_phase=use_phase, hb=hb),
             interpret=interpret, compiler_params=_MOSAIC_PARAMS,
             out_shape=(jax.ShapeDtypeStruct((ng * hb8, q.nsteps),
@@ -1171,7 +1181,7 @@ def hist_grid_grouped_packed(packed: dict, steps0, q: GridQuery, hb: int,
                        jax.ShapeDtypeStruct((ng * hb8, q.nsteps),
                                             jnp.float32)),
             grid=(ng,),
-            in_specs=[_smem(),
+            in_specs=[_smem(), _smem(),
                       pl.BlockSpec((8, group_lanes), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
                       pl.BlockSpec((nb, group_lanes), lambda i: (0, i),
@@ -1180,7 +1190,7 @@ def hist_grid_grouped_packed(packed: dict, steps0, q: GridQuery, hb: int,
                                     memory_space=pltpu.VMEM),
                        pl.BlockSpec((hb8, q.nsteps), lambda i: (i, 0),
                                     memory_space=pltpu.VMEM)),
-        )(s0, m, p)
+        )(s0, r0, m, p)
         sums.append(s)
         cnts.append(c)
     s = sums[0] if len(sums) == 1 else jnp.concatenate(sums, axis=0)
@@ -1219,14 +1229,14 @@ _TOPK_ONEHOT_MAX_G = 2048
 @functools.partial(devicewatch.jit,
                    program="grid.event_topk_grid_packed",
                    static_argnames=("q", "k", "num_groups", "filt_op",
-                                    "filt_q", "row0", "interpret",
-                                    "largest", "group_width"))
+                                    "filt_q", "interpret", "largest",
+                                    "group_width"))
 def event_topk_grid_packed(packed: dict, steps0, q: GridQuery, k: int,
                            garr, num_groups: int,
                            filt_packed: Optional[dict] = None,
                            filt_op: str = "gt", filt_thresh=0.0,
                            filt_q: Optional[GridQuery] = None,
-                           filt_pos=None, row0: int = 0,
+                           filt_pos=None, row0=0,
                            interpret: bool = False, largest: bool = True,
                            group_width: int = 0):
     """``topk(k, agg by (g)(fn(value_col[w])))`` with an optional scan

@@ -702,9 +702,13 @@ def test_lifecycle_periodic_thread_and_finalize_and_pool():
 
 @pytest.fixture(scope="module")
 def tree_findings():
-    t0 = time.monotonic()
+    """The whole tree's findings and the lint's own CPU seconds: the
+    process's CPU clock, which the other workers of a parallel test run
+    do not inflate as they do the wall clock (the lint runs in this one
+    thread, on no pool)."""
+    t0 = time.process_time()
     findings = A.run_paths([PKG])
-    elapsed = time.monotonic() - t0
+    elapsed = time.process_time() - t0
     return findings, elapsed
 
 
@@ -719,10 +723,12 @@ def test_full_tree_zero_unsuppressed_under_budget(tree_findings):
             assert f.suppress_reason.strip()
     # budget raised 10s -> 15s in PR 17: the tree grew to 126+ files
     # (typical run ~4-5s, vs 2.4s when PR 13 set 10s) and single-core
-    # CI boxes spike 2x under load — the guard still catches any
-    # super-linear regression without flaking on host noise
+    # CI boxes spike 2x under load.  It holds the lint's CPU time, not
+    # its wall time: under six test workers the wall read 15.6 s for
+    # ~12.8 s of work, a budget of the host's load; the CPU budget still
+    # catches any super-linear regression of the linter itself
     assert elapsed <= 15.0, \
-        f"filolint full-tree run took {elapsed:.1f}s (budget 15s)"
+        f"filolint full-tree run took {elapsed:.1f}s of CPU (budget 15s)"
 
 
 def test_cli_json_output_for_ci(capsys):

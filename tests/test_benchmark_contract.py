@@ -198,6 +198,14 @@ def test_live_edge_stage_span_is_emitted(span, emitted):
     assert span in emitted
 
 
+@pytest.mark.parametrize("span", ["grid.packed"])
+def test_sliding_stage_span_is_emitted(span, emitted):
+    """The packed programs' launch span (doc/observability.md
+    "Stage spans"), read by ``packed_per_query`` and
+    ``packed_dispatch_ms`` of ``jmh1.sliding``."""
+    assert span in emitted
+
+
 def test_the_append_program_is_a_helper_that_answers_no_request():
     """``devicestore.tail_append`` writes an open block's cells for the
     ingest thread: one name, through ``devicewatch.jit``, never stacked.
@@ -416,3 +424,38 @@ def test_a_query_range_reads_the_unflushed_rows(edge_server):
     _post(port, _container(range(30, 33)))
     assert _ingested(edge_server, 33) == 33
     assert newest() == 1032.0 and shard.stats.chunks_flushed == 0
+
+
+def test_end_ignored_still_moves_what_a_client_reads(live_server,
+                                                     monkeypatch):
+    """``selftest/broken_run.py`` plants ``end_ignored`` by replacing
+    ``http.server.FiloHttpServer._query_range(self, binding, params)``:
+    it moves ``params``' ``start`` and ``end`` (seconds, as text) and
+    takes the ``(code, dict)`` it returns back to the asked steps,
+    ``[seconds, text]`` a value.  So the served path calls the method
+    through the class at request time, with a mapping of the request's
+    parameters, and answers with ``json.dumps`` of that dict: the fault
+    that proves a sliding cell's ends are honoured keeps its teeth."""
+    import inspect
+
+    from filodb_tpu.http import server
+    whole = server.FiloHttpServer._query_range
+    assert len(inspect.signature(whole).parameters) == 3
+    sound = _query_range(live_server)
+    back = 100                       # seconds: two steps of the query
+
+    def ignored(self, b, p):         # the fault's own moves
+        moved = {k: str((int(float(p[k]) * 1000) + back * 1000) / 1000)
+                 for k in ("start", "end")}
+        code, body = whole(self, b, dict(p, **moved))
+        for row in body["data"]["result"]:
+            row["values"] = [[t - back, v] for t, v in row["values"]]
+        return code, body
+    monkeypatch.setattr(server.FiloHttpServer, "_query_range", ignored)
+    moved = _query_range(live_server)
+    for s, m in zip(sound, moved):
+        assert [t for t, _v in s["values"]] == [t for t, _v in m["values"]]
+        # a value two steps on answers for the step asked
+        assert [v for _t, v in m["values"][:-2]] == \
+            [v for _t, v in s["values"][2:]]
+        assert all(isinstance(v, str) for _t, v in m["values"])

@@ -192,15 +192,21 @@ def _packed_block(ncols: int, stride: int = 1):
     return dict(pk.planes)
 
 
-# row0 = 3: a window start that is NOT a sublane multiple — the static
-# offset the packed kernels exist to serve
-@pytest.mark.parametrize("nsteps,k", [(56, 5), (100, 20), (120, 5)])
+# row0 is a traced int32: the span's first row in the block, any of them
+# (3 is not a sublane multiple), rotated to the top inside the kernel —
+# one build serves every offset
+ROW0 = _sds((), jnp.int32)
+
+
+# (60, 20): a "last 15 minutes" panel at a 15 s step, 79 rows of a block
+@pytest.mark.parametrize("nsteps,k", [(56, 5), (60, 20), (100, 20),
+                                      (120, 5)])
 @pytest.mark.parametrize("use_phase", [True, False],
                          ids=["phase", "free"])
 def test_rate_grid_packed(one_chip, use_phase, nsteps, k):
     q = _query("phase" if use_phase else "free", nsteps, k)
     _compile(grid.rate_grid_packed, one_chip, _packed_block(4096),
-             _sds((), jnp.int32), q=q, row0=3, use_phase=use_phase)
+             _sds((), jnp.int32), q=q, row0=ROW0, use_phase=use_phase)
 
 
 def test_hist_grid_grouped_packed(one_chip):
@@ -208,7 +214,7 @@ def test_hist_grid_grouped_packed(one_chip):
     hb, groups = 64, 4
     _compile(grid.hist_grid_grouped_packed, one_chip,
              _packed_block(groups * 1024, stride=hb), _sds((), jnp.int32),
-             q=_query("phase", 56, 5), hb=hb, group_lanes=1024, row0=3)
+             q=_query("phase", 56, 5), hb=hb, group_lanes=1024, row0=ROW0)
 
 
 def test_event_topk_grid_packed(one_chip):
@@ -218,7 +224,7 @@ def test_event_topk_grid_packed(one_chip):
              garr=_sds((ncols,), jnp.int32), num_groups=groups,
              filt_packed=_packed_block(ncols), filt_op="gt",
              filt_thresh=_sds((), jnp.float32),
-             filt_q=_query("free", 56, 5, op="last"), row0=3)
+             filt_q=_query("free", 56, 5, op="last"), row0=ROW0)
 
 
 def test_m4_grid(one_chip):
@@ -305,7 +311,7 @@ def test_fused_program_wide_mixed_classes_compiles_fast(one_chip, as_tpu):
 def test_fused_program_packed(one_chip, as_tpu, prog, use_phase):
     ncols = 4096
     q = _query("phase" if use_phase else "free", 100, 20)
-    kw = dict(q=q, row0=3, use_phase=use_phase)
+    kw = dict(q=q, row0=ROW0, use_phase=use_phase)
     fn = devicestore._fused_progs()[prog]
     if prog == "series_packed":
         _compile(fn, one_chip, _packed_block(ncols), _sds((), jnp.int64),

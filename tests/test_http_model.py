@@ -4,6 +4,7 @@ against the cell-at-a-time implementation they replaced
 as it was before PR 32), and a count that says the batch-at-a-time form
 makes no NumPy call a series or a cell."""
 
+import gc
 import json
 import sys
 
@@ -167,6 +168,20 @@ def test_vector_is_the_oracles_byte_for_byte(case):
                     START + 22 * STEP, START + 10**9):
         assert json.dumps(to_prom_vector(result, time_ms, column)) == \
             json.dumps(oracle.prom_vector(result, time_ms, column)), time_ms
+
+
+def test_an_answers_cells_leave_the_collectors_books():
+    """A cell is a tuple of a float and a str, which the cyclic collector
+    stops tracking at the first collection that meets it: the cells of
+    the answers in flight are not promoted into the old generation,
+    where they paced its full collections (PERF.md section 5,
+    ``jmh1.sliding``).  ``json.dumps`` writes it as the array a list
+    was (the byte-for-byte cases above)."""
+    result = to_prom_matrix(CASES["cells-128x23-whole"])["data"]["result"]
+    gc.collect()
+    cells = [c for s in result for c in s["values"]]
+    assert len(cells) == 128 * 23
+    assert not any(gc.is_tracked(c) for c in cells)
 
 
 def test_the_cases_say_what_their_names_say():

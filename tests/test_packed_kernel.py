@@ -152,19 +152,65 @@ def _served_grouped_packed(dev, inv, garr, num_groups, q):
     return np.asarray(both)
 
 
+# A "last 15 minutes" panel over a whole 128-row block: 60 steps and a
+# [5m] window at a 15 s scrape (K = 20), 79 rows a span, so its first row
+# can stand at any of the block's rows 0..49
+BLOCK_T, BLOCK_K = 60, 20
+BLOCK_OFFSETS = range(devicestore.BLOCK_BUCKETS
+                      - (BLOCK_T + BLOCK_K - 1) + 1)
+
+
+def _oracle_block(op, v, phase, K, g=STEP):
+    """``tests/oracle.py`` over every window of a whole block, a lane at
+    a time, in f64: window t covers rows [t, t+K-1] and ends at t*g,
+    so row c's sample is stamped (c - K)*g + phase.  -> [windows, L]."""
+    B, L = v.shape
+    ts = (np.arange(B)[:, None] - K) * g + phase[None, :].astype(np.int64)
+    ends = (B - K) * g
+    name = {"rate": "rate", "sum": "sum_over_time", "last": "last"}[op]
+    return np.stack([oracle.range_fn(name, ts[:, s], v[:, s], 0, ends, g,
+                                     K * g) for s in range(L)], axis=1)
+
+
+@pytest.fixture(scope="module")
+def phase_block():
+    """A packed 128-row block of phase-uniform counters (an empty band
+    of lanes in it), its device planes and the oracle's every window."""
+    rng = np.random.default_rng(11)
+    B, L = devicestore.BLOCK_BUCKETS, 512
+    v = _counters(rng, B, L)
+    v[:, 200:230] = np.nan
+    phase = rng.integers(1, STEP, L).astype(np.int32)
+    pk, dev = _pack_dev(v, phase=phase)
+    return v, phase, pk, dev, _oracle_block("rate", v, phase, BLOCK_K)
+
+
+@pytest.fixture(scope="module")
+def edge_block():
+    """A packed 128-row block of every class edge case (NaN payloads,
+    holes, mixed classes), its device planes and the oracle's every
+    window of the two TS-free ops the sweep serves."""
+    rng = np.random.default_rng(12)
+    B, L = devicestore.BLOCK_BUCKETS, 512
+    v = _edge_plane(rng, B, L)
+    phase = np.full(L, STEP, np.int32)       # bucket-edge stamps
+    pk, dev = _pack_dev(v)
+    want = {op: _oracle_block(op, v, phase, BLOCK_K)
+            for op in ("last", "sum")}
+    return v, pk, dev, want
+
+
 class TestFusedKernelEquivalence:
     """rate_grid_packed, alone and under the device store's grouped
-    reduce, in interpret mode vs the decoded-plane reference."""
+    reduce, in interpret mode vs the decoded-plane reference and the
+    oracle."""
 
-    @pytest.mark.parametrize("row0", [0, 3, 9])
-    def test_phase_rate_matches_ref(self, row0):
-        rng = np.random.default_rng(11)
-        B, L = 64, 512
-        v = _counters(rng, B, L)
-        v[:, 200:230] = np.nan
-        phase = rng.integers(1, STEP, L).astype(np.int32)
-        pk, dev = _pack_dev(v, phase=phase)
-        T, K = 20, 5
+    @pytest.mark.parametrize("row0", BLOCK_OFFSETS)
+    def test_phase_rate_matches_ref(self, row0, phase_block):
+        """Every offset of a span in a block: the kernel rotates its
+        traced row0 to the top; XLA decode path and oracle agree."""
+        v, phase, pk, dev, want = phase_block
+        T, K = BLOCK_T, BLOCK_K
         q = GridQuery(nsteps=T, kbuckets=K, gstep_ms=STEP, is_rate=True,
                       dense=True)
         out = np.asarray(rate_grid_packed(dev, 0, q, row0=row0,
@@ -176,6 +222,34 @@ class TestFusedKernelEquivalence:
         fin = np.isfinite(ref)
         assert (np.isfinite(out) == fin).all()
         np.testing.assert_allclose(out[fin], ref[fin], rtol=2e-5)
+        want = want[row0:row0 + T]
+        assert (np.isfinite(want) == fin).all()
+        np.testing.assert_allclose(out[fin], want[fin], rtol=2e-6)
+
+    @pytest.mark.parametrize("row0", BLOCK_OFFSETS)
+    @pytest.mark.parametrize("op", ["last", "sum"])
+    def test_free_op_every_offset(self, op, row0, edge_block):
+        """TS-free ``last`` and ``sum`` over a mixed-class block at every
+        offset: ``last`` exact, ``sum`` within the cell's 2e-6, against
+        the XLA decode path and the oracle."""
+        v, pk, dev, want = edge_block
+        T, K = BLOCK_T, BLOCK_K
+        q = GridQuery(nsteps=T, kbuckets=K, gstep_ms=STEP, op=op,
+                      is_rate=False, dense=False)
+        out = np.asarray(rate_grid_packed(dev, 0, q, row0=row0,
+                                          interpret=True))[:, pk.inv]
+        ref = np.asarray(rate_grid_ref(
+            None, jnp.asarray(v[row0:row0 + T + K - 1]), 0, q))
+        want = want[op][row0:row0 + T]
+        fin = np.isfinite(want)
+        assert (np.isfinite(out) == fin).all()
+        assert (np.isfinite(ref) == fin).all()
+        if op == "last":
+            np.testing.assert_array_equal(out[fin], want[fin])
+            np.testing.assert_array_equal(out[fin], ref[fin])
+        else:
+            np.testing.assert_allclose(out[fin], want[fin], rtol=2e-6)
+            np.testing.assert_allclose(out[fin], ref[fin], rtol=2e-6)
 
     @pytest.mark.parametrize("op", ["sum", "max", "count", "last"])
     def test_free_ops_match_ref(self, op):
@@ -226,9 +300,11 @@ class TestFusedKernelEquivalence:
         v = _counters(rng, 64, 256)
         pk, dev = _pack_dev(v, min_width=16)
         assert packed_width(dev) == 256
-        q = GridQuery(nsteps=8, kbuckets=4, gstep_ms=STEP, dense=True)
+        # a span longer than the block; where one that fits starts is
+        # the dispatcher's proof (the offset is traced)
+        q = GridQuery(nsteps=62, kbuckets=4, gstep_ms=STEP, dense=True)
         with pytest.raises(ValueError, match="rows"):
-            rate_grid_packed(dev, 0, q, row0=60, interpret=True,
+            rate_grid_packed(dev, 0, q, row0=0, interpret=True,
                              use_phase=True)
         qbad = GridQuery(nsteps=8, kbuckets=4, gstep_ms=STEP, op="rate",
                          dense=True)
@@ -408,6 +484,32 @@ class TestFusedKernelEquivalence:
         assert (np.isfinite(out) == fin).all()
         np.testing.assert_allclose(out[fin], ref[fin], rtol=2e-5)
 
+    def test_one_executable_serves_every_offset(self, phase_block):
+        """``devicestore.series_packed`` takes ``row0`` as an operand:
+        three offsets, one of them 8-aligned, run through ONE compiled
+        executable, and each answers its own rows."""
+        from filodb_tpu.utils.devicewatch import COMPILE_WATCH
+        v, phase, pk, dev, want = phase_block
+        # a shape no other case compiles, so the first call compiles
+        T, K = BLOCK_T - 1, BLOCK_K
+        q = GridQuery(nsteps=T, kbuckets=K, gstep_ms=STEP, is_rate=True,
+                      dense=True)
+        prog = devicestore._fused_progs()["series_packed"]
+
+        def compiles():
+            return sum(r["compiles"] for r in COMPILE_WATCH.table()
+                       if r["program"] == "devicestore.series_packed")
+        before, cached = compiles(), prog._jitted._cache_size()
+        for row0 in (3, 40, 17):
+            out = np.asarray(prog(dev, 0, q=q, row0=row0, use_phase=True,
+                                  interpret=True))[:, pk.inv]
+            w = want[row0:row0 + T]
+            fin = np.isfinite(w)
+            assert (np.isfinite(out) == fin).all()
+            np.testing.assert_allclose(out[fin], w[fin], rtol=2e-6)
+        assert compiles() == before + 1
+        assert prog._jitted._cache_size() == cached + 1
+
 
 def _hist_plane(rng, B, n_series, hb, mixed=False):
     """[B, n_series*hb] bucket plane: column s*hb + j = series s's
@@ -469,6 +571,137 @@ class TestHistStridePack:
             assert n % LANE_BLOCK == 0 or n <= UNPADDED_MAX, (key, n)
         np.testing.assert_array_equal(unpack_vals(pk).view(np.uint32),
                                       v.view(np.uint32))
+
+
+S_STEP = 60_000
+S_T0 = 1_700_000_040_000
+S_ROWS = 260            # block 1 (buckets 128..255) full and frozen
+S_PANELS = {            # query -> (oracle fn, aggregate, exact)
+    'c_total{_ws_="w",_ns_="n"}': ("last", False, True),
+    'sum_over_time(c_total{_ws_="w",_ns_="n"}[5m])':
+        ("sum_over_time", False, False),
+    'rate(c_total{_ws_="w",_ns_="n"}[5m])': ("rate", False, False),
+    'sum(rate(c_total{_ws_="w",_ns_="n"}[5m]))': ("rate", True, False),
+}
+
+
+class TestSlidingEndsServed:
+    """"Last 20 minutes" panels whose end slides through one block,
+    through ``query_range`` on a small store served by the packed
+    programs (interpret mode): every end is the oracle's answer, and the
+    programs compile once a panel, not once an end."""
+
+    @pytest.fixture(scope="class")
+    def served(self):
+        from filodb_tpu.coordinator.planner import SingleClusterPlanner
+        from filodb_tpu.core.record import RecordBuilder, decode_container
+        from filodb_tpu.core.schemas import DEFAULT_SCHEMAS, DatasetOptions
+        from filodb_tpu.core.storeconfig import StoreConfig
+        from filodb_tpu.http.server import DatasetBinding, FiloHttpServer
+        from filodb_tpu.memstore.memstore import TimeSeriesMemStore
+        from filodb_tpu.parallel.shardmap import ShardMapper, ShardStatus
+        mp = pytest.MonkeyPatch()
+        mp.setattr(devicestore, "_PACKED_INTERPRET", True)
+        mp.setattr(devicestore, "_PACKED_BROKEN", False)
+        mp.setattr(devicestore.DeviceGridCache, "_val_dtype",
+                   lambda self: np.float32)
+        ms = TimeSeriesMemStore()
+        shard = ms.setup("grid", DEFAULT_SCHEMAS, 0,
+                         StoreConfig(device_cache_compress=True))
+        rng = np.random.default_rng(7)
+        b = RecordBuilder(DEFAULT_SCHEMAS["prom-counter"])
+        series = {}
+        for i in range(8):
+            ph = int(rng.integers(1, S_STEP))
+            ts = S_T0 + np.arange(S_ROWS, dtype=np.int64) * S_STEP \
+                - S_STEP + ph
+            vals = (2 ** 23 + 128 * np.cumsum(
+                rng.integers(1, 8, S_ROWS))).astype(np.float64)
+            series[f"i{i}"] = (ts, vals)
+            b.add_series(ts, [vals], {"__name__": "c_total",
+                                      "instance": f"i{i}", "_ws_": "w",
+                                      "_ns_": "n"})
+        for off, c in enumerate(b.containers()):
+            shard.ingest(decode_container(c, DEFAULT_SCHEMAS), off)
+        shard.flush_all()
+        mapper = ShardMapper(1)
+        mapper.register_node([0], "local")
+        mapper.update_status(0, ShardStatus.ACTIVE)
+        srv = FiloHttpServer()
+        srv.bind_dataset(DatasetBinding(
+            "grid", ms, SingleClusterPlanner("grid", mapper,
+                                             DatasetOptions(),
+                                             spread_default=0)))
+        port = srv.start()
+        yield port, shard, series
+        srv.shutdown()
+        mp.undo()
+
+    def test_every_end_is_the_oracles_and_compiles_once(self, served):
+        import json
+        import time
+        import urllib.parse
+        import urllib.request
+
+        from filodb_tpu.utils.devicewatch import COMPILE_WATCH
+        from filodb_tpu.utils.observability import TRACER
+        port, shard, series = served
+        T, W = 20, 300_000
+
+        def compiles():
+            return {r["program"]: r["compiles"]
+                    for r in COMPILE_WATCH.table()
+                    if r["program"].endswith("_packed")}
+
+        def packed_spans():
+            return TRACER.stages.snapshot().get(
+                "grid.packed", {"count": 0})["count"]
+        before, spans0 = compiles(), packed_spans()
+        # step k stands at bucket k + 1 of the cache: an end at step
+        # 150 + r puts the 24-row span at row r of block 1, r = 0..104
+        offsets = (0, 1, 7, 8, 9, 31, 64, 99, 104)
+        served_at = set()
+        for r in offsets:
+            end = S_T0 + (150 + r) * S_STEP
+            start = end - (T - 1) * S_STEP
+            for query, (fn, agg, exact) in S_PANELS.items():
+                qs = urllib.parse.urlencode(dict(
+                    query=query, start=start / 1000, end=end / 1000,
+                    step="60s"))
+                with urllib.request.urlopen(
+                        f"http://127.0.0.1:{port}/promql/grid/api/v1/"
+                        f"query_range?{qs}", timeout=60) as resp:
+                    result = json.loads(resp.read())["data"]["result"]
+                want = {k: oracle.range_fn(fn, ts, vals, start, end,
+                                           S_STEP, W)
+                        for k, (ts, vals) in series.items()}
+                if agg:
+                    want = {"": np.sum(list(want.values()), axis=0)}
+                got = {e["metric"].get("instance", ""):
+                       np.array([float(v) for _t, v in e["values"]])
+                       for e in result}
+                assert got.keys() == want.keys(), (query, r)
+                for k, w in want.items():
+                    assert np.isfinite(w).all()
+                    if exact:
+                        np.testing.assert_array_equal(got[k], w)
+                    else:
+                        np.testing.assert_allclose(got[k], w, rtol=2e-6,
+                                                   err_msg=f"{query} {r}")
+            cache = next(iter(shard.device_caches.values()))
+            served_at |= {p.packed_row0 for p in cache._plan_memo.values()
+                          if p.packed is not None}
+        assert served_at == set(offsets)
+        asked = len(offsets) * len(S_PANELS)
+        for _ in range(100):      # the worker folds its stages in last
+            if packed_spans() - spans0 >= asked:
+                break
+            time.sleep(0.02)
+        assert packed_spans() - spans0 == asked
+        # one compile a panel shape at most, whatever the ends
+        grew = {p: n - before.get(p, 0) for p, n in compiles().items()}
+        assert grew.get("devicestore.series_packed", 0) <= 3, grew
+        assert grew.get("devicestore.grouped_packed", 0) <= 1, grew
 
 
 class TestHistFusedKernels:
